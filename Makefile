@@ -117,8 +117,11 @@ bench-attribution:
 
 # 2-worker crash-tolerant ledger build of the example fleet config
 # (docs/robustness.md "Multi-worker builds") — the smoke proof that N
-# worker processes coordinate through the shared-volume ledger
+# worker processes coordinate through the shared-volume ledger. On the
+# CPU, explicitly: N local workers cannot share one chip, and
+# build-fleet refuses --workers N>1 anywhere else
 build-multiworker:
+	JAX_PLATFORMS=cpu \
 	MACHINES="$$(cat examples/machines_fleet.yaml)" \
 	OUTPUT_DIR=$${OUTPUT_DIR:-/tmp/gordo-tpu-multiworker} \
 	python -m gordo_tpu.cli build-fleet --workers 2 --lease-ttl 15

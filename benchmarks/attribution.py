@@ -36,9 +36,8 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import enable_compile_cache, honor_jax_platforms_env
+from gordo_tpu.utils import enable_compile_cache
 
-honor_jax_platforms_env()
 enable_compile_cache()
 
 from benchmarks.load_test import self_serve  # noqa: E402

@@ -3,6 +3,11 @@ Cold-start benchmark: time-to-first-prediction for a FRESHLY EXEC'D
 server process, cold trace vs AOT executable cache
 (docs/performance.md "AOT executable cache").
 
+Process model: this parent never initializes JAX. The build runs in a
+child that exits, then each measured server is its own fresh process —
+an accelerator belongs to one process at a time, so a parent that built
+in-process would hold the chip its servers need.
+
 The paper's regime — thousands of tiny models — makes XLA compile time
 the dominant cost of every fresh serving process: the goodput lost is
 time the device is reserved but doing no model work (PAPERS.md
@@ -51,14 +56,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import enable_compile_cache, honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 _SERVER_SCRIPT = """
-import os
-from gordo_tpu.utils import honor_jax_platforms_env
-honor_jax_platforms_env()
 from werkzeug.serving import make_server
 from gordo_tpu.server import build_app
 app = build_app()
@@ -80,16 +78,17 @@ def first_prediction_seconds(
     Exec a fresh server process against ``collection`` and poll the
     fleet endpoint until the first 200; returns (seconds from exec to
     that response, response body bytes, the response's server-side
-    ``predict`` phase in seconds). The persistent XLA compile cache is
-    pointed at a per-RUN directory so the cold arm cannot warm itself
-    across repeats into an AOT-cache lookalike.
+    ``predict`` phase in seconds). The child's persistent XLA compile
+    cache is placed (``JAX_COMPILATION_CACHE_DIR``) in a per-RUN directory
+    so the cold arm cannot warm itself across repeats into an AOT-cache
+    lookalike.
     """
     env = dict(os.environ)
     env.update(
         MODEL_COLLECTION_DIR=collection,
         GORDO_SERVER_PRELOAD="true",
         GORDO_AOT_CACHE="true" if aot else "false",
-        GORDO_XLA_CACHE_DIR=xla_cache_dir,
+        JAX_COMPILATION_CACHE_DIR=xla_cache_dir,
     )
     script = _SERVER_SCRIPT.format(port=port)
     t0 = time.perf_counter()
@@ -171,18 +170,25 @@ def main() -> dict:
     tmp_ctx = tempfile.TemporaryDirectory(prefix="gordo_cold_start_")
     tmp = tmp_ctx.name
     if args.collection_dir is None:
-        # the build process may use its own compile cache freely — only
+        # the build child may use its own compile cache freely — only
         # the measured server arms get segregated cache dirs below
-        enable_compile_cache(os.path.join(tmp, "xla_build"))
-        from benchmarks.server_latency import build_collection
+        from benchmarks.server_latency import build_collection_in_child
 
-        collection = build_collection(args.machines, tmp, args.model)
-        from gordo_tpu.programs import export_serving_programs
-
-        export_report = export_serving_programs(collection)
+        built = build_collection_in_child(
+            args.machines, tmp, args.model, export_programs=True,
+            env=dict(
+                os.environ,
+                JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "xla_build"),
+            ),
+        )
+        collection = built["collection"]
     else:
         collection = args.collection_dir
-        export_report = None
+        built = {}
+    # the world the measured servers ran in, as the collection's own
+    # manifest records it (this process never asks JAX)
+    with open(os.path.join(collection, ".programs", "manifest.json")) as fh:
+        manifest = json.load(fh)
 
     names = sorted(
         n for n in os.listdir(collection)
@@ -231,13 +237,11 @@ def main() -> dict:
             "first_predict_s": round(min(phases), 4) if phases else None,
         }
 
-    import jax
-
     result = {
         "bench_schema_version": 1,
         "benchmark": "cold_start",
-        "platform": jax.default_backend(),
-        "device_kind": getattr(jax.devices()[0], "device_kind", None),
+        "platform": manifest["backend"],
+        "device_kind": manifest["device_kind"],
         "n_machines": len(names),
         "model": args.model,
         "samples": args.samples,
@@ -264,7 +268,7 @@ def main() -> dict:
         else None,
         "predictions_identical": payloads.get("cold_trace")
         == payloads.get("aot_cache"),
-        "n_programs_exported": (export_report or {}).get("n_programs"),
+        "n_programs_exported": built.get("n_programs"),
         "arms": arms,
     }
     line = json.dumps(result)

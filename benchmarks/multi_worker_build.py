@@ -16,9 +16,10 @@ Measures, on one JSON line (the bench-output contract):
    "recoverable interruptions dominate fleet goodput" number from the
    ML-goodput paper, PAPERS.md arXiv:2502.06982).
 
-CPU-runnable end to end (JAX_PLATFORMS=cpu); on a TPU host the same
-script measures real compile/dispatch overlap. Worker counts that
-exceed the host (or the bucket count) just shard shallower.
+CPU only (JAX_PLATFORMS=cpu): N local workers cannot share one chip,
+and ``build-fleet --workers N>1`` refuses to start anywhere else.
+Worker counts that exceed the host (or the bucket count) just shard
+shallower.
 """
 
 import argparse

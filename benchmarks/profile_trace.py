@@ -27,9 +27,8 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import enable_compile_cache, honor_jax_platforms_env
+from gordo_tpu.utils import enable_compile_cache
 
-honor_jax_platforms_env()
 enable_compile_cache()
 
 

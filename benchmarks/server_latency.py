@@ -11,15 +11,16 @@ import argparse
 import json
 import os
 import statistics
+import subprocess
 import sys
 import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
 
-from gordo_tpu.utils import enable_compile_cache, honor_jax_platforms_env
+from gordo_tpu.utils import enable_compile_cache
 
-honor_jax_platforms_env()
 enable_compile_cache()
 
 
@@ -97,6 +98,50 @@ def build_collection(
     return collection
 
 
+_BUILD_CHILD_SCRIPT = """
+import json, sys
+sys.path.insert(0, {repo!r})
+from benchmarks.server_latency import build_collection
+collection = build_collection({n_machines}, {tmp!r}, {model!r})
+n_programs = None
+if {export_programs}:
+    from gordo_tpu.programs import export_serving_programs
+    n_programs = export_serving_programs(collection)["n_programs"]
+print(json.dumps({{"collection": collection, "n_programs": n_programs}}))
+"""
+
+
+def build_collection_in_child(
+    n_machines: int,
+    tmp: str,
+    model: str = "hourglass",
+    export_programs: bool = False,
+    env: "dict | None" = None,
+) -> dict:
+    """
+    :func:`build_collection` in a child process that EXITS before the
+    caller starts anything else. An accelerator belongs to one process at
+    a time: a parent that built in-process has initialized JAX and holds
+    the chip, and a server it then spawns on the same chip fails or
+    hangs. Returns ``{collection, n_programs}``.
+    """
+    script = _BUILD_CHILD_SCRIPT.format(
+        repo=REPO_ROOT,
+        n_machines=int(n_machines),
+        tmp=tmp,
+        model=model,
+        export_programs=bool(export_programs),
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", script],
+        env=env,
+        stdout=subprocess.PIPE,
+        text=True,
+        check=True,
+    )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def summarize_ms(times):
     """mean/p50/p95/p99 summary of a list of millisecond latencies."""
     ordered = sorted(times)
@@ -118,17 +163,6 @@ def timed_posts(client, url, body, rounds):
     return {**summarize_ms(times), "rounds": rounds}
 
 
-_LIVE_SERVER_SCRIPT = """
-import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from gordo_tpu.utils import honor_jax_platforms_env
-honor_jax_platforms_env()
-from gordo_tpu.server.app import run_server
-run_server("127.0.0.1", {port}, workers={workers}, log_level="warning",
-           threads={threads})
-"""
-
-
 def live_throughput(
     collection: str,
     workers: int,
@@ -144,7 +178,6 @@ def live_throughput(
     """
     import signal
     import socket
-    import subprocess
     import threading
 
     import requests as http
@@ -153,13 +186,17 @@ def live_throughput(
     port = probe.getsockname()[1]
     probe.close()
 
-    env = dict(os.environ, MODEL_COLLECTION_DIR=collection, JAX_PLATFORMS="cpu")
+    # the normal entry point, on whatever platform the environment names:
+    # the child is never forced onto the CPU behind the caller's back
+    env = dict(os.environ, MODEL_COLLECTION_DIR=collection)
     proc = subprocess.Popen(
         [
-            sys.executable,
-            "-c",
-            _LIVE_SERVER_SCRIPT.format(port=port, workers=workers, threads=threads),
+            sys.executable, "-m", "gordo_tpu.cli", "run-server",
+            "--host", "127.0.0.1", "--port", str(port),
+            "--workers", str(workers), "--threads", str(threads),
+            "--log-level", "warning",
         ],
+        cwd=REPO_ROOT,
         env=env,
         stdout=subprocess.DEVNULL,
         stderr=subprocess.DEVNULL,
@@ -250,13 +287,14 @@ def main():
     from werkzeug.test import Client
 
     with tempfile.TemporaryDirectory() as tmp:
-        collection = build_collection(args.fleet_machines, tmp)
+        # build in a child that exits, and run the live-server arm BEFORE
+        # this process initializes JAX: each live server needs the device
+        # for itself (one process per chip)
+        collection = build_collection_in_child(args.fleet_machines, tmp)[
+            "collection"
+        ]
         os.environ["MODEL_COLLECTION_DIR"] = collection
 
-        from gordo_tpu.server import build_app
-        from gordo_tpu.server.utils import dataframe_to_dict
-
-        client = Client(build_app())
         rng = np.random.default_rng(0)
         index = pd.date_range(
             "2019-01-01", periods=args.samples, freq="10min", tz="UTC"
@@ -266,9 +304,26 @@ def main():
             columns=[f"tag-{i}" for i in range(4)],
             index=index,
         )
-        X = dataframe_to_dict(frame)
 
+        from gordo_tpu.server.utils import dataframe_to_dict
+
+        X = dataframe_to_dict(frame)
         results = {"bench_schema_version": 1, "bench": "server_latency"}
+
+        if args.concurrency:
+            arms = [(1, 1), (1, 8)]
+            if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+                # run-server refuses >1 local worker off the CPU (N
+                # processes cannot share one chip)
+                arms.append((2, 8))
+            results["live_concurrency"] = [
+                live_throughput(collection, workers, threads, {"X": X})
+                for workers, threads in arms
+            ]
+
+        from gordo_tpu.server import build_app
+
+        client = Client(build_app())
         base_url = "/gordo/v0/proj"
         # warmup (first request pays model load + jit compile)
         client.post(f"{base_url}/bench-m0/prediction", json={"X": X})
@@ -296,12 +351,6 @@ def main():
             fleet["mean_ms"] / args.fleet_machines, 3
         )
         results["fleet_prediction"] = fleet
-
-        if args.concurrency:
-            results["live_concurrency"] = [
-                live_throughput(collection, workers, threads, {"X": X})
-                for workers, threads in ((1, 1), (1, 8), (2, 8))
-            ]
 
         print(json.dumps(results))
 

@@ -30,9 +30,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import enable_compile_cache, honor_jax_platforms_env
+from gordo_tpu.utils import enable_compile_cache
 
-honor_jax_platforms_env()
 enable_compile_cache()
 
 from benchmarks.load_test import open_loop, self_serve  # noqa: E402

@@ -14,10 +14,6 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 N_MACHINES = 4
 
 MACHINE_TPL = """

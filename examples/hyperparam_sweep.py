@@ -11,10 +11,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
-
 import numpy as np  # noqa: E402
 
 from gordo_tpu.data import RandomDataset  # noqa: E402

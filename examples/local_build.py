@@ -11,10 +11,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
-
 from gordo_tpu.builder.local_build import local_build  # noqa: E402
 
 CONFIG = """

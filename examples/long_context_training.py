@@ -14,10 +14,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from gordo_tpu.utils import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 
 def main():
     import jax

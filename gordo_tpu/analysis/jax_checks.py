@@ -347,7 +347,7 @@ def check_host_sync(tree: ast.Module) -> typing.List[str]:
     """
     Device->host synchronization inside a ``for``/``while`` body: each
     occurrence stalls the async dispatch pipeline once PER ITERATION —
-    over a DCN/tunnel link that is the whole epoch budget
+    over a DCN link that is the whole epoch budget
     (docs/performance.md, "Device-resident multi-epoch training"). Only
     enforced on hot modules (``HOT_PATH_PATTERNS``; the engine applies
     the path filter). Flagged inside loop bodies:

@@ -116,7 +116,7 @@ class LSTMBaseEstimator(BaseJaxEstimator, TransformerMixin):
         windows are gathered inside the compiled program (chunked —
         FleetTrainer's predict machinery with a fleet of one): a host-side
         gather would transfer every row ``lookback_window`` times, the
-        dominant request cost on tunneled/PCIe links. Rows are padded to a
+        dominant request cost on PCIe links. Rows are padded to a
         power-of-two bucket so jit sees a bounded set of shapes.
         """
         X = X.values if hasattr(X, "values") else np.asarray(X)

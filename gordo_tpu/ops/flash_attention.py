@@ -28,7 +28,8 @@ Head_dim is padded to lane multiples (128) and seq to the block size
 outside the kernels; padded key columns are masked to zero probability,
 padded query rows carry zero dO/delta so they contribute nothing to dk/dv.
 
-On non-TPU backends (CPU tests) the kernels run in interpret mode.
+On the CPU backend (tests, rehearsals) the kernels run in interpret mode;
+on ``tpu`` they compile through Mosaic; any other backend is an error.
 """
 
 import functools
@@ -371,6 +372,25 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, residuals, d_out):
 _flash_attention_bhsd.defvjp(_fwd, _bwd)
 
 
+def _interpret_for_backend(backend: str) -> bool:
+    """
+    Whether the kernels run in the Pallas interpreter on ``backend``.
+    Only ``cpu`` maps to the interpreter and only ``tpu`` to the compiled
+    Mosaic kernel: an unknown backend name must never fall into "not tpu,
+    so interpret" — that is how a chip under another platform name would
+    silently run the interpreter.
+    """
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise ValueError(
+        f"flash_attention has no kernel mode for backend {backend!r} "
+        "(compiled on 'tpu', interpreted on 'cpu'); pass interpret= "
+        "explicitly"
+    )
+
+
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -386,11 +406,12 @@ def flash_attention(
     gordo_tpu.models.specs_seq.dense_attention, O(seq) HBM and
     O(block_q x block_k) VMEM in BOTH passes (see module docstring).
 
-    ``interpret=None`` auto-selects: compiled Mosaic kernel on TPU,
-    interpreter elsewhere (so CPU test runs exercise identical kernel code).
+    ``interpret=None`` selects from the backend: compiled Mosaic kernel
+    on ``tpu``, interpreter on ``cpu`` (so CPU test runs exercise
+    identical kernel code), ValueError on anything else.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_for_backend(jax.default_backend())
     batch, seq, heads, head_dim = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)

@@ -3,7 +3,7 @@ Build-time AOT compilation of serving programs.
 
 The paper's regime is thousands of tiny models, so XLA compile time —
 not math — dominates every fresh serving process (docs/performance.md:
-the r05 bench spent ~50 s of a ~128 s run in warmup). The fix is the
+the driver's r05 CPU-fallback run spent ~50 s of ~180 s in warmup). The fix is the
 Julia→TPU full-compilation move (PAPERS.md arXiv:1810.09868): compile
 at BUILD time, once, and make serving cold start a deserialize.
 
@@ -18,6 +18,7 @@ CLI switch, and the function stands alone for re-exporting an existing
 collection (multi-worker builds, a jax upgrade).
 """
 
+import contextlib
 import logging
 import os
 import typing
@@ -32,6 +33,36 @@ logger = logging.getLogger(__name__)
 DEFAULT_ROW_BUCKETS = (128, 256)
 
 ROW_BUCKETS_ENV_VAR = "GORDO_AOT_ROW_BUCKETS"
+
+
+@contextlib.contextmanager
+def fresh_compile():
+    """
+    Compile inside this block past JAX's persistent compile cache — on
+    the CPU backend only. An XLA:CPU executable that came from a cache
+    HIT re-serializes into a payload that loads and then cannot execute
+    ("Function ... not found"), so an export over a warm cache shipped a
+    store whose every program fell back at dispatch. On TPU the
+    re-serialized executable runs (``chip_smoke.py`` twice in one call:
+    the second build exports over a warm cache and serves with zero
+    fallbacks), and the hit is worth keeping there.
+    """
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if (
+        jax.default_backend() != "cpu"
+        or not jax.config.jax_enable_compilation_cache
+    ):
+        yield
+        return
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
 
 
 def serving_row_buckets() -> typing.Tuple[int, ...]:

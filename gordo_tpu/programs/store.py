@@ -116,6 +116,7 @@ class ProgramStore:
             "key": key,
             "file": path.name,
             "bytes": len(blob),
+            "sha256": hashlib.sha256(blob).hexdigest(),
         }
         return digest
 
@@ -163,6 +164,7 @@ class ProgramStore:
         chaos run exercises the exact byte-level failure a torn disk
         write or partial rsync would produce.
         """
+        import jax
         from jax.experimental import serialize_executable
 
         from gordo_tpu.robustness import faults
@@ -171,9 +173,24 @@ class ProgramStore:
         entry = self._index[digest]
         blob = (self.directory / entry["file"]).read_bytes()
         blob = faults.corrupt_program_payload(blob, digest=digest)
+        # a torn or mangled payload must fail HERE: bytes flipped inside
+        # the object code can deserialize cleanly and then crash the
+        # process at dispatch, which no fallback rung can absorb
+        expected = entry.get("sha256")
+        if expected and hashlib.sha256(blob).hexdigest() != expected:
+            raise ValueError(
+                f"program payload {entry['file']} does not match its "
+                "manifest checksum (torn write or corruption)"
+            )
         payload, in_tree, out_tree = pickle.loads(blob)
+        # serving programs are compiled for ONE device (the scorer places
+        # nothing); left to its default, deserialize_and_load spreads the
+        # executable over every local device, and on a multi-device host
+        # (4 chips, or the suite's 8 virtual CPUs) each dispatch then
+        # fails "expected 8 shards" and silently retraces
         return serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree
+            payload, in_tree, out_tree,
+            execution_devices=jax.local_devices()[:1],
         )
 
 

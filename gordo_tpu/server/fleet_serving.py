@@ -326,7 +326,7 @@ class FleetScorer:
         sibling artifacts. Best-effort per program: one architecture
         failing to serialize skips that program, never the build.
         """
-        from gordo_tpu.programs.aot import serving_row_buckets
+        from gordo_tpu.programs.aot import fresh_compile, serving_row_buckets
 
         if row_buckets is None:
             row_buckets = serving_row_buckets()
@@ -345,7 +345,7 @@ class FleetScorer:
             try:
                 with tracing.start_span(
                     "program.compile", m=m, rows=rows, fn=group["fn_digest"]
-                ):
+                ), fresh_compile():
                     compiled = group["apply"].lower(
                         params_struct, batch_struct
                     ).compile()
@@ -519,7 +519,11 @@ class FleetScorer:
         )
         if exe is not None:
             try:
-                return exe(params, jnp.asarray(batch))
+                # dispatch is asynchronous: an executable that loaded but
+                # cannot run reports it only when its result is awaited —
+                # await it HERE so that failure lands on the ladder, not
+                # in the caller's device->host fetch as a failed request
+                return jax.block_until_ready(exe(params, jnp.asarray(batch)))
             except Exception as exc:  # noqa: BLE001 - ANY failure retraces
                 logger.warning(
                     "AOT executable failed at dispatch (%s); retracing", exc

@@ -14,10 +14,11 @@ same knobs are honored natively:
 - ``worker_connections`` — per-worker bound on simultaneously *accepted*
   connections (handled + queued-behind-the-gate).
 
-One worker (the default for TPU serving) short-circuits the fork and
-serves in-process: a single process keeps a single device context hot —
-scale-out on TPU is by replica, not by local workers, since the chip is
-exclusive to one process.
+One worker short-circuits the fork and serves in-process: a single
+process keeps a single device context hot. Off the CPU that is the rule,
+not a default: the chip is exclusive to one process, so ``run-server
+--workers N>1`` is refused at start unless ``JAX_PLATFORMS=cpu`` —
+scale-out on TPU is by replica, not by local workers.
 
 Interplay with dynamic batching (docs/serving.md#dynamic-batching):
 batching is per-process — each worker owns its own request queues and

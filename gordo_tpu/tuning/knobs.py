@@ -680,7 +680,6 @@ NON_KNOB_ENV_VARS: typing.FrozenSet[str] = frozenset(
         "GORDO_PROFILE_OUT",
         # paths and mounts
         "GORDO_TPU_LAKE_DIR",
-        "GORDO_XLA_CACHE_DIR",
         "GORDO_MOUNT_PATH",
         "GORDO_MOUNT_WAIT_SECONDS",
         "GORDO_TUNING_PROFILE",

@@ -7,7 +7,6 @@ from .utils import (
     compile_cache_dir,
     compile_cache_dir_bytes,
     enable_compile_cache,
-    honor_jax_platforms_env,
     replace_all_non_ascii_chars_with_default,
 )
 from . import atomic, disk_registry
@@ -18,7 +17,6 @@ __all__ = [
     "compile_cache_dir",
     "compile_cache_dir_bytes",
     "enable_compile_cache",
-    "honor_jax_platforms_env",
     "replace_all_non_ascii_chars_with_default",
     "atomic",
     "disk_registry",

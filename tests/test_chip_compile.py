@@ -1,0 +1,280 @@
+"""
+The main path's programs, compiled at their real widths for a TPU v5e that
+is DESCRIBED, not attached (on-chip-measurement guide, section 2): the
+chip's own compiler is installed here and refuses what it would refuse
+there — a slice not aligned to the tiling, a kernel over its VMEM limit, a
+program that does not fit HBM. Nothing runs, so these say nothing about
+results or times; they keep every later PR from shipping a program the
+chip cannot compile, at no chip time.
+
+Code that asks ``jax.default_backend()`` sees the CPU in such a compile,
+so each case lowers the jitted step itself, and the flash kernel's mode is
+steered HERE (``compiled_kernels``), not through a program option.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from gordo_tpu.models.factories.gru import gru_model
+from gordo_tpu.models.factories.lstm import lstm_model
+from gordo_tpu.models.factories.transformer import transformer_model
+from gordo_tpu.ops import flash_attention as flash_module
+from gordo_tpu.ops.flash_attention import flash_attention
+from gordo_tpu.parallel.fleet import FleetTrainer
+
+# the flagship plant (chip_smoke.py's full size; bench.py)
+N_TAGS, LOOKBACK, BATCH, N_TIMESTEPS, N_MACHINES = 50, 64, 512, 16384, 8
+ENC, DEC = (128, 64), (64, 128)
+
+
+@pytest.fixture(scope="module")
+def topology():
+    """A described v5e 2x2 host; skipped only where the topology cannot be
+    described (no TPU compiler in the installation)."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    """One described chip's sharding."""
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _cache_off(no_persistent_compile_cache):
+    """A described-topology compile is written to the persistent cache but
+    cannot be read back without a chip (the next run warns and compiles
+    again) — keep it off around these."""
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Under a described-topology compile the backend still reads "cpu",
+    which selects the Pallas interpreter; compile the Mosaic kernel, as
+    the chip would."""
+    monkeypatch.setattr(
+        flash_module, "_interpret_for_backend", lambda backend: False
+    )
+
+
+def on_chip(tree, sharding):
+    """Shapes of ``tree`` placed on the described device."""
+    return jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding
+        ),
+        tree,
+    )
+
+
+def plant_spec(factory=lstm_model, **kwargs):
+    return factory(
+        n_features=N_TAGS, lookback_window=LOOKBACK,
+        encoding_dim=ENC, encoding_func=("tanh",) * len(ENC),
+        decoding_dim=DEC, decoding_func=("tanh",) * len(DEC),
+        fused=True, **kwargs,
+    )
+
+
+# -- the Pallas kernel -------------------------------------------------------
+
+
+@pytest.mark.parametrize("seq", [1024, 16384])
+def test_flash_attention_fwd_bwd_compiles(chip, seq):
+    """Causal forward AND backward as Mosaic kernels (interpret=False), 4
+    heads of 64 — phase 4's width; 16,384 is the long-context bound."""
+    qkv = jax.ShapeDtypeStruct((1, seq, 4, 64), jnp.float32, sharding=chip)
+
+    def loss(q, k, v):
+        return jnp.sum(
+            flash_attention(q, k, v, causal=True, interpret=False) ** 2
+        )
+
+    text = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        .lower(qkv, qkv, qkv).compile().as_text()
+    )
+    # forward, dq, dk/dv
+    assert text.count("tpu_custom_call") >= 3
+
+
+# -- the fused recurrent train steps -----------------------------------------
+
+
+@pytest.mark.parametrize(
+    "factory,kwargs",
+    [
+        (lstm_model, {"schedule": "layer"}),
+        (lstm_model, {"schedule": "stacked"}),
+        (gru_model, {}),
+    ],
+    ids=["lstm-layer", "lstm-stacked", "gru"],
+)
+def test_fused_recurrent_train_step_compiles(chip, factory, kwargs):
+    """One optimizer step of the fused scan at batch 512 x 64 x 50."""
+    spec = plant_spec(factory, **kwargs)
+    optimizer = spec.make_optimizer()
+    x = jax.ShapeDtypeStruct((BATCH, LOOKBACK, N_TAGS), jnp.float32)
+    y = jax.ShapeDtypeStruct((BATCH, N_TAGS), jnp.float32)
+    params = jax.eval_shape(
+        lambda: spec.module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, LOOKBACK, N_TAGS))
+        )
+    )
+    opt_state = jax.eval_shape(optimizer.init, params)
+
+    def train_step(params, opt_state, x, y):
+        def loss_fn(p):
+            out, penalty = spec.module.apply(p, x)
+            return jnp.mean((out - y) ** 2) + penalty
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return jax.tree.map(jnp.add, params, updates), opt_state, loss
+
+    compiled = jax.jit(train_step).lower(
+        *on_chip((params, opt_state, x, y), chip)
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+# -- the fleet programs ------------------------------------------------------
+
+
+def fleet_args(trainer, n_features, n_timesteps, sharding, n_machines=N_MACHINES):
+    """(params, opt_state, keys, X, y, w) shapes of an M-machine fleet."""
+    keys = jax.eval_shape(lambda: trainer.machine_keys(n_machines))
+    params = jax.eval_shape(
+        lambda: trainer.init_params(
+            trainer.machine_keys(n_machines), n_features
+        )
+    )
+    opt_state = jax.eval_shape(trainer.init_opt_state, params)
+    grid = jax.ShapeDtypeStruct(
+        (n_machines, n_timesteps, n_features), jnp.float32
+    )
+    w = jax.ShapeDtypeStruct((n_machines, n_timesteps), jnp.float32)
+    return on_chip((params, opt_state, keys, grid, grid, w), sharding)
+
+
+def test_fleet_epoch_program_compiles(chip):
+    """build-fleet's epoch program for the flagship bucket: 8 machines x
+    16,384 timesteps, batch 512, the quarantine guard on (the default)."""
+    trainer = FleetTrainer(plant_spec(), lookahead=0)
+    healthy = jax.ShapeDtypeStruct((N_MACHINES,), jnp.bool_, sharding=chip)
+    compiled = trainer._epoch_fn(
+        N_TIMESTEPS, BATCH, True, quarantine=True
+    ).lower(*fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip), healthy).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_chunked_epoch_program_compiles(chip):
+    """The fused multi-epoch program (``_fit_chunked``, epoch_chunk=3)."""
+    trainer = FleetTrainer(plant_spec(), lookahead=0, epoch_chunk=3)
+    healthy = jax.ShapeDtypeStruct((N_MACHINES,), jnp.bool_, sharding=chip)
+    epoch_ids = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=chip)
+    compiled = trainer._chunk_fn(
+        N_TIMESTEPS, BATCH, True, chunk_len=3, sample_cap=None,
+        with_val=False, val_lo=0, gated=False, track_best=False,
+        monitor_val=False, quarantine=True,
+    ).lower(
+        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip), epoch_ids, healthy
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_fleet_epoch_program_compiles_for_four_chips(topology):
+    """chip_smoke --chips 4: the same epoch program over the 2x2 host's
+    fleet mesh, two machines a chip — no cross-chip collective belongs in
+    it (machines are independent), and each chip holds a quarter."""
+    from gordo_tpu.parallel.mesh import fleet_sharding, get_device_mesh
+
+    mesh = get_device_mesh(devices=topology.devices)
+    sharding = fleet_sharding(mesh)
+    trainer = FleetTrainer(plant_spec(), lookahead=0, mesh=mesh)
+    # shapes only: init_params would device_put onto the described mesh
+    unsharded = FleetTrainer(plant_spec(), lookahead=0)
+    healthy = jax.ShapeDtypeStruct((N_MACHINES,), jnp.bool_, sharding=sharding)
+    compiled = trainer._epoch_fn(
+        N_TIMESTEPS, BATCH, True, quarantine=True
+    ).lower(
+        *fleet_args(unsharded, N_TAGS, N_TIMESTEPS, sharding), healthy
+    ).compile()
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "all-gather" not in text
+    single = 8.5e9  # the one-chip program's temp (M=8 on one device)
+    assert compiled.memory_analysis().temp_size_in_bytes < single / 2
+
+
+def test_fleet_predict_program_fits_hbm(chip):
+    """build-fleet scores every CV fold through ``FleetTrainer.predict``.
+    Chunked per MACHINE (8192 windows each) the flagship bucket asked for
+    16.06 GB of 15.75 GB and the chip's compiler refused it; the chunk is
+    now bounded per device."""
+    trainer = FleetTrainer(plant_spec(), lookahead=0)
+    params, _, _, X, _, _ = fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip)
+    chunk = trainer._predict_chunk(N_MACHINES, 8192)
+    assert chunk * N_MACHINES <= 8192
+    compiled = trainer._predict_fn(N_TIMESTEPS, chunk).lower(params, X).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_flash_fleet_epoch_program_compiles(chip, compiled_kernels):
+    """chip_smoke's phase 4: the kernel under FleetTrainer's vmap —
+    2-layer TransformerNet, d_model 256, 4 heads, lookback 1024."""
+    lookback, batch, n_features = 1024, 8, 16
+    spec = transformer_model(
+        n_features=n_features, lookback_window=lookback, d_model=256,
+        n_heads=4, n_layers=2, dropout=0.0, attention_impl="flash",
+    )
+    trainer = FleetTrainer(spec, lookahead=0)
+    n = lookback + 3 * batch - 1
+    healthy = jax.ShapeDtypeStruct((2,), jnp.bool_, sharding=chip)
+    text = trainer._epoch_fn(n, batch, True, quarantine=True).lower(
+        *fleet_args(trainer, n_features, n, chip, n_machines=2), healthy
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_fleet_scorer_predict_program_compiles(chip):
+    """run-server's scoring program at the shape the build exports and a
+    fleet POST dispatches: 8 machines x 256 rows."""
+    from gordo_tpu.models import LSTMAutoEncoder
+    from gordo_tpu.programs import ProgramCache
+    from gordo_tpu.server.fleet_serving import FleetScorer
+
+    X = np.random.default_rng(0).random((80, N_TAGS)).astype("float32")
+    estimator = LSTMAutoEncoder(
+        kind="lstm_model", lookback_window=LOOKBACK,
+        encoding_dim=list(ENC), encoding_func=["tanh"] * len(ENC),
+        decoding_dim=list(DEC), decoding_func=["tanh"] * len(DEC),
+        fused=True, epochs=1, batch_size=16,
+    )
+    estimator.fit(X, X.copy())
+    scorer = FleetScorer(
+        {f"m{i}": estimator for i in range(N_MACHINES)},
+        cache=ProgramCache("serving"),
+    )
+    (group,) = scorer._groups
+    batch = jax.ShapeDtypeStruct(
+        (N_MACHINES, 256, N_TAGS), jnp.float32, sharding=chip
+    )
+    compiled = group["apply"].lower(
+        on_chip(group["params"], chip), batch
+    ).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (N_MACHINES, 256 - LOOKBACK + 1, N_TAGS)

@@ -619,6 +619,33 @@ def test_run_server_cli_passes_concurrency_knobs(runner, monkeypatch):
     }
 
 
+@pytest.mark.parametrize(
+    "argv",
+    [
+        ["run-server", "--workers", "2"],
+        ["build-fleet", "--workers", "2", "[]", "unused-out"],
+    ],
+    ids=["run-server", "build-fleet"],
+)
+def test_local_workers_refused_off_cpu(runner, monkeypatch, argv):
+    """Off the CPU, N>1 local workers would each need the one chip: both
+    commands refuse at start with a usage error naming the reason —
+    decided from JAX_PLATFORMS alone, before anything is spawned."""
+    from gordo_tpu.builder import ledger as fleet_ledger
+    from gordo_tpu.server import app as server_app
+
+    def must_not_start(*args, **kwargs):
+        raise AssertionError("workers were started off the CPU")
+
+    monkeypatch.setattr(server_app, "run_server", must_not_start)
+    monkeypatch.setattr(fleet_ledger, "orchestrate", must_not_start)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    result = runner.invoke(gordo, argv)
+    assert result.exit_code == 2, result.output
+    assert "a chip belongs to one process at a time" in result.output
+    assert "JAX_PLATFORMS=cpu" in result.output
+
+
 def test_run_server_cli_passes_batching_knobs(runner, monkeypatch):
     """--batch-wait-ms/--queue-limit reach the server config intact."""
     captured = {}

@@ -39,8 +39,10 @@ REPO_ROOT = Path(__file__).parent.parent
 
 
 @pytest.fixture(autouse=True)
-def _isolate(monkeypatch):
-    """Fresh process-wide serving cache + fault registry per test."""
+def _isolate(monkeypatch, no_persistent_compile_cache):
+    """Fresh process-wide serving cache + fault registry per test, and
+    every export serializes a FRESH compile (never a persistent-cache
+    hit, whose XLA:CPU re-serialization does not execute)."""
     reset_serving_program_cache()
     faults.reset()
     yield
@@ -106,6 +108,27 @@ def test_aot_predictions_bit_identical_to_traced(estimators, exported_store):
     aot = scorer.predict(X)
     for name in traced:
         assert (traced[name] == aot[name]).all()
+
+
+def test_aot_executable_executes_on_multi_device_host(
+    estimators, exported_store, event_log
+):
+    """A loaded executable must EXECUTE, not merely load: the suite's
+    host has 8 devices, and an executable left to spread over all of
+    them fails every dispatch and silently retraces (bit-identical, so
+    only the fallback event shows it)."""
+    import jax
+
+    assert len(jax.devices()) > 1
+    scorer = FleetScorer(
+        estimators,
+        store=open_store(exported_store),
+        cache=ProgramCache("serving"),
+    )
+    scorer.predict(_predict_inputs(estimators))
+    hits = _events(event_log, "program_cache_hit")
+    assert [e["outcome"] for e in hits] == ["aot"]
+    assert _events(event_log, "program_cache_fallback") == []
 
 
 def test_warm_from_store_loads_only_matching_groups(
@@ -194,6 +217,33 @@ def test_corrupt_payload_falls_back_via_chaos_site(
     assert _events(event_log, "fault_injected")
     events = _events(event_log, "program_cache_fallback")
     assert events and events[-1]["outcome"] == "deserialize_error"
+
+
+def test_async_execute_failure_falls_back_to_retrace(
+    estimators, event_log, monkeypatch
+):
+    """Dispatch is asynchronous: an executable that loads but cannot run
+    (seen live: an XLA:CPU executable re-serialized after a persistent-
+    cache hit) reports it only when its result is awaited. That must land
+    on the ladder — execute_error + retrace — not fail the request in the
+    caller's device->host fetch."""
+
+    class FailsWhenAwaited:
+        def block_until_ready(self):
+            raise RuntimeError("Function fusion.3 not found")
+
+    X = _predict_inputs(estimators)
+    traced = FleetScorer(estimators, cache=ProgramCache("serving")).predict(X)
+    cache = ProgramCache("serving")
+    monkeypatch.setattr(
+        cache, "aot_program",
+        lambda key, store: lambda params, batch: FailsWhenAwaited(),
+    )
+    out = FleetScorer(estimators, cache=cache).predict(X)
+    for name in traced:
+        assert (traced[name] == out[name]).all()
+    events = _events(event_log, "program_cache_fallback")
+    assert [e["outcome"] for e in events] == ["execute_error"]
 
 
 def test_corrupt_attempts_limit_allows_reload(
@@ -344,7 +394,8 @@ def test_enable_compile_cache_emits_event_and_sizes(
     )
 
     cache_dir = tmp_path / "xla-cache"
-    enable_compile_cache(str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    enable_compile_cache()
     events = _events(event_log, "compile_cache_enabled")
     assert events and events[-1]["directory"] == str(cache_dir)
     assert compile_cache_dir() == str(cache_dir)
@@ -362,7 +413,8 @@ def test_builder_samples_compile_cache_gauge(tmp_path, monkeypatch):
     cache_dir = tmp_path / "xla-cache"
     os.makedirs(cache_dir)
     (cache_dir / "entry.bin").write_bytes(b"y" * 2048)
-    enable_compile_cache(str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    enable_compile_cache()
     assert FleetModelBuilder([])._sample_compile_cache() == 2048
     snapshot = get_registry().snapshot()
     series = snapshot["gordo_compile_cache_dir_bytes"]["series"]
@@ -393,6 +445,44 @@ def test_export_serving_programs_from_disk(tmp_path, estimators):
     store = open_store(tmp_path)
     assert store is not None
     assert len(store.keys()) == report["n_programs"]
+
+
+def test_export_over_a_warm_compile_cache_still_executes(
+    estimators, tmp_path, event_log
+):
+    """On XLA:CPU an executable that came from a persistent-cache HIT
+    re-serializes into a payload that loads and cannot execute — so a
+    second build on the same host shipped a store whose every program
+    fell back at dispatch. The export compiles past the cache there."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # this module runs with the persistent cache off; this test is about it
+    prior_floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()
+    try:
+        for build in ("cold", "warm"):
+            scorer = FleetScorer(estimators, cache=ProgramCache("serving"))
+            # a fresh jit handle per build, as a fresh process has
+            reset_serving_program_cache()
+            scorer.export_programs(
+                ProgramStore(store_directory(tmp_path / build))
+            )
+    finally:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prior_floor
+        )
+    served = FleetScorer(
+        estimators, store=open_store(tmp_path / "warm"),
+        cache=ProgramCache("serving"),
+    )
+    served.predict(_predict_inputs(estimators))
+    assert [
+        e["outcome"] for e in _events(event_log, "program_cache_hit")
+    ] == ["aot"]
+    assert _events(event_log, "program_cache_fallback") == []
 
 
 def test_export_row_buckets_env_knob(monkeypatch):

@@ -142,6 +142,18 @@ def test_flash_matches_dense(causal):
 
 
 @pytest.mark.slow
+def test_flash_unknown_backend_is_an_error(monkeypatch):
+    """Interpret mode comes from the backend with ONLY ``cpu`` mapping to
+    the interpreter: "not tpu, so interpret" let a chip under another
+    platform name run the interpreter silently. An unknown backend
+    raises; an explicit ``interpret=`` still decides for itself."""
+    q = jnp.ones((1, 8, 1, 8), dtype=jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "somechip")
+    with pytest.raises(ValueError, match="no kernel mode for backend 'somechip'"):
+        flash_attention(q, q, q)
+    assert flash_attention(q, q, q, interpret=True).shape == q.shape
+
+
 def test_flash_gradients_match_dense():
     q, k, v = (
         jnp.asarray(RNG.normal(size=(1, 24, 2, 8)), dtype=jnp.float32)

@@ -101,10 +101,6 @@ def test_concurrency_gate_releases_on_app_error():
 
 
 _MULTIWORKER_SCRIPT = """
-import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from gordo_tpu.utils import honor_jax_platforms_env
-honor_jax_platforms_env()
 from gordo_tpu.server.app import run_server
 run_server("127.0.0.1", {port}, workers=2, log_level="warning", threads=4)
 """

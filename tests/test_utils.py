@@ -81,114 +81,42 @@ def test_replace_non_ascii():
     assert replace_all_non_ascii_chars_with_default("plain") == "plain"
 
 
-def test_enable_compile_cache_env_resolution(monkeypatch, tmp_path):
-    """Explicit arg > GORDO_XLA_CACHE_DIR > tempdir default; empty string
-    disables without touching jax config."""
+def test_enable_compile_cache_env_resolution(monkeypatch):
+    """One cache, placed from outside: with JAX_COMPILATION_CACHE_DIR set
+    no directory is set in code (JAX read the variable itself); unset, the
+    directory is the fixed in-checkout <repo>/.jax_cache — never a temp
+    name."""
+    import os
+
     import jax
 
-    from gordo_tpu.utils import enable_compile_cache
+    from gordo_tpu.utils import compile_cache_dir, enable_compile_cache
+
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    updates = []
+    real_update = jax.config.update
+
+    def recording_update(name, value):
+        updates.append(name)
+        real_update(name, value)
 
     prior_dir = jax.config.jax_compilation_cache_dir
     prior_floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setattr(jax.config, "update", recording_update)
     try:
-        target = str(tmp_path / "cache-a")
-        enable_compile_cache(target)
-        assert jax.config.jax_compilation_cache_dir == target
-
-        env_target = str(tmp_path / "cache-b")
-        monkeypatch.setenv("GORDO_XLA_CACHE_DIR", env_target)
+        # conftest placed the session cache through the variable
+        placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
         enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == env_target
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
+        assert compile_cache_dir() == placed
+        assert jax.config.jax_compilation_cache_dir == placed
 
-        monkeypatch.setenv("GORDO_XLA_CACHE_DIR", "")
-        enable_compile_cache()  # disabled: must leave the previous setting
-        assert jax.config.jax_compilation_cache_dir == env_target
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        enable_compile_cache()
+        assert "jax_compilation_cache_dir" in updates
+        assert compile_cache_dir() == os.path.join(repo_root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == compile_cache_dir()
     finally:
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prior_floor
-        )
-
-
-def _assert_cache_default_skipped(monkeypatch, tmp_path):
-    """Helper: with tempdir redirected at tmp_path, the default-dir path
-    must leave jax's cache config untouched."""
-    import jax
-
-    from gordo_tpu.utils import enable_compile_cache
-
-    monkeypatch.delenv("GORDO_XLA_CACHE_DIR", raising=False)
-    monkeypatch.setattr("tempfile.gettempdir", lambda: str(tmp_path))
-    prior = jax.config.jax_compilation_cache_dir
-    sentinel = "/nonexistent-gordo-sentinel"
-    try:
-        jax.config.update("jax_compilation_cache_dir", sentinel)
-        enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == sentinel
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
-
-
-def _default_cache_dirname():
-    import os
-
-    from gordo_tpu.utils.utils import _host_cpu_fingerprint
-
-    return f"gordo_tpu_xla_cache_{os.getuid()}_{_host_cpu_fingerprint()}"
-
-
-def test_enable_compile_cache_skips_foreign_owned_default(monkeypatch, tmp_path):
-    """A default cache dir owned by another uid must disable the cache,
-    not deserialize foreign compiled executables. Simulated by patching
-    os.fstat (the dir is verified through an O_NOFOLLOW fd) so the branch
-    runs for any test uid."""
-    import os
-
-    real_fstat = os.fstat
-
-    def foreign_fstat(fd):
-        st = real_fstat(fd)
-        return os.stat_result((st.st_mode, st.st_ino, st.st_dev,
-                               st.st_nlink, 12345, 12345, st.st_size,
-                               st.st_atime, st.st_mtime, st.st_ctime))
-
-    monkeypatch.setattr("os.fstat", foreign_fstat)
-    _assert_cache_default_skipped(monkeypatch, tmp_path)
-
-
-def test_enable_compile_cache_rejects_symlinked_default(monkeypatch, tmp_path):
-    """An attacker-planted symlink at the default path must disable the
-    cache (O_NOFOLLOW refuses to open through the link, atomically with
-    the use — no lstat-then-use window)."""
-    target = tmp_path / "attacker-writable"
-    target.mkdir()
-    link = tmp_path / _default_cache_dirname()
-    link.symlink_to(target)
-    _assert_cache_default_skipped(monkeypatch, tmp_path)
-
-
-def test_default_cache_dir_is_fingerprinted_per_host_cpu(monkeypatch, tmp_path):
-    """The default dir embeds a host-CPU fingerprint: XLA:CPU persists AOT
-    executables for the compiling host's exact feature set, and a workspace
-    moved to a lesser CPU must get a FRESH cache dir, not load artifacts
-    that fault or hang (observed live: round-3 cache on a different host
-    wedged round-4 runs until cleared)."""
-    import jax
-
-    from gordo_tpu.utils import enable_compile_cache
-
-    monkeypatch.delenv("GORDO_XLA_CACHE_DIR", raising=False)
-    monkeypatch.setattr("tempfile.gettempdir", lambda: str(tmp_path))
-    prior = jax.config.jax_compilation_cache_dir
-    try:
-        enable_compile_cache()
-        configured = jax.config.jax_compilation_cache_dir
-        assert configured == str(tmp_path / _default_cache_dirname())
-        # a different host CPU must resolve to a different directory
-        monkeypatch.setattr(
-            "gordo_tpu.utils.utils._host_cpu_fingerprint", lambda: "deadbeef0123"
-        )
-        enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir != configured
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
+        real_update("jax_compilation_cache_dir", prior_dir)
+        real_update("jax_persistent_cache_min_compile_time_secs", prior_floor)
