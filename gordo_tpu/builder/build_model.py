@@ -32,7 +32,7 @@ from sklearn.pipeline import Pipeline
 from gordo_tpu import MAJOR_VERSION, MINOR_VERSION, __version__, serializer
 from gordo_tpu.data import _get_dataset
 from gordo_tpu.observability import tracing
-from gordo_tpu.observability.profiler import annotate, maybe_trace
+from gordo_tpu.observability.profiler import maybe_trace
 from gordo_tpu.machine import Machine
 from gordo_tpu.machine.metadata import (
     BuildMetadata,
@@ -158,8 +158,9 @@ class ModelBuilder:
 
     def _build(self) -> Tuple[BaseEstimator, Machine]:
         """Run the actual build (reference: build_model.py:160-303),
-        profiler-traced when GORDO_TPU_PROFILE_DIR is configured and
-        span-traced when GORDO_TPU_TRACE_LOG is."""
+        profiler-traced when GORDO_TPU_PROFILE_DIR is configured (its
+        spans then lie on that trace's host plane) and span-logged when
+        GORDO_TPU_TRACE_LOG is."""
         with maybe_trace(f"build-{self.machine.name}"), tracing.start_span(
             "build.machine", machine=self.machine.name
         ):
@@ -173,7 +174,7 @@ class ModelBuilder:
 
         dataset = _get_dataset(self.machine.dataset.to_dict())
         start = time.time()
-        with annotate("data-fetch"), tracing.start_span(
+        with tracing.start_span(
             "build.fetch", machine=self.machine.name
         ):
             X, y = dataset.get_data()
@@ -196,7 +197,7 @@ class ModelBuilder:
                 return model, machine
 
         start = time.time()
-        with annotate("fit"), tracing.start_span(
+        with tracing.start_span(
             "build.fit", machine=self.machine.name
         ):
             model.fit(X, y)
@@ -228,7 +229,7 @@ class ModelBuilder:
 
         # anomaly models own their CV (threshold derivation rides along)
         run = getattr(model, "cross_validate", None) or partial(cross_validate, model)
-        with annotate("cross-validation"), tracing.start_span(
+        with tracing.start_span(
             "build.cv", machine=self.machine.name
         ):
             cv = run(X=X, y=y, scoring=scorers, return_estimator=True, cv=splitter)

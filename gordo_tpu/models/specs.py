@@ -269,13 +269,17 @@ class FusedLSTMLayer(nn.Module):
             jnp.zeros((batch, h_dim), dtype=jnp.float32),
             jnp.zeros((batch, h_dim), dtype=jnp.float32),
         )
-        _, hs = jax.lax.scan(
-            step,
-            carry0,
-            z if self.time_major else z.swapaxes(0, 1),
-            unroll=max(1, int(self.unroll)),
-        )
-        hs = hs if self.time_major else hs.swapaxes(0, 1)
+        # a stable name for "the time scan of this layer" on a device trace
+        # (.../FusedLSTMLayer_k/scan/..., under transpose(jvp(...)) for the
+        # backward pass), with the layout swaps that feed and drain it
+        with jax.named_scope("scan"):
+            _, hs = jax.lax.scan(
+                step,
+                carry0,
+                z if self.time_major else z.swapaxes(0, 1),
+                unroll=max(1, int(self.unroll)),
+            )
+            hs = hs if self.time_major else hs.swapaxes(0, 1)
         return hs.astype(self.dtype)
 
 
@@ -332,13 +336,14 @@ class FusedGRULayer(nn.Module):
 
         batch = x.shape[1] if self.time_major else x.shape[0]
         h0 = jnp.zeros((batch, h_dim), dtype=jnp.float32)
-        _, hs = jax.lax.scan(
-            step,
-            h0,
-            z if self.time_major else z.swapaxes(0, 1),
-            unroll=max(1, int(self.unroll)),
-        )
-        hs = hs if self.time_major else hs.swapaxes(0, 1)
+        with jax.named_scope("scan"):  # see FusedLSTMLayer
+            _, hs = jax.lax.scan(
+                step,
+                h0,
+                z if self.time_major else z.swapaxes(0, 1),
+                unroll=max(1, int(self.unroll)),
+            )
+            hs = hs if self.time_major else hs.swapaxes(0, 1)
         return hs.astype(self.dtype)
 
 
@@ -499,9 +504,10 @@ class LSTMNet(nn.Module):
         else:
             init = tuple(jnp.zeros((b_dim, d), jnp.float32) for d in dims)
             step = gru_step
-        final, _ = jax.lax.scan(
-            step, init, z1, unroll=max(1, int(self.time_unroll))
-        )
+        with jax.named_scope("scan"):  # see FusedLSTMLayer
+            final, _ = jax.lax.scan(
+                step, init, z1, unroll=max(1, int(self.time_unroll))
+            )
         last = final[-1]
         h_last = last[1] if self.cell == "lstm" else last
         return h_last.astype(self.dtype)  # (batch, h_last)

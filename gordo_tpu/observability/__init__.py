@@ -13,9 +13,10 @@ modeling PR stands on.
 - :mod:`tracing` — dependency-light span layer with W3C ``traceparent``
   propagation client→server→fleet, JSONL span persistence, and
   Chrome-trace (Perfetto) export behind ``gordo-tpu trace``.
-- :mod:`profiler` — ``jax.profiler`` hooks (``maybe_trace`` /
-  ``annotate``) bridging spans onto the device timeline (promoted from
-  ``utils/tracing.py``, where a shim remains).
+- :mod:`profiler` — ``maybe_trace``: the operator's switch
+  (``GORDO_TPU_PROFILE_DIR``) that starts and stops a ``jax.profiler``
+  session around a region. Spans reach any such session's timeline
+  through :mod:`tracing` itself.
 - :mod:`device_memory` — HBM watermark sampling via
   ``device.memory_stats()``, degrading gracefully (null bytes) on CPU.
 - :mod:`prom_bridge` — optional export of the registry into a
@@ -64,7 +65,7 @@ from .events import (
     emit_event,
     read_events,
 )
-from .profiler import PROFILE_DIR_ENV_VAR, annotate, maybe_trace, profile_dir
+from .profiler import PROFILE_DIR_ENV_VAR, maybe_trace, profile_dir
 from .sampling import (
     PROFILE_HZ_ENV_VAR,
     PROFILE_OUT_ENV_VAR,
@@ -142,7 +143,6 @@ __all__ = [
     "emit_event",
     "read_events",
     "PROFILE_DIR_ENV_VAR",
-    "annotate",
     "maybe_trace",
     "profile_dir",
     "TRACE_ID_RESPONSE_HEADER",

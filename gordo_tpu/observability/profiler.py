@@ -1,35 +1,26 @@
 """
-Profiling/trace hooks — the TPU-native analogue of the reference's
-lightweight timing surface (SURVEY.md §5: Server-Timing headers and
-metadata-embedded durations, which this package also keeps). Promoted
-from ``gordo_tpu/utils/tracing.py`` (a re-export shim remains there)
-into the observability subsystem, next to the span layer
-(:mod:`gordo_tpu.observability.tracing`) whose dispatch spans call
-:func:`annotate` to land on the device timeline too.
+The operator's profiler switch — the TPU-native analogue of the
+reference's lightweight timing surface (SURVEY.md §5: Server-Timing
+headers and metadata-embedded durations, which this package also keeps).
 
 ``maybe_trace`` wraps a region in a ``jax.profiler`` trace when profiling
 is enabled, producing TensorBoard-loadable dumps (XLA op timelines, HBM
 usage) under ``<dir>/<name>-<timestamp>/``. Enable per-process with the
 ``GORDO_TPU_PROFILE_DIR`` env var or per-call with an explicit directory.
-
-``annotate`` adds named spans inside an active trace so builder phases
-(data fetch, CV folds, fit) and trainer dispatches are attributable on
-the timeline.
+It only starts and stops the profiler: the program's phases reach the
+timeline through :func:`gordo_tpu.observability.tracing.start_span`,
+which writes every span onto the host plane of whatever profiler session
+is active in the process, this one or anyone else's.
 """
 
 import contextlib
 import logging
 import os
-import threading
 import time
 
 logger = logging.getLogger(__name__)
 
 PROFILE_DIR_ENV_VAR = "GORDO_TPU_PROFILE_DIR"
-
-# set while a maybe_trace region is active, so annotate() works for both
-# env-var and explicit-directory tracing
-_active = threading.local()
 
 #: distinguishable "the profiler call failed" result (None is a valid
 #: return for start/stop)
@@ -77,39 +68,14 @@ def maybe_trace(name: str, directory: str = ""):
         )
         is not _FAILED
     )
-    if started:
-        _active.tracing = True
     try:
         yield
     finally:
-        if started:
-            _active.tracing = False
-            if (
-                _profiler_call(
-                    "stop jax profiler trace",
-                    lambda jax: jax.profiler.stop_trace(),
-                )
-                is not _FAILED
-            ):
-                logger.info("Wrote profiler trace to %s", target)
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """
-    Named span inside an active ``maybe_trace`` region. Cheap no-op when no
-    trace is active, and never breaks the annotated workload if the
-    profiler is unusable.
-    """
-    if not getattr(_active, "tracing", False):
-        yield
-        return
-    span = _profiler_call(
-        "annotate jax profiler trace",
-        lambda jax: jax.profiler.TraceAnnotation(name),
-    )
-    if span is _FAILED:
-        yield
-        return
-    with span:
-        yield
+        if started and (
+            _profiler_call(
+                "stop jax profiler trace",
+                lambda jax: jax.profiler.stop_trace(),
+            )
+            is not _FAILED
+        ):
+            logger.info("Wrote profiler trace to %s", target)

@@ -7,11 +7,13 @@ need — PAPERS.md arXiv:2502.06982).
 
 Design constraints, in order:
 
-1. **Strict no-op when disabled.** Tracing is on iff
+1. **Strict no-op when disabled.** Recording is on iff
    ``GORDO_TPU_TRACE_LOG`` points at a span JSONL file. Every span
-   entry point starts with exactly one ``os.environ`` dict lookup and
-   returns a process-wide singleton no-op span when it misses — the
-   same hot-path discipline PR 4 pinned for ``GORDO_FAULT_INJECT``.
+   entry point starts with exactly one ``os.environ`` dict lookup and,
+   when it misses and no ``jax.profiler`` session is active (one atomic
+   tested by ``TraceMe``), returns a process-wide singleton no-op span
+   — the same hot-path discipline PR 4 pinned for
+   ``GORDO_FAULT_INJECT``.
 2. **Dependency-light.** No OpenTelemetry; spans are plain dicts on a
    JSONL file next to the event log, ids are ``os.urandom`` hex,
    context is one :mod:`contextvars` variable.
@@ -20,6 +22,18 @@ Design constraints, in order:
    id>-<flags>``), so the ids survive any proxy that understands trace
    context, and the server can echo them (``X-Gordo-Trace-Id``) even
    when its own recording is off.
+4. **One seam, on the device trace's clock.** ``start_span`` is the only
+   way the program brackets a phase. While a ``jax.profiler`` session is
+   active in the process — however it was started (``maybe_trace``, a
+   benchmark harness, TensorBoard attached to a running server) and on
+   whichever thread — every span is also a ``TraceAnnotation`` on the
+   profiler's host plane, beside the device's operations, whether or
+   not the JSONL log is set. A recording span's event carries its
+   ``span_id`` / ``parent_span_id`` / ``trace_id``, so the JSONL record
+   and the profiler event of one span can be joined; with recording off,
+   nesting on the thread's line gives the parent. This module never
+   imports JAX (the client uses it): the annotation class is bound from
+   ``sys.modules`` once the process has imported it.
 
 Sampling: ``GORDO_TPU_TRACE_SAMPLE`` (float in [0, 1], default 1) is a
 head-sampling knob applied when a ROOT span mints a new trace id. The
@@ -37,6 +51,7 @@ import contextvars
 import json
 import logging
 import os
+import sys
 import threading
 import time
 import typing
@@ -236,6 +251,45 @@ def parse_traceparent(value: typing.Optional[str]) -> typing.Optional[SpanContex
     return SpanContext(trace_id, span_id, bool(flag_bits & _SAMPLED_FLAG))
 
 
+# -- the profiler's timeline -----------------------------------------------
+
+#: ``jax.profiler.TraceAnnotation``, bound on first use once the process
+#: has imported JAX (this module must not import it: the client never does)
+_annotation_class = None
+
+#: spans whose event on the profiler's timeline keeps an older name.
+#: ``chipbench/drivers/fit_loop.py`` (``Driver.SPANS``) reads
+#: "train-dispatch" and only a ``benchmark`` PR may edit it; when it reads
+#: the catalogue's name (docs/observability.md) this table goes.
+_TIMELINE_NAMES = {"train.dispatch": "train-dispatch"}
+
+
+def _timeline_active() -> bool:
+    """Is a ``jax.profiler`` session recording in this process? One dict
+    lookup until JAX is imported, then one test of ``TraceMe``'s atomic."""
+    global _annotation_class
+    annotation = _annotation_class
+    if annotation is None:
+        profiler = sys.modules.get("jax.profiler")
+        annotation = _annotation_class = getattr(
+            profiler, "TraceAnnotation", None
+        )
+    return annotation is not None and annotation.is_enabled()
+
+
+def _open_timeline_event(name: str, **ids):
+    """An entered ``TraceAnnotation`` for the span, or None where the
+    profiler cannot annotate: that must never break the bracketed
+    workload. Call only after :func:`_timeline_active` said yes."""
+    try:
+        event = _annotation_class(_TIMELINE_NAMES.get(name, name), **ids)
+        event.__enter__()
+        return event
+    except Exception:
+        logger.warning("Could not annotate the profiler trace", exc_info=True)
+        return None
+
+
 # -- span lifecycle --------------------------------------------------------
 
 
@@ -268,9 +322,10 @@ def _begin_span(
 
 class _NoopSpanContextManager:
     """The reusable disabled-path context manager: ``start_span`` with
-    tracing off costs one env dict lookup and returns this singleton —
-    no generator, no per-call allocation (beyond the call's own
-    kwargs), no contextvar touch."""
+    no span log and no profiler session costs one env dict lookup and
+    one test of the profiler's atomic, and returns this singleton — no
+    generator, no per-call allocation (beyond the call's own kwargs), no
+    contextvar touch."""
 
     __slots__ = ()
 
@@ -284,8 +339,30 @@ class _NoopSpanContextManager:
 _NOOP_CM = _NoopSpanContextManager()
 
 
+class _TimelineSpanContextManager:
+    """A span with recording off while a profiler session is active: an
+    event on the profiler's timeline and nothing else (the body still
+    gets :data:`NOOP_SPAN`; the contextvar is untouched)."""
+
+    __slots__ = ("_name", "_event")
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        self._event = _open_timeline_event(self._name)
+        return NOOP_SPAN
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._event is not None:
+            self._event.__exit__(exc_type, exc, tb)
+        return False
+
+
 class _SpanContextManager:
-    __slots__ = ("_name", "_parent", "_attributes", "_path", "_span", "_token")
+    __slots__ = (
+        "_name", "_parent", "_attributes", "_path", "_span", "_token", "_event",
+    )
 
     def __init__(self, name, parent, attributes, path):
         self._name = name
@@ -297,10 +374,20 @@ class _SpanContextManager:
         span = _begin_span(self._name, self._parent, self._attributes)
         self._span = span
         self._token = _CURRENT.set(span)
+        self._event = None
+        if _timeline_active():
+            ids = {}
+            if span.recording:
+                ids = {"span_id": span.span_id, "trace_id": span.trace_id}
+                if span.parent_span_id:
+                    ids["parent_span_id"] = span.parent_span_id
+            self._event = _open_timeline_event(span.name, **ids)
         return span
 
     def __exit__(self, exc_type, exc, tb):
         span = self._span
+        if self._event is not None:
+            self._event.__exit__(exc_type, exc, tb)
         _CURRENT.reset(self._token)
         if span.recording:
             if exc is not None:
@@ -314,9 +401,13 @@ def start_span(name: str, parent=_USE_CURRENT, **attributes):
     """
     Open a span around the ``with`` body and make it the current span.
 
-    - disabled (``GORDO_TPU_TRACE_LOG`` unset): one dict lookup, then
-      the process-wide no-op context manager yielding :data:`NOOP_SPAN`;
-      the contextvar is never touched.
+    - disabled (``GORDO_TPU_TRACE_LOG`` unset, no profiler session): one
+      dict lookup and one test of the profiler's atomic, then the
+      process-wide no-op context manager yielding :data:`NOOP_SPAN`; the
+      contextvar is never touched.
+    - a ``jax.profiler`` session active in the process: the span is also
+      an event of its name on the profiler's host plane, on the thread
+      that opened it, with or without the log.
     - ``parent``: a :class:`Span` / :class:`SpanContext` to attach under
       (the cross-thread handoff — contextvars do not follow
       ``ThreadPoolExecutor`` workers), ``None`` to force a new root, or
@@ -330,6 +421,8 @@ def start_span(name: str, parent=_USE_CURRENT, **attributes):
     """
     path = os.environ.get(TRACE_LOG_ENV_VAR)
     if not path:
+        if _timeline_active():
+            return _TimelineSpanContextManager(name)
         return _NOOP_CM
     return _SpanContextManager(name, parent, attributes, path)
 
@@ -629,11 +722,14 @@ def summarize_spans(records: typing.Sequence[dict], top: int = 5) -> str:
 
 def measure_overhead(samples: int = 2000) -> dict:
     """
-    Nanoseconds per :func:`start_span` enter/exit in the three regimes —
-    disabled (the strict no-op), enabled-but-sampled-out, and enabled
-    with a real JSONL write — so benchmarks can report the cost tracing
-    adds per request/phase and the sampling default is justified by a
-    number rather than vibes.
+    Nanoseconds per :func:`start_span` enter/exit in each regime —
+    disabled (no log, no profiler session: the strict no-op), a profiler
+    session only (the span is a ``TraceAnnotation`` and nothing else;
+    None where the process has not imported JAX or a session is already
+    running), enabled-but-sampled-out, and enabled with a real JSONL
+    write — so benchmarks can report the cost tracing adds per
+    request/phase and the sampling default is justified by a number
+    rather than vibes.
 
     Measures the REAL entry path (env lookup included), so it mutates
     the process-wide tracing env vars while running: any span another
@@ -655,9 +751,26 @@ def measure_overhead(samples: int = 2000) -> dict:
                 pass
         return (time.perf_counter() - start) / samples * 1e9
 
+    def _time_under_profiler(directory: str) -> typing.Optional[float]:
+        jax = sys.modules.get("jax")
+        if jax is None or _timeline_active():
+            return None
+        try:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0  # the host's TraceMe events only
+            jax.profiler.start_trace(directory, profiler_options=options)
+        except Exception:
+            logger.warning("Could not start a profiler session", exc_info=True)
+            return None
+        try:
+            return _time_loop()
+        finally:
+            jax.profiler.stop_trace()
+
     try:
         disabled = _time_loop()
         with tempfile.TemporaryDirectory() as tmp:
+            profiler_only = _time_under_profiler(os.path.join(tmp, "profile"))
             os.environ[TRACE_LOG_ENV_VAR] = os.path.join(tmp, "spans.jsonl")
             os.environ[TRACE_SAMPLE_ENV_VAR] = "0"
             sampled_out = _time_loop()
@@ -672,6 +785,9 @@ def measure_overhead(samples: int = 2000) -> dict:
     return {
         "samples": samples,
         "disabled_ns_per_span": round(disabled, 1),
+        "profiler_only_ns_per_span": (
+            None if profiler_only is None else round(profiler_only, 1)
+        ),
         "sampled_out_ns_per_span": round(sampled_out, 1),
         "enabled_ns_per_span": round(enabled, 1),
     }

@@ -23,7 +23,10 @@ Within one machine the epoch runs exactly like the single-model path
 minibatches, windowed gathers for sequence models.
 """
 
+import collections
+import contextlib
 import dataclasses
+import functools
 import logging
 import math
 import time
@@ -40,7 +43,6 @@ from gordo_tpu.models.specs import (
     per_sample_loss,
 )
 from gordo_tpu.observability import (
-    annotate,
     attribution,
     emit_event,
     get_registry,
@@ -121,6 +123,51 @@ def host_fetch(x):
 
         return multihost_utils.process_allgather(x, tiled=True)
     return jax.device_get(x)
+
+
+class _FitPhases:
+    """
+    What one fit measures at its own host-side boundaries, tracing on or
+    off: ``time.perf_counter`` pairs summed per phase (``prepare_s``,
+    ``decide_s``, ``checkpoint_s``, ``collect_s``, ``report_s``; each
+    stands beside the ``train.*`` span of the same phase) and the count
+    and bytes of its device->host fetches, booked where each happens.
+    """
+
+    def __init__(self):
+        self.seconds = collections.defaultdict(float)
+        self.n_host_syncs = 0
+        self.host_fetch_bytes = 0
+
+    @contextlib.contextmanager
+    def timed(self, key: str):
+        start = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.seconds[key] += time.perf_counter() - start
+
+    def fetched(self, tree):
+        """Book one ``host_fetch`` by what it brought; returns ``tree``."""
+        self.n_host_syncs += 1
+        self.host_fetch_bytes += sum(
+            np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree)
+        )
+        return tree
+
+
+def _under_fit_span(fit):
+    """Run ``FleetTrainer.fit`` under its root span, ``train.fit``."""
+
+    @functools.wraps(fit)
+    def traced_fit(self, data, keys, epochs=1, batch_size=32, *args, **kwargs):
+        with tracing.start_span(
+            "train.fit", n_machines=len(keys), epochs=epochs,
+            batch_size=batch_size, epoch_chunk=self.epoch_chunk,
+        ):
+            return fit(self, data, keys, epochs, batch_size, *args, **kwargs)
+
+    return traced_fit
 
 
 @dataclasses.dataclass
@@ -593,24 +640,32 @@ class FleetTrainer:
             healthy = _extras.pop(0) if quarantine else None
             inj_flag = _extras.pop(0) if inject else None
             fm = _extras.pop(0) if masked else None  # (f_out,) column mask
-            wb_all = sample_weights(wi)            # (n_samples,)
-            real = wb_all > 0
-            if shuffle:
-                noise = jax.random.uniform(key, (n_samples,))
-                sort_key = jnp.where(real, noise, 2.0 + noise)
-            else:
-                # stable: real samples keep their time order up front.
-                # int32 keys: float32 arange collides above 2^24 samples,
-                # which could misplace a real sample past the scan cap.
-                ar = jnp.arange(n_samples, dtype=jnp.int32)
-                sort_key = jnp.where(real, ar, n_samples + ar)
-            order = jnp.argsort(sort_key).astype(jnp.int32)
-            if n_pad > n_samples:
-                order = jnp.concatenate(
-                    [order, jnp.zeros(n_pad - n_samples, dtype=jnp.int32)]
-                )
-            sel_all = order[:n_pad].reshape(n_batches, batch_size)
-            pm_all = jnp.asarray(pm_all_np)
+            # The trainer's own work carries stable scope names
+            # (jax.named_scope: metadata only, no arithmetic), so that a
+            # device trace can sum it by what it is and not by fusion.237:
+            # fleet.order, fleet.step > fleet.gather, fleet.loss_grad,
+            # fleet.optimizer, then fleet.guard (docs/observability.md;
+            # chipbench/scopes.json)
+            with jax.named_scope("fleet.order"):
+                wb_all = sample_weights(wi)            # (n_samples,)
+                real = wb_all > 0
+                if shuffle:
+                    noise = jax.random.uniform(key, (n_samples,))
+                    sort_key = jnp.where(real, noise, 2.0 + noise)
+                else:
+                    # stable: real samples keep their time order up front.
+                    # int32 keys: float32 arange collides above 2^24
+                    # samples, which could misplace a real sample past the
+                    # scan cap.
+                    ar = jnp.arange(n_samples, dtype=jnp.int32)
+                    sort_key = jnp.where(real, ar, n_samples + ar)
+                order = jnp.argsort(sort_key).astype(jnp.int32)
+                if n_pad > n_samples:
+                    order = jnp.concatenate(
+                        [order, jnp.zeros(n_pad - n_samples, dtype=jnp.int32)]
+                    )
+                sel_all = order[:n_pad].reshape(n_batches, batch_size)
+                pm_all = jnp.asarray(pm_all_np)
 
             def loss_fn(p, xb, yb, wb, dropout_key):
                 out, penalty = module.apply(
@@ -629,58 +684,69 @@ class FleetTrainer:
             def step(carry, batch):
                 p, o = carry
                 sel, pm, idx = batch
-                xb, yb = gather(Xi, yi, sel)
-                wb = wb_all[sel] * pm
+                with jax.named_scope("fleet.gather"):
+                    xb, yb = gather(Xi, yi, sel)
+                    wb = wb_all[sel] * pm
                 dkey = jax.random.fold_in(key, idx)
-                (_, loss_sum), grads = grad_fn(p, xb, yb, wb, dkey)
-                updates, new_o = optimizer.update(grads, o, p)
-                new_p = jax.tree.map(lambda a, u: a + u, p, updates)
-                # an all-padding batch must be a no-op, not a zero-gradient
-                # optimizer step (momentum decay / penalty gradients would
-                # still move the params)
-                has_real = jnp.sum(wb) > 0
-                p = jax.tree.map(
-                    lambda new, old: jnp.where(has_real, new, old), new_p, p
-                )
-                o = jax.tree.map(
-                    lambda new, old: jnp.where(has_real, new, old), new_o, o
-                )
+                # the model's own Flax scopes nest under this one, the
+                # backward pass under transpose(jvp(...))
+                with jax.named_scope("fleet.loss_grad"):
+                    (_, loss_sum), grads = grad_fn(p, xb, yb, wb, dkey)
+                with jax.named_scope("fleet.optimizer"):
+                    updates, new_o = optimizer.update(grads, o, p)
+                    new_p = jax.tree.map(lambda a, u: a + u, p, updates)
+                    # an all-padding batch must be a no-op, not a
+                    # zero-gradient optimizer step (momentum decay / penalty
+                    # gradients would still move the params)
+                    has_real = jnp.sum(wb) > 0
+                    p = jax.tree.map(
+                        lambda new, old: jnp.where(has_real, new, old), new_p, p
+                    )
+                    o = jax.tree.map(
+                        lambda new, old: jnp.where(has_real, new, old), new_o, o
+                    )
                 return (p, o), (loss_sum, jnp.sum(wb))
 
             step_ids = jnp.arange(n_batches, dtype=jnp.int32)
-            (new_params, new_opt), (loss_sums, w_sums) = jax.lax.scan(
-                step,
-                (params, opt_state),
-                (sel_all, pm_all, step_ids),
-                unroll=min(self.scan_unroll, n_batches),
-            )
-            epoch_loss = jnp.sum(loss_sums) / jnp.maximum(jnp.sum(w_sums), 1.0)
-            if inject:
-                # the train:nan fault seam: poison this machine's epoch
-                # loss so the guard below sees exactly what a real
-                # divergence produces
-                epoch_loss = jnp.where(inj_flag, jnp.nan, epoch_loss)
-            keep = active > 0.5 if gated else None
-            healthy_out = None
-            if quarantine:
-                finite = jnp.isfinite(epoch_loss)
-                for leaf in jax.tree.leaves(new_params):
-                    finite = finite & jnp.all(jnp.isfinite(leaf))
-                healthy_out = healthy & finite
-                keep = healthy_out if keep is None else keep & healthy_out
-            if keep is not None:
-                params = jax.tree.map(
-                    lambda new, old: jnp.where(keep, new, old),
-                    new_params,
-                    params,
+            # fleet.step holds what the step loop runs beside the named
+            # parts of a step: the loop's own plumbing and the buffers XLA
+            # makes for it (on the chip, the zero-fill of the model scans'
+            # stacked outputs carries the step body's path and no more)
+            with jax.named_scope("fleet.step"):
+                (new_params, new_opt), (loss_sums, w_sums) = jax.lax.scan(
+                    step,
+                    (params, opt_state),
+                    (sel_all, pm_all, step_ids),
+                    unroll=min(self.scan_unroll, n_batches),
                 )
-                opt_state = jax.tree.map(
-                    lambda new, old: jnp.where(keep, new, old),
-                    new_opt,
-                    opt_state,
-                )
-            else:
-                params, opt_state = new_params, new_opt
+            with jax.named_scope("fleet.guard"):
+                epoch_loss = jnp.sum(loss_sums) / jnp.maximum(jnp.sum(w_sums), 1.0)
+                if inject:
+                    # the train:nan fault seam: poison this machine's epoch
+                    # loss so the guard below sees exactly what a real
+                    # divergence produces
+                    epoch_loss = jnp.where(inj_flag, jnp.nan, epoch_loss)
+                keep = active > 0.5 if gated else None
+                healthy_out = None
+                if quarantine:
+                    finite = jnp.isfinite(epoch_loss)
+                    for leaf in jax.tree.leaves(new_params):
+                        finite = finite & jnp.all(jnp.isfinite(leaf))
+                    healthy_out = healthy & finite
+                    keep = healthy_out if keep is None else keep & healthy_out
+                if keep is not None:
+                    params = jax.tree.map(
+                        lambda new, old: jnp.where(keep, new, old),
+                        new_params,
+                        params,
+                    )
+                    opt_state = jax.tree.map(
+                        lambda new, old: jnp.where(keep, new, old),
+                        new_opt,
+                        opt_state,
+                    )
+                else:
+                    params, opt_state = new_params, new_opt
             if quarantine:
                 return params, opt_state, epoch_loss, healthy_out
             return params, opt_state, epoch_loss
@@ -770,24 +836,28 @@ class FleetTrainer:
 
             def one_chunk(args):
                 sel, pm = args
-                if windowed:
-                    rows = sel[:, None] + jnp.arange(lb, dtype=jnp.int32)[None, :]
-                    xb = Xi[rows]
-                    tgt = sel + (lb - 1 + la)
-                    yb = yi[tgt]
-                    wb = jnp.min(vi[rows], axis=1) * vi[tgt]
-                else:
-                    xb = Xi[sel]
-                    yb = yi[sel]
-                    wb = vi[sel]
-                wb = wb * pm
-                out, _ = module.apply(params, xb)
-                per = (
-                    masked_per_sample_loss(loss_name, out, yb, fm)
-                    if masked
-                    else per_sample_loss(loss_name, out, yb)
-                )
-                return jnp.sum(per * wb), jnp.sum(wb)
+                with jax.named_scope("fleet.gather"):
+                    if windowed:
+                        rows = (
+                            sel[:, None] + jnp.arange(lb, dtype=jnp.int32)[None, :]
+                        )
+                        xb = Xi[rows]
+                        tgt = sel + (lb - 1 + la)
+                        yb = yi[tgt]
+                        wb = jnp.min(vi[rows], axis=1) * vi[tgt]
+                    else:
+                        xb = Xi[sel]
+                        yb = yi[sel]
+                        wb = vi[sel]
+                    wb = wb * pm
+                with jax.named_scope("fleet.val_loss"):
+                    out, _ = module.apply(params, xb)
+                    per = (
+                        masked_per_sample_loss(loss_name, out, yb, fm)
+                        if masked
+                        else per_sample_loss(loss_name, out, yb)
+                    )
+                    return jnp.sum(per * wb), jnp.sum(wb)
 
             sums, ws = jax.lax.map(one_chunk, (sel_all, pm_all))
             return jnp.sum(sums) / jnp.maximum(jnp.sum(ws), 1.0)
@@ -1084,6 +1154,7 @@ class FleetTrainer:
         )
 
     # -- public API ------------------------------------------------------
+    @_under_fit_span
     def fit(
         self,
         data: StackedData,
@@ -1162,152 +1233,173 @@ class FleetTrainer:
         training loss regardless (Keras ``monitor="loss"``).
         """
         fit_start = time.perf_counter()
-        if shuffle is None:
-            shuffle = not self.spec.windowed
-        if not 0.0 <= float(validation_split) < 1.0:
-            raise ValueError(
-                f"validation_split must be in [0, 1), got {validation_split}"
-            )
-        data = self.shard_data(data)
-        w = data.sample_weight
-        # padded-policy buckets carry a per-machine output-column mask;
-        # None (every exact-policy fit) keeps the historical unmasked
-        # programs bit-identically
-        fmask = data.feature_out_weight
-        masked = fmask is not None
-        if masked and self.broadcast_data:
-            raise ValueError(
-                "broadcast_data fleets share one dataset and cannot take "
-                "per-machine feature_out_weight masks"
-            )
-        if extra_weight is not None:
-            w = w * self._shard(jnp.asarray(extra_weight))
-        # the ONE device->host weight transfer per fit: the validation
-        # split and the sample cap both work from this copy
-        w_host = np.asarray(host_fetch(w), dtype=np.float64)
-
-        val_w = None
-        has_val = None
-        val_lo = 0
-        self.val_losses_: Optional[np.ndarray] = None
-        if validation_split > 0.0:
-            # computed from the EFFECTIVE weights so a CV fold's extra
-            # mask shrinks the split's base, exactly like a solo fold fit
-            # on that fold's rows would
-            train_mask, val_w, has_val, val_lo, train_mask_host = (
-                self._validation_masks(
-                    w_host, data.n_timesteps, float(validation_split)
+        phases = _FitPhases()
+        with phases.timed("prepare_s"), tracing.start_span("train.prepare"):
+            if shuffle is None:
+                shuffle = not self.spec.windowed
+            if not 0.0 <= float(validation_split) < 1.0:
+                raise ValueError(
+                    f"validation_split must be in [0, 1), got {validation_split}"
                 )
+            data = self.shard_data(data)
+            w = data.sample_weight
+            # padded-policy buckets carry a per-machine output-column mask;
+            # None (every exact-policy fit) keeps the historical unmasked
+            # programs bit-identically
+            fmask = data.feature_out_weight
+            masked = fmask is not None
+            if masked and self.broadcast_data:
+                raise ValueError(
+                    "broadcast_data fleets share one dataset and cannot take "
+                    "per-machine feature_out_weight masks"
+                )
+            if extra_weight is not None:
+                w = w * self._shard(jnp.asarray(extra_weight))
+            # the ONE device->host weight transfer per fit: the validation
+            # split and the sample cap both work from this copy
+            w_host = np.asarray(phases.fetched(host_fetch(w)), dtype=np.float64)
+
+            val_w = None
+            has_val = None
+            val_lo = 0
+            self.val_losses_: Optional[np.ndarray] = None
+            if validation_split > 0.0:
+                # computed from the EFFECTIVE weights so a CV fold's extra
+                # mask shrinks the split's base, exactly like a solo fold fit
+                # on that fold's rows would
+                train_mask, val_w, has_val, val_lo, train_mask_host = (
+                    self._validation_masks(
+                        w_host, data.n_timesteps, float(validation_split)
+                    )
+                )
+                w = w * train_mask
+                w_host = w_host * train_mask_host
+            monitor_val = (
+                val_w is not None
+                if early_stopping_on_val is None
+                else bool(early_stopping_on_val) and val_w is not None
             )
-            w = w * train_mask
-            w_host = w_host * train_mask_host
-        monitor_val = (
-            val_w is not None
-            if early_stopping_on_val is None
-            else bool(early_stopping_on_val) and val_w is not None
-        )
 
-        if params is None:
-            params = self.init_params(keys, data.X.shape[-1])
-        if opt_state is None:
-            opt_state = self.init_opt_state(params)
-        keys = self._shard(jnp.asarray(keys))
+            if params is None:
+                params = self.init_params(keys, data.X.shape[-1])
+            if opt_state is None:
+                opt_state = self.init_opt_state(params)
+            keys = self._shard(jnp.asarray(keys))
 
-        early_stopping = early_stopping_patience is not None
-        m = len(keys)  # the fleet axis (== data.n_machines unless broadcast)
-        quarantine = self.quarantine_nonfinite
-        # the train:nan fault seam, resolved ONCE per fit: None unless a
-        # matching GORDO_FAULT_INJECT spec targets this fleet (and then
-        # an ((M,) mask, epoch) pair baked into a distinct program)
-        inj = _faults.train_nan_injection(machine_names, m, sites=self.fault_sites)
-        healthy_np = np.ones(m, dtype=bool)
-        self.healthy_: Optional[np.ndarray] = None
-        self.quarantine_epoch_: Optional[np.ndarray] = None
-        self.healthy_history_: Optional[np.ndarray] = None
-        if has_val is not None and has_val.shape[0] != m:
-            # broadcast_data: masks are per weight ROW (the one shared
-            # dataset), but monitored metrics and val columns are per
-            # MACHINE — expand so boolean indexing lines up
-            has_val = np.repeat(has_val, m)
-        if early_stopping:
-            es_state = {
-                "best": np.full(m, np.inf, dtype=np.float64),
-                "wait": np.zeros(m, dtype=np.int64),
-                "active": np.ones(m, dtype=bool),
-                "last_loss": np.zeros(m, dtype=np.float64),
-            }
-            es_stop_at = max(int(early_stopping_patience), 1)
-            es_delta = abs(float(early_stopping_min_delta))
-
-        start_epoch = 0
-        if checkpointer is not None and checkpointer.latest_epoch() is not None:
-            extra_template: dict = {}
-            if quarantine:
-                extra_template["healthy"] = healthy_np
+            early_stopping = early_stopping_patience is not None
+            m = len(keys)  # the fleet axis (== data.n_machines unless broadcast)
+            quarantine = self.quarantine_nonfinite
+            # the train:nan fault seam, resolved ONCE per fit: None unless a
+            # matching GORDO_FAULT_INJECT spec targets this fleet (and then
+            # an ((M,) mask, epoch) pair baked into a distinct program)
+            inj = _faults.train_nan_injection(machine_names, m, sites=self.fault_sites)
+            healthy_np = np.ones(m, dtype=bool)
+            self.healthy_: Optional[np.ndarray] = None
+            self.quarantine_epoch_: Optional[np.ndarray] = None
+            self.healthy_history_: Optional[np.ndarray] = None
+            if has_val is not None and has_val.shape[0] != m:
+                # broadcast_data: masks are per weight ROW (the one shared
+                # dataset), but monitored metrics and val columns are per
+                # MACHINE — expand so boolean indexing lines up
+                has_val = np.repeat(has_val, m)
             if early_stopping:
-                extra_template.update(es_state)
-            if extra_template:
-                params, opt_state, done, restored_extra = (
-                    checkpointer.restore_with_extra(
-                        params, opt_state, extra_template,
-                        # a pre-quarantine ES checkpoint lacks "healthy";
-                        # its ES state must still restore
-                        optional_extra_keys=("healthy",),
+                es_state = {
+                    "best": np.full(m, np.inf, dtype=np.float64),
+                    "wait": np.zeros(m, dtype=np.int64),
+                    "active": np.ones(m, dtype=bool),
+                    "last_loss": np.zeros(m, dtype=np.float64),
+                }
+                es_stop_at = max(int(early_stopping_patience), 1)
+                es_delta = abs(float(early_stopping_min_delta))
+
+            start_epoch = 0
+            if checkpointer is not None and checkpointer.latest_epoch() is not None:
+                extra_template: dict = {}
+                if quarantine:
+                    extra_template["healthy"] = healthy_np
+                if early_stopping:
+                    extra_template.update(es_state)
+                if extra_template:
+                    params, opt_state, done, restored_extra = (
+                        checkpointer.restore_with_extra(
+                            params, opt_state, extra_template,
+                            # a pre-quarantine ES checkpoint lacks "healthy";
+                            # its ES state must still restore
+                            optional_extra_keys=("healthy",),
+                        )
                     )
+                    if restored_extra is not None:
+                        restored_extra = {
+                            k: np.asarray(v) for k, v in restored_extra.items()
+                        }
+                        restored_healthy = restored_extra.pop("healthy", None)
+                        if quarantine and restored_healthy is not None:
+                            healthy_np = restored_healthy.astype(bool)
+                    if early_stopping and restored_extra and "active" in restored_extra:
+                        es_state = restored_extra
+                        es_state["active"] = es_state["active"].astype(bool)
+                    elif early_stopping:
+                        # no (or healthy-only) extra: a checkpoint from a
+                        # plain fit or an older layout
+                        logger.warning(
+                            "Resuming an early-stopping fleet fit without saved "
+                            "early-stop state (older checkpoint?): stopped "
+                            "machines will briefly reactivate"
+                        )
+                else:
+                    params, opt_state, done = checkpointer.restore(params, opt_state)
+                start_epoch = done + 1
+                logger.info("Resuming fleet fit at epoch %d/%d", start_epoch, epochs)
+                emit_event(
+                    "fit_resume", path="fleet", start_epoch=start_epoch, epochs=epochs
                 )
-                if restored_extra is not None:
-                    restored_extra = {
-                        k: np.asarray(v) for k, v in restored_extra.items()
-                    }
-                    restored_healthy = restored_extra.pop("healthy", None)
-                    if quarantine and restored_healthy is not None:
-                        healthy_np = restored_healthy.astype(bool)
-                if early_stopping and restored_extra and "active" in restored_extra:
-                    es_state = restored_extra
-                    es_state["active"] = es_state["active"].astype(bool)
-                elif early_stopping:
-                    # no (or healthy-only) extra: a checkpoint from a
-                    # plain fit or an older layout
-                    logger.warning(
-                        "Resuming an early-stopping fleet fit without saved "
-                        "early-stop state (older checkpoint?): stopped "
-                        "machines will briefly reactivate"
+
+            if self.broadcast_data:
+                if data.n_machines != 1:
+                    raise ValueError(
+                        "broadcast_data expects a single-machine StackedData "
+                        f"(shared by all fleet members), got M={data.n_machines}"
                     )
+                if w.shape[0] != 1:
+                    # e.g. a per-machine (M, n) extra_weight: the shared-data
+                    # epoch takes ONE weight row; silently using row 0 would
+                    # train every member with machine 0's mask
+                    raise ValueError(
+                        "broadcast_data cannot take per-machine weights "
+                        f"(got weight shape {w.shape}); weights must be (1, n)"
+                    )
+                X_arg, y_arg, w_arg = data.X[0], data.y[0], w[0]
+                val_arg = val_w[0] if val_w is not None else None
             else:
-                params, opt_state, done = checkpointer.restore(params, opt_state)
-            start_epoch = done + 1
-            logger.info("Resuming fleet fit at epoch %d/%d", start_epoch, epochs)
-            emit_event(
-                "fit_resume", path="fleet", start_epoch=start_epoch, epochs=epochs
-            )
+                X_arg, y_arg, w_arg = data.X, data.y, w
+                val_arg = val_w
 
-        if self.broadcast_data:
-            if data.n_machines != 1:
-                raise ValueError(
-                    "broadcast_data expects a single-machine StackedData "
-                    f"(shared by all fleet members), got M={data.n_machines}"
-                )
-            if w.shape[0] != 1:
-                # e.g. a per-machine (M, n) extra_weight: the shared-data
-                # epoch takes ONE weight row; silently using row 0 would
-                # train every member with machine 0's mask
-                raise ValueError(
-                    "broadcast_data cannot take per-machine weights "
-                    f"(got weight shape {w.shape}); weights must be (1, n)"
-                )
-            X_arg, y_arg, w_arg = data.X[0], data.y[0], w[0]
-            val_arg = val_w[0] if val_w is not None else None
-        else:
-            X_arg, y_arg, w_arg = data.X, data.y, w
-            val_arg = val_w
+            if self.broadcast_data:
+                # every fleet member trains on the one shared dataset
+                rows_per_machine = np.full(m, int((w_host > 0).sum()), dtype=np.int64)
+            else:
+                rows_per_machine = (w_host > 0).sum(axis=1).astype(np.int64)
+            sample_cap = self._sample_cap(w_host, data.n_timesteps)
+            track_best = early_stopping and restore_best_weights
 
-        if self.broadcast_data:
-            # every fleet member trains on the one shared dataset
-            rows_per_machine = np.full(m, int((w_host > 0).sum()), dtype=np.int64)
-        else:
-            rows_per_machine = (w_host > 0).sum(axis=1).astype(np.int64)
-        sample_cap = self._sample_cap(w_host, data.n_timesteps)
-        track_best = early_stopping and restore_best_weights
+            if self.epoch_chunk <= 1:
+                epoch_fn = self._epoch_fn(
+                    data.n_timesteps,
+                    batch_size,
+                    shuffle,
+                    gated=early_stopping,
+                    sample_cap=sample_cap,
+                    quarantine=quarantine,
+                    inject=inj is not None,
+                    masked=masked,
+                )
+                val_fn = (
+                    self._val_fn(
+                        data.n_timesteps, batch_size, lo=val_lo, masked=masked
+                    )
+                    if val_w is not None
+                    else None
+                )
 
         if self.epoch_chunk > 1:
             # device-resident loop: K epochs per compiled program, one
@@ -1326,24 +1418,8 @@ class FleetTrainer:
                 checkpoint_every=checkpoint_every, start_epoch=start_epoch,
                 m=m, rows_per_machine=rows_per_machine, fit_start=fit_start,
                 quarantine=quarantine, inj=inj, healthy_np=healthy_np,
-                machine_names=machine_names, fmask=fmask,
+                machine_names=machine_names, fmask=fmask, phases=phases,
             )
-
-        epoch_fn = self._epoch_fn(
-            data.n_timesteps,
-            batch_size,
-            shuffle,
-            gated=early_stopping,
-            sample_cap=sample_cap,
-            quarantine=quarantine,
-            inject=inj is not None,
-            masked=masked,
-        )
-        val_fn = (
-            self._val_fn(data.n_timesteps, batch_size, lo=val_lo, masked=masked)
-            if val_w is not None
-            else None
-        )
 
         best_params = None  # set at the first monitored improvement
 
@@ -1361,35 +1437,34 @@ class FleetTrainer:
         epochs_run = 0
         timesteps_trained = 0
         early_stop_epoch: Optional[int] = None
-        n_host_syncs = 1  # the setup's one effective-weights fetch
         dispatch_times: list = []
         loop_start = time.perf_counter()
         for epoch in range(start_epoch, epochs):
             epoch_start = time.perf_counter()
-            epoch_keys = jax.vmap(lambda k: jax.random.fold_in(k, epoch))(keys)
-            extras = []
-            if early_stopping:
-                extras.append(
-                    _put_fleet_arr(
-                        es_state["active"].astype(np.float32), self.mesh
+            # the host's share of one epoch: key fold-in, the per-machine
+            # flags and the enqueue of the epoch program (the device work
+            # is asynchronous and not inside)
+            with tracing.start_span("train.dispatch", epoch=epoch, n_epochs=1):
+                epoch_keys = jax.vmap(
+                    lambda k: jax.random.fold_in(k, epoch)
+                )(keys)
+                extras = []
+                if early_stopping:
+                    extras.append(
+                        _put_fleet_arr(
+                            es_state["active"].astype(np.float32), self.mesh
+                        )
                     )
-                )
-            if quarantine:
-                extras.append(healthy_dev)
-            if inj is not None:
-                # the host-side twin of the chunk program's in-scan
-                # flag: poison only at the configured epoch
-                extras.append(
-                    _put_fleet_arr(inj[0] & (epoch == inj[1]), self.mesh)
-                )
-            if masked:
-                extras.append(fmask)
-            # span + profiler annotation: the same dispatch shows up in
-            # the distributed trace AND (when a jax.profiler trace is
-            # active) on the XLA device timeline
-            with tracing.start_span(
-                "train.dispatch", epoch=epoch, n_epochs=1
-            ), annotate("train-dispatch"):
+                if quarantine:
+                    extras.append(healthy_dev)
+                if inj is not None:
+                    # the host-side twin of the chunk program's in-scan
+                    # flag: poison only at the configured epoch
+                    extras.append(
+                        _put_fleet_arr(inj[0] & (epoch == inj[1]), self.mesh)
+                    )
+                if masked:
+                    extras.append(fmask)
                 t_disp = time.perf_counter()
                 result = epoch_fn(
                     params, opt_state, epoch_keys, X_arg, y_arg, w_arg,
@@ -1415,7 +1490,8 @@ class FleetTrainer:
             if first_epoch_s is None:
                 # guarded to run ONCE per fit (compile-cost telemetry),
                 # not per iteration — the sync budget accounts for it
-                jax.block_until_ready(epoch_loss)  # lint: disable=host-sync
+                with tracing.start_span("train.first_sync", epoch=epoch):
+                    jax.block_until_ready(epoch_loss)  # lint: disable=host-sync
                 first_epoch_s = time.perf_counter() - epoch_start
             if val_fn is not None:
                 val_losses.append(
@@ -1433,71 +1509,76 @@ class FleetTrainer:
                 # pulls it with the losses (no extra sync)
                 healthy_rows.append(healthy_dev)
             if early_stopping:
-                if quarantine:
-                    # healthy rides the SAME per-epoch decision sync the
-                    # ES path already pays — one call, one transfer
-                    step_fetch = host_fetch(
-                        {"loss": epoch_loss, "healthy": healthy_dev}
-                    )
-                    loss_np = np.asarray(step_fetch["loss"], dtype=np.float64)
-                    healthy_np = np.asarray(step_fetch["healthy"], dtype=bool)
-                    healthy_rows.append(healthy_np)
-                else:
-                    loss_np = np.asarray(
-                        host_fetch(epoch_loss), dtype=np.float64
-                    )
-                n_host_syncs += 1
-                # a stopped machine's computed loss reflects a discarded
-                # would-be update; report its last active loss instead
-                report = np.where(
-                    es_state["active"], loss_np, es_state["last_loss"]
-                )
-                losses.append(report)
-                es_state["last_loss"] = report
-                if monitor_val:
-                    val_np = np.asarray(
-                        host_fetch(val_losses[-1]), dtype=np.float64
-                    )
-                    n_host_syncs += 1
-                    # keep the host copy: the end-of-fit stack must not
-                    # re-transfer a history already fetched epoch by epoch
-                    val_losses[-1] = val_np
-                    # a machine too small for any validation samples falls
-                    # back to its training loss (solo path: n_val == 0
-                    # skips val_loss and EarlyStopping monitors loss) —
-                    # monitoring its constant-0.0 val loss would spuriously
-                    # stop it at epoch 0
-                    monitored = np.where(has_val, val_np, loss_np)
-                else:
-                    monitored = loss_np
-                if epoch >= int(early_stopping_start_from_epoch):
-                    # the improvement test runs in float32 — the same
-                    # arithmetic the device-resident (epoch_chunk > 1)
-                    # state machine uses — so both paths take bit-identical
-                    # stopping decisions (the state itself stays float64
-                    # for checkpoint-format stability; the values are
-                    # exact float32s either way)
-                    improved = es_state["active"] & (
-                        monitored.astype(np.float32)
-                        < es_state["best"].astype(np.float32)
-                        - np.float32(es_delta)
-                    )
-                    es_state["best"] = np.where(
-                        improved, monitored, es_state["best"]
-                    )
-                    es_state["wait"] = np.where(
-                        improved, 0, es_state["wait"] + 1
-                    )
-                    es_state["active"] = es_state["active"] & (
-                        es_state["wait"] < es_stop_at
-                    )
-                    if track_best and improved.any():
-                        mask = _put_fleet_arr(improved, self.mesh)
-                        best_params = _keep_better(
-                            mask,
-                            params,
-                            params if best_params is None else best_params,
+                with phases.timed("decide_s"), tracing.start_span(
+                    "train.decide", epoch=epoch
+                ):
+                    if quarantine:
+                        # healthy rides the SAME per-epoch decision sync the
+                        # ES path already pays — one call, one transfer
+                        step_fetch = phases.fetched(
+                            host_fetch(
+                                {"loss": epoch_loss, "healthy": healthy_dev}
+                            )
                         )
+                        loss_np = np.asarray(step_fetch["loss"], dtype=np.float64)
+                        healthy_np = np.asarray(step_fetch["healthy"], dtype=bool)
+                        healthy_rows.append(healthy_np)
+                    else:
+                        loss_np = np.asarray(
+                            phases.fetched(host_fetch(epoch_loss)),
+                            dtype=np.float64,
+                        )
+                    # a stopped machine's computed loss reflects a discarded
+                    # would-be update; report its last active loss instead
+                    report = np.where(
+                        es_state["active"], loss_np, es_state["last_loss"]
+                    )
+                    losses.append(report)
+                    es_state["last_loss"] = report
+                    if monitor_val:
+                        val_np = np.asarray(
+                            phases.fetched(host_fetch(val_losses[-1])),
+                            dtype=np.float64,
+                        )
+                        # keep the host copy: the end-of-fit stack must not
+                        # re-transfer a history already fetched epoch by epoch
+                        val_losses[-1] = val_np
+                        # a machine too small for any validation samples falls
+                        # back to its training loss (solo path: n_val == 0
+                        # skips val_loss and EarlyStopping monitors loss) —
+                        # monitoring its constant-0.0 val loss would spuriously
+                        # stop it at epoch 0
+                        monitored = np.where(has_val, val_np, loss_np)
+                    else:
+                        monitored = loss_np
+                    if epoch >= int(early_stopping_start_from_epoch):
+                        # the improvement test runs in float32 — the same
+                        # arithmetic the device-resident (epoch_chunk > 1)
+                        # state machine uses — so both paths take bit-identical
+                        # stopping decisions (the state itself stays float64
+                        # for checkpoint-format stability; the values are
+                        # exact float32s either way)
+                        improved = es_state["active"] & (
+                            monitored.astype(np.float32)
+                            < es_state["best"].astype(np.float32)
+                            - np.float32(es_delta)
+                        )
+                        es_state["best"] = np.where(
+                            improved, monitored, es_state["best"]
+                        )
+                        es_state["wait"] = np.where(
+                            improved, 0, es_state["wait"] + 1
+                        )
+                        es_state["active"] = es_state["active"] & (
+                            es_state["wait"] < es_stop_at
+                        )
+                        if track_best and improved.any():
+                            mask = _put_fleet_arr(improved, self.mesh)
+                            best_params = _keep_better(
+                                mask,
+                                params,
+                                params if best_params is None else best_params,
+                            )
             else:
                 losses.append(epoch_loss)
             epoch_fields: dict = {"path": "fleet", "epoch": epoch}
@@ -1512,21 +1593,24 @@ class FleetTrainer:
             if checkpointer is not None and (epoch + 1) % max(
                 1, checkpoint_every
             ) == 0:
-                extra: Optional[dict] = None
-                if quarantine or early_stopping:
-                    extra = {}
-                    if quarantine:
-                        if not early_stopping:
-                            # plain fits keep healthy on device; the
-                            # checkpoint write is already a sync point
-                            healthy_np = np.asarray(
-                                host_fetch(healthy_dev), dtype=bool
-                            )
-                            n_host_syncs += 1
-                        extra["healthy"] = healthy_np
-                    if early_stopping:
-                        extra.update(es_state)
-                checkpointer.save(epoch, params, opt_state, extra=extra)
+                with phases.timed("checkpoint_s"), tracing.start_span(
+                    "train.checkpoint", epoch=epoch
+                ):
+                    extra: Optional[dict] = None
+                    if quarantine or early_stopping:
+                        extra = {}
+                        if quarantine:
+                            if not early_stopping:
+                                # plain fits keep healthy on device; the
+                                # checkpoint write is already a sync point
+                                healthy_np = np.asarray(
+                                    phases.fetched(host_fetch(healthy_dev)),
+                                    dtype=bool,
+                                )
+                            extra["healthy"] = healthy_np
+                        if early_stopping:
+                            extra.update(es_state)
+                    checkpointer.save(epoch, params, opt_state, extra=extra)
             if early_stopping and not es_state["active"].any():
                 logger.info(
                     "Fleet early stop: all %d machines stopped at epoch "
@@ -1541,55 +1625,26 @@ class FleetTrainer:
                 )
                 break
         if checkpointer is not None:
-            checkpointer.wait()
+            with phases.timed("checkpoint_s"), tracing.start_span(
+                "train.checkpoint"
+            ):
+                checkpointer.wait()
         if track_best and best_params is not None:
             # each machine leaves with the params of its best epoch; a
             # machine that never hit a monitored epoch (epochs <=
             # start_from_epoch) was never snapshotted and keeps its final
             # params via the first keep_better call's fallback
             params = best_params
-        # early stopping already host-materialized each epoch's losses
-        # (its per-epoch decision IS the sync); fetching them again
-        # would make process_allgather treat the replicated host copy
-        # as per-process data. Everything still on device — the plain
-        # fit's whole loss/val history — is ONE bulk transfer.
-        pending: dict = {}
-        if val_losses and not isinstance(val_losses[0], np.ndarray):
-            pending["val"] = val_losses
-        if losses and not isinstance(losses[0], np.ndarray):
-            pending["loss"] = losses
-        if healthy_rows and not isinstance(healthy_rows[0], np.ndarray):
-            pending["healthy"] = healthy_rows
-        if pending:
-            fetched = host_fetch(pending)
-            n_host_syncs += 1
-            if "val" in fetched:
-                val_losses = list(fetched["val"])
-            if "loss" in fetched:
-                losses = list(fetched["loss"])
-            if "healthy" in fetched:
-                healthy_rows = [
-                    np.asarray(r, dtype=bool) for r in fetched["healthy"]
-                ]
-        if val_losses:
-            stacked = np.stack(val_losses).astype(np.float64)
-            # machines with no validation samples have no val loss (their
-            # computed 0.0 is an artifact of the empty weight sum)
-            if has_val is not None and not has_val.all():
-                stacked[:, ~has_val] = np.nan
-            self.val_losses_ = stacked
-        if losses:
-            losses_out = np.stack([np.asarray(l) for l in losses])
-        else:
-            losses_out = np.zeros((0, len(keys)))
-        n_quarantined = 0
-        if quarantine:
-            n_quarantined = self._finish_quarantine(
-                healthy_rows, healthy_entry, start_epoch, machine_names, m
-            )
-        # loop time is read AFTER the loss fetch above — that fetch is the
+        losses_out, n_quarantined = self._collect_fit(
+            phases, losses=losses, val_losses=val_losses,
+            healthy_rows=healthy_rows, has_val=has_val,
+            healthy_entry=healthy_entry, start_epoch=start_epoch,
+            machine_names=machine_names, m=m,
+        )
+        # loop time is read AFTER the bulk fetch above — that fetch is the
         # sync that makes the async epochs' wall-clock real
-        self._record_fit_telemetry(
+        self._report_fit(
+            phases,
             wall_time_s=time.perf_counter() - fit_start,
             loop_time_s=time.perf_counter() - loop_start,
             first_sync_s=first_epoch_s,
@@ -1606,7 +1661,6 @@ class FleetTrainer:
                 int((~es_state["active"]).sum()) if early_stopping else 0
             ),
             n_dispatches=epochs_run,
-            n_host_syncs=n_host_syncs,
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
         )
@@ -1647,6 +1701,7 @@ class FleetTrainer:
         healthy_np: Optional[np.ndarray] = None,
         machine_names: Optional[List[str]] = None,
         fmask: Optional[jnp.ndarray] = None,
+        phases: "_FitPhases",
     ) -> Tuple[Any, np.ndarray]:
         """
         The ``epoch_chunk > 1`` fit loop: dispatch ONE fused program per
@@ -1679,38 +1734,40 @@ class FleetTrainer:
         def put_fleet(x):
             return _put_fleet_arr(x, self.mesh)
 
-        if healthy_np is None:
-            healthy_np = np.ones(m, dtype=bool)
-        healthy_entry = healthy_np.copy()
-        healthy_dev = put_fleet(healthy_np) if quarantine else None
-        healthy_chunks: list = []
-        inj_mask_dev = inj_epoch_dev = None
-        if inj is not None:
-            inj_mask_dev = put_fleet(inj[0])
-            inj_epoch_dev = jnp.asarray(np.int32(inj[1]))
-        es_dev: Optional[dict] = None
-        has_val_dev = None
-        if early_stopping:
-            es_dev = {
-                "active": put_fleet(es_state["active"]),
-                "best": put_fleet(es_state["best"].astype(np.float32)),
-                "wait": put_fleet(es_state["wait"].astype(np.int32)),
-                "last": put_fleet(es_state["last_loss"].astype(np.float32)),
-            }
-            if monitor_val:
-                has_val_dev = put_fleet(np.asarray(has_val, dtype=bool))
-        best_params_dev = None
-        ever_dev = None
-        ever_improved = False
-        if track_best:
-            # garbage until the first improving epoch (ever_improved
-            # gates its use), but it must be a DISTINCT buffer: params is
-            # donated, and aliasing a donated arg is not allowed
-            best_params_dev = self._shard(
-                jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
-            )
-            ever_dev = jnp.asarray(False)
+        # the device-resident state the chunk program carries
+        with phases.timed("prepare_s"), tracing.start_span("train.prepare"):
+            if healthy_np is None:
+                healthy_np = np.ones(m, dtype=bool)
+            healthy_entry = healthy_np.copy()
+            healthy_dev = put_fleet(healthy_np) if quarantine else None
+            inj_mask_dev = inj_epoch_dev = None
+            if inj is not None:
+                inj_mask_dev = put_fleet(inj[0])
+                inj_epoch_dev = jnp.asarray(np.int32(inj[1]))
+            es_dev: Optional[dict] = None
+            has_val_dev = None
+            if early_stopping:
+                es_dev = {
+                    "active": put_fleet(es_state["active"]),
+                    "best": put_fleet(es_state["best"].astype(np.float32)),
+                    "wait": put_fleet(es_state["wait"].astype(np.int32)),
+                    "last": put_fleet(es_state["last_loss"].astype(np.float32)),
+                }
+                if monitor_val:
+                    has_val_dev = put_fleet(np.asarray(has_val, dtype=bool))
+            best_params_dev = None
+            ever_dev = None
+            ever_improved = False
+            if track_best:
+                # garbage until the first improving epoch (ever_improved
+                # gates its use), but it must be a DISTINCT buffer: params is
+                # donated, and aliasing a donated arg is not allowed
+                best_params_dev = self._shard(
+                    jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
+                )
+                ever_dev = jnp.asarray(False)
 
+        healthy_chunks: list = []
         loss_chunks: list = []
         val_chunks: list = []
         first_sync_s: Optional[float] = None
@@ -1719,7 +1776,6 @@ class FleetTrainer:
         epochs_dispatched = 0
         timesteps_trained = 0
         early_stop_epoch: Optional[int] = None
-        n_host_syncs = 1  # the setup's one effective-weights fetch
         n_dispatches = 0
         dispatch_times: list = []
         loop_start = time.perf_counter()
@@ -1744,46 +1800,44 @@ class FleetTrainer:
         while e < epochs:
             k = chunk_len(e)
             chunk_start = time.perf_counter()
-            chunk_fn = self._chunk_fn(
-                n_timesteps, batch_size, shuffle,
-                chunk_len=k, sample_cap=sample_cap, with_val=with_val,
-                val_lo=val_lo, gated=early_stopping, track_best=track_best,
-                monitor_val=monitor_val, es_delta=es_delta,
-                es_stop_at=es_stop_at, es_start_from=es_start_from,
-                quarantine=quarantine, inject=inj is not None,
-                masked=masked,
-            )
-            epoch_vec = prefetched_epochs.pop((e, k), None)
-            if epoch_vec is None:
-                if self.prefetch_depth > 0:
-                    transfer.count_transfer("train", "direct")
-                epoch_vec = jnp.arange(e, e + k, dtype=jnp.int32)
-            args = [
-                params, opt_state, keys, X_arg, y_arg, w_arg, epoch_vec,
-            ]
-            if with_val:
-                args.append(val_arg)
-            if masked:
-                args.append(fmask)
-            if quarantine:
-                args.append(healthy_dev)
-            if early_stopping:
-                args += [
-                    es_dev["active"], es_dev["best"],
-                    es_dev["wait"], es_dev["last"],
+            # one fused K-epoch program per dispatch: the span is the unit
+            # the sync-budget telemetry counts, and covers the host's whole
+            # share of it (program lookup, arguments, enqueue)
+            with tracing.start_span("train.dispatch", epoch=e, n_epochs=k):
+                chunk_fn = self._chunk_fn(
+                    n_timesteps, batch_size, shuffle,
+                    chunk_len=k, sample_cap=sample_cap, with_val=with_val,
+                    val_lo=val_lo, gated=early_stopping, track_best=track_best,
+                    monitor_val=monitor_val, es_delta=es_delta,
+                    es_stop_at=es_stop_at, es_start_from=es_start_from,
+                    quarantine=quarantine, inject=inj is not None,
+                    masked=masked,
+                )
+                epoch_vec = prefetched_epochs.pop((e, k), None)
+                if epoch_vec is None:
+                    if self.prefetch_depth > 0:
+                        transfer.count_transfer("train", "direct")
+                    epoch_vec = jnp.arange(e, e + k, dtype=jnp.int32)
+                args = [
+                    params, opt_state, keys, X_arg, y_arg, w_arg, epoch_vec,
                 ]
-                if monitor_val:
-                    args.append(has_val_dev)
-            if inj is not None:
-                args += [inj_mask_dev, inj_epoch_dev]
-            if track_best:
-                args += [best_params_dev, ever_dev]
-            # one fused K-epoch program per dispatch: the span (and, when
-            # a jax.profiler trace is active, the device-timeline
-            # annotation) is the unit the sync-budget telemetry counts
-            with tracing.start_span(
-                "train.dispatch", epoch=e, n_epochs=k
-            ), annotate("train-dispatch"):
+                if with_val:
+                    args.append(val_arg)
+                if masked:
+                    args.append(fmask)
+                if quarantine:
+                    args.append(healthy_dev)
+                if early_stopping:
+                    args += [
+                        es_dev["active"], es_dev["best"],
+                        es_dev["wait"], es_dev["last"],
+                    ]
+                    if monitor_val:
+                        args.append(has_val_dev)
+                if inj is not None:
+                    args += [inj_mask_dev, inj_epoch_dev]
+                if track_best:
+                    args += [best_params_dev, ever_dev]
                 t_disp = time.perf_counter()
                 final, outs = chunk_fn(*args)
                 attribution.record(
@@ -1820,83 +1874,85 @@ class FleetTrainer:
             epochs_dispatched += k
 
             if early_stopping:
-                # the ONE host sync per chunk: reported losses, per-epoch
-                # activity, end-of-chunk ES state (and the snapshot flag)
-                # come back in a single transfer
-                fetch = {"loss": outs["loss"], "active": outs["active"],
-                         "es": final["es"]}
-                if with_val:
-                    fetch["val"] = outs["val"]
-                if track_best:
-                    fetch["ever"] = final["ever_improved"]
-                if quarantine:
-                    fetch["healthy"] = outs["healthy"]
-                fetched = host_fetch(fetch)
-                n_host_syncs += 1
-                if first_sync_s is None:
-                    first_sync_s = time.perf_counter() - chunk_start
-                    first_sync_epochs = k
-                loss_rep = np.asarray(fetched["loss"], dtype=np.float64)
-                active_out = np.asarray(fetched["active"], dtype=bool)
-                # activity ENTERING each epoch: the chunk-entry state,
-                # then the previous epoch's post-update state
-                active_in = np.concatenate(
-                    [es_state["active"][None, :], active_out[:-1]], axis=0
-                )
-                stopped = ~active_out.any(axis=1)
-                n_rep = int(np.argmax(stopped)) + 1 if stopped.any() else k
-                loss_chunks.append(loss_rep[:n_rep])
-                if with_val:
-                    val_chunks.append(
-                        np.asarray(fetched["val"], dtype=np.float64)[:n_rep]
+                with phases.timed("decide_s"), tracing.start_span(
+                    "train.decide", epoch=e, n_epochs=k
+                ):
+                    # the ONE host sync per chunk: reported losses, per-epoch
+                    # activity, end-of-chunk ES state (and the snapshot flag)
+                    # come back in a single transfer
+                    fetch = {"loss": outs["loss"], "active": outs["active"],
+                             "es": final["es"]}
+                    if with_val:
+                        fetch["val"] = outs["val"]
+                    if track_best:
+                        fetch["ever"] = final["ever_improved"]
+                    if quarantine:
+                        fetch["healthy"] = outs["healthy"]
+                    fetched = phases.fetched(host_fetch(fetch))
+                    if first_sync_s is None:
+                        first_sync_s = time.perf_counter() - chunk_start
+                        first_sync_epochs = k
+                    loss_rep = np.asarray(fetched["loss"], dtype=np.float64)
+                    active_out = np.asarray(fetched["active"], dtype=bool)
+                    # activity ENTERING each epoch: the chunk-entry state,
+                    # then the previous epoch's post-update state
+                    active_in = np.concatenate(
+                        [es_state["active"][None, :], active_out[:-1]], axis=0
                     )
-                if quarantine:
-                    healthy_out_rows = np.asarray(
-                        fetched["healthy"], dtype=bool
-                    )[:n_rep]
-                    healthy_chunks.append(healthy_out_rows)
-                    if len(healthy_out_rows):
-                        healthy_np = healthy_out_rows[-1]
-                if track_best:
-                    ever_improved = bool(fetched["ever"])
-                timesteps_trained += int(
-                    (active_in[:n_rep] * rows_per_machine[None, :]).sum()
-                )
-                epochs_run += n_rep
-                # host mirror of the device ES state (checkpoint extra +
-                # telemetry); when the fleet stopped mid-chunk the mirror
-                # includes the gated no-op tail epochs, but then no
-                # checkpoint is written and only `active` (all False
-                # either way) is read again
-                es_state["best"] = np.asarray(
-                    fetched["es"]["best"], dtype=np.float64
-                )
-                es_state["wait"] = np.asarray(
-                    fetched["es"]["wait"], dtype=np.int64
-                )
-                es_state["active"] = np.asarray(
-                    fetched["es"]["active"], dtype=bool
-                )
-                es_state["last_loss"] = np.asarray(
-                    fetched["es"]["last"], dtype=np.float64
-                )
-                for j in range(n_rep):
-                    emit_event(
-                        "epoch", path="fleet", epoch=e + j,
-                        mean_loss=float(np.mean(loss_rep[j])),
-                        n_active=int(active_out[j].sum()),
+                    stopped = ~active_out.any(axis=1)
+                    n_rep = int(np.argmax(stopped)) + 1 if stopped.any() else k
+                    loss_chunks.append(loss_rep[:n_rep])
+                    if with_val:
+                        val_chunks.append(
+                            np.asarray(fetched["val"], dtype=np.float64)[:n_rep]
+                        )
+                    if quarantine:
+                        healthy_out_rows = np.asarray(
+                            fetched["healthy"], dtype=bool
+                        )[:n_rep]
+                        healthy_chunks.append(healthy_out_rows)
+                        if len(healthy_out_rows):
+                            healthy_np = healthy_out_rows[-1]
+                    if track_best:
+                        ever_improved = bool(fetched["ever"])
+                    timesteps_trained += int(
+                        (active_in[:n_rep] * rows_per_machine[None, :]).sum()
                     )
-                if stopped.any():
-                    early_stop_epoch = e + n_rep - 1
-                    logger.info(
-                        "Fleet early stop: all %d machines stopped at epoch "
-                        "%d/%d (chunked: %d gated no-op epochs discarded)",
-                        m, early_stop_epoch, epochs, k - n_rep,
+                    epochs_run += n_rep
+                    # host mirror of the device ES state (checkpoint extra +
+                    # telemetry); when the fleet stopped mid-chunk the mirror
+                    # includes the gated no-op tail epochs, but then no
+                    # checkpoint is written and only `active` (all False
+                    # either way) is read again
+                    es_state["best"] = np.asarray(
+                        fetched["es"]["best"], dtype=np.float64
                     )
-                    emit_event(
-                        "early_stop", path="fleet",
-                        epoch=early_stop_epoch, n_machines=m,
+                    es_state["wait"] = np.asarray(
+                        fetched["es"]["wait"], dtype=np.int64
                     )
+                    es_state["active"] = np.asarray(
+                        fetched["es"]["active"], dtype=bool
+                    )
+                    es_state["last_loss"] = np.asarray(
+                        fetched["es"]["last"], dtype=np.float64
+                    )
+                    for j in range(n_rep):
+                        emit_event(
+                            "epoch", path="fleet", epoch=e + j,
+                            mean_loss=float(np.mean(loss_rep[j])),
+                            n_active=int(active_out[j].sum()),
+                        )
+                    if stopped.any():
+                        early_stop_epoch = e + n_rep - 1
+                        logger.info(
+                            "Fleet early stop: all %d machines stopped at epoch "
+                            "%d/%d (chunked: %d gated no-op epochs discarded)",
+                            m, early_stop_epoch, epochs, k - n_rep,
+                        )
+                        emit_event(
+                            "early_stop", path="fleet",
+                            epoch=early_stop_epoch, n_machines=m,
+                        )
             else:
                 loss_chunks.append(outs["loss"])
                 if with_val:
@@ -1908,7 +1964,8 @@ class FleetTrainer:
                 if first_sync_s is None:
                     # sync ONCE (a readiness wait, not a transfer) so
                     # compile+first-chunk cost separates from steady state
-                    jax.block_until_ready(outs["loss"])  # lint: disable=host-sync
+                    with tracing.start_span("train.first_sync", epoch=e):
+                        jax.block_until_ready(outs["loss"])  # lint: disable=host-sync
                     first_sync_s = time.perf_counter() - chunk_start
                     first_sync_epochs = k
                 timesteps_trained += int(rows_per_machine.sum()) * k
@@ -1924,66 +1981,47 @@ class FleetTrainer:
                 # chunk boundaries were forced onto the checkpoint cadence
                 # above; a mid-chunk early stop means the per-epoch loop
                 # would have broken before this boundary, so skip it
-                extra: Optional[dict] = None
-                if quarantine or early_stopping:
-                    extra = {}
-                    if quarantine:
-                        if not early_stopping:
-                            # plain chunked fits keep healthy on device;
-                            # the checkpoint write is already a sync point
-                            healthy_np = np.asarray(
-                                host_fetch(healthy_dev), dtype=bool
-                            )
-                            n_host_syncs += 1
-                        extra["healthy"] = healthy_np
-                    if early_stopping:
-                        extra.update(es_state)
-                checkpointer.save(e + k - 1, params, opt_state, extra=extra)
+                with phases.timed("checkpoint_s"), tracing.start_span(
+                    "train.checkpoint", epoch=e + k - 1
+                ):
+                    extra: Optional[dict] = None
+                    if quarantine or early_stopping:
+                        extra = {}
+                        if quarantine:
+                            if not early_stopping:
+                                # plain chunked fits keep healthy on device;
+                                # the checkpoint write is already a sync point
+                                healthy_np = np.asarray(
+                                    phases.fetched(host_fetch(healthy_dev)),
+                                    dtype=bool,
+                                )
+                            extra["healthy"] = healthy_np
+                        if early_stopping:
+                            extra.update(es_state)
+                    checkpointer.save(
+                        e + k - 1, params, opt_state, extra=extra
+                    )
             if early_stop_epoch is not None:
                 break
             e += k
 
         if checkpointer is not None:
-            checkpointer.wait()
+            with phases.timed("checkpoint_s"), tracing.start_span(
+                "train.checkpoint"
+            ):
+                checkpointer.wait()
         if track_best and ever_improved:
             params = best_params_dev
-        # the plain fit's ONLY loop sync: the whole (epochs, M) loss/val
-        # history in one transfer
-        pending: dict = {}
-        if loss_chunks and not isinstance(loss_chunks[0], np.ndarray):
-            pending["loss"] = loss_chunks
-        if val_chunks and not isinstance(val_chunks[0], np.ndarray):
-            pending["val"] = val_chunks
-        if healthy_chunks and not isinstance(healthy_chunks[0], np.ndarray):
-            pending["healthy"] = healthy_chunks
-        if pending:
-            fetched = host_fetch(pending)
-            n_host_syncs += 1
-            if "loss" in fetched:
-                loss_chunks = [np.asarray(a) for a in fetched["loss"]]
-            if "val" in fetched:
-                val_chunks = [np.asarray(a) for a in fetched["val"]]
-            if "healthy" in fetched:
-                healthy_chunks = [
-                    np.asarray(a, dtype=bool) for a in fetched["healthy"]
-                ]
-        if val_chunks:
-            stacked = np.concatenate(val_chunks, axis=0).astype(np.float64)
-            if has_val is not None and not has_val.all():
-                stacked[:, ~has_val] = np.nan
-            self.val_losses_ = stacked
-        if loss_chunks:
-            losses_out = np.concatenate(
-                [np.asarray(a) for a in loss_chunks], axis=0
-            )
-        else:
-            losses_out = np.zeros((0, m))
-        n_quarantined = 0
-        if quarantine:
-            n_quarantined = self._finish_quarantine(
-                healthy_chunks, healthy_entry, start_epoch, machine_names, m
-            )
-        self._record_fit_telemetry(
+        # a plain chunked fit's ONLY sync after set-up: the whole
+        # (epochs, M) loss/val history in one transfer
+        losses_out, n_quarantined = self._collect_fit(
+            phases, losses=loss_chunks, val_losses=val_chunks,
+            healthy_rows=healthy_chunks, has_val=has_val,
+            healthy_entry=healthy_entry, start_epoch=start_epoch,
+            machine_names=machine_names, m=m,
+        )
+        self._report_fit(
+            phases,
             wall_time_s=time.perf_counter() - fit_start,
             loop_time_s=time.perf_counter() - loop_start,
             first_sync_s=first_sync_s,
@@ -2000,11 +2038,74 @@ class FleetTrainer:
                 int((~es_state["active"]).sum()) if early_stopping else 0
             ),
             n_dispatches=n_dispatches,
-            n_host_syncs=n_host_syncs,
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
         )
         return params, losses_out
+
+    def _collect_fit(
+        self,
+        phases: "_FitPhases",
+        *,
+        losses: list,
+        val_losses: list,
+        healthy_rows: list,
+        has_val: Optional[np.ndarray],
+        healthy_entry: np.ndarray,
+        start_epoch: int,
+        machine_names: Optional[List[str]],
+        m: int,
+    ) -> Tuple[np.ndarray, int]:
+        """
+        The end of a fit's loop, under ``train.collect``. Whatever history
+        is still on the device — a plain fit's whole loss / val / healthy
+        history — comes back in ONE bulk transfer. Early stopping already
+        host-materialized its history (its decision IS the sync), and
+        fetching that again would make ``process_allgather`` treat the
+        replicated host copy as per-process data. The histories (rows of
+        (M,) or blocks of (k, M), in epoch order) are stacked, and the
+        quarantine bookkeeping runs. Sets ``val_losses_``; returns (losses
+        (epochs, M), how many machines ended quarantined).
+        """
+
+        def stacked(rows, dtype=None):
+            return np.concatenate(
+                [np.atleast_2d(np.asarray(r, dtype=dtype)) for r in rows]
+            )
+
+        with phases.timed("collect_s"), tracing.start_span("train.collect"):
+            history = {"loss": losses, "val": val_losses, "healthy": healthy_rows}
+            pending = {
+                name: rows for name, rows in history.items()
+                if rows and not isinstance(rows[0], np.ndarray)
+            }
+            if pending:
+                history.update(phases.fetched(host_fetch(pending)))
+            if history["val"]:
+                val = stacked(history["val"], np.float64)
+                # machines with no validation samples have no val loss
+                # (their computed 0.0 is an artifact of the empty weight sum)
+                if has_val is not None and not has_val.all():
+                    val[:, ~has_val] = np.nan
+                self.val_losses_ = val
+            losses_out = (
+                stacked(history["loss"]) if history["loss"] else np.zeros((0, m))
+            )
+            n_quarantined = 0
+            if self.quarantine_nonfinite:
+                n_quarantined = self._finish_quarantine(
+                    history["healthy"], healthy_entry, start_epoch,
+                    machine_names, m,
+                )
+        return losses_out, n_quarantined
+
+    def _report_fit(self, phases: "_FitPhases", **telemetry) -> None:
+        """A fit's last phase, under ``train.report``: publish its telemetry
+        (:meth:`_record_fit_telemetry`). What the publishing itself took can
+        only be added once it is over."""
+        with phases.timed("report_s"), tracing.start_span("train.report"):
+            self._record_fit_telemetry(phases=phases, **telemetry)
+        self.fit_telemetry_["report_s"] = phases.seconds["report_s"]
 
     def _finish_quarantine(
         self,
@@ -2076,7 +2177,7 @@ class FleetTrainer:
         early_stop_epoch: Optional[int],
         n_stopped: int,
         n_dispatches: int,
-        n_host_syncs: int,
+        phases: "_FitPhases",
         dispatch_times: Optional[list] = None,
         n_quarantined: int = 0,
     ) -> None:
@@ -2101,7 +2202,12 @@ class FleetTrainer:
         epochs. The first dispatch is excluded (it carries tracing and
         compile time). ``epochs_per_sync`` is how many epochs each
         device->host round-trip bought.
+
+        ``phases`` carries what the fit measured at its own boundaries
+        (:class:`_FitPhases`): the seconds of each host phase outside the
+        dispatches and the bytes and count of its device->host fetches.
         """
+        n_host_syncs = phases.n_host_syncs
         steady = None
         if epochs_dispatched > first_sync_epochs and first_sync_s is not None:
             steady = max(
@@ -2162,6 +2268,13 @@ class FleetTrainer:
             "epochs_per_sync": epochs_per_sync,
             "dispatch_overhead_s": dispatch_overhead,
             "dispatch_gap_s_mean": dispatch_gap,
+            # the host's phases outside the dispatches (the first sync is
+            # first_dispatch_s above; report_s is added when it is over)
+            "prepare_s": phases.seconds["prepare_s"],
+            "decide_s": phases.seconds["decide_s"],
+            "checkpoint_s": phases.seconds["checkpoint_s"],
+            "collect_s": phases.seconds["collect_s"],
+            "host_fetch_bytes": phases.host_fetch_bytes,
         }
         reg = get_registry()
         reg.histogram(
@@ -2204,6 +2317,11 @@ class FleetTrainer:
             "Device->host synchronizations paid by fits",
             ("path",),
         ).inc(n_host_syncs, path="fleet")
+        reg.counter(
+            "gordo_train_host_fetch_bytes_total",
+            "Bytes fits brought from the device to the host",
+            ("path",),
+        ).inc(phases.host_fetch_bytes, path="fleet")
         if epochs_per_sync is not None:
             reg.gauge(
                 "gordo_train_epochs_per_sync",

@@ -79,6 +79,13 @@ def enable_compile_cache(min_compile_seconds: float = 0.5) -> None:
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", float(min_compile_seconds)
     )
+    # An executable's own metadata (the scope names of parallel/fleet.py and
+    # models/specs.py, source lines) is what a profiler trace of it shows.
+    # JAX leaves metadata out of the cache key by default, so an entry
+    # compiled from another version of the source would be loaded for this
+    # one and show THAT version's names, or none. With it in the key, an
+    # edit that moves a traced line costs each program one compile.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     global _active_compile_cache_dir
     _active_compile_cache_dir = directory
     try:

@@ -20,7 +20,7 @@ def managed_attribute_form():
 
 
 def managed_multi_item(profiler):
-    with profiler.annotate("fit"), start_span("build.fit"):
+    with profiler.maybe_trace("build"), start_span("build.fit"):
         pass
 
 

@@ -599,6 +599,7 @@ def test_measure_overhead_reports_all_regimes(monkeypatch):
     assert set(out) == {
         "samples",
         "disabled_ns_per_span",
+        "profiler_only_ns_per_span",
         "sampled_out_ns_per_span",
         "enabled_ns_per_span",
     }

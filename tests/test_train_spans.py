@@ -1,0 +1,245 @@
+"""
+What ``FleetTrainer.fit`` says about itself at its own boundaries: the
+``train.*`` spans of its host phases (in order, each under the root
+``train.fit``), the seconds and fetched bytes it books in ``fit_telemetry_``
+whether tracing is on or off, and the stable scope names its compiled
+programs carry for a device trace (``jax.named_scope``: metadata only).
+"""
+
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from gordo_tpu.models.factories.feedforward import feedforward_hourglass
+from gordo_tpu.models.factories.gru import gru_model
+from gordo_tpu.models.factories.lstm import lstm_model
+from gordo_tpu.observability import get_registry, tracing
+from gordo_tpu.observability.tracing import TRACE_LOG_ENV_VAR
+from gordo_tpu.parallel import FleetTrainer, StackedData
+from gordo_tpu.parallel.checkpoint import FleetCheckpointer
+
+F, M, N, EPOCHS, BATCH = 3, 2, 40, 3, 8
+
+SCOPES_FILE = Path(__file__).resolve().parents[1] / "chipbench" / "scopes.json"
+
+
+def fleet_data():
+    X = jnp.asarray(np.random.default_rng(0).random((M, N, F)), jnp.float32)
+    return StackedData(X, X, jnp.ones((M, N), jnp.float32))
+
+
+def recurrent_spec(factory=lstm_model, **kwargs):
+    return factory(
+        n_features=F, lookback_window=4, encoding_dim=(8,),
+        encoding_func=("tanh",), decoding_dim=(8,), decoding_func=("tanh",),
+        fused=True, **kwargs,
+    )
+
+
+def traced_fit(tmp_path, monkeypatch, trainer, **fit_kwargs):
+    """One fit with the span log on: [(name, parent's name, attributes)]
+    in the order the spans were opened."""
+    log = tmp_path / "spans.jsonl"
+    monkeypatch.setenv(TRACE_LOG_ENV_VAR, str(log))
+    trainer.fit(
+        fleet_data(), trainer.machine_keys(M), epochs=EPOCHS, batch_size=BATCH,
+        **fit_kwargs,
+    )
+    spans = tracing.read_spans(str(log))
+    assert len({s["trace_id"] for s in spans}) == 1
+    names = {s["span_id"]: s["name"] for s in spans}
+    opened = sorted(spans, key=lambda s: s["start_unix_ms"])
+    return [
+        (s["name"], names.get(s["parent_span_id"]), s["attributes"]) for s in opened
+    ]
+
+
+def test_fit_spans_in_order_under_one_root(tmp_path, monkeypatch):
+    trainer = FleetTrainer(feedforward_hourglass(n_features=F))
+    spans = traced_fit(tmp_path, monkeypatch, trainer)
+    assert [name for name, _, _ in spans] == [
+        "train.fit", "train.prepare", "train.dispatch", "train.first_sync",
+        "train.dispatch", "train.dispatch", "train.collect", "train.report",
+    ]
+    root, *phases = spans
+    assert root[1] is None
+    assert root[2] == {
+        "n_machines": M, "epochs": EPOCHS, "batch_size": BATCH, "epoch_chunk": 1,
+    }
+    assert {parent for _, parent, _ in phases} == {"train.fit"}
+    assert [a["epoch"] for n, _, a in phases if n == "train.dispatch"] == [0, 1, 2]
+
+
+def test_chunked_fit_spans(tmp_path, monkeypatch):
+    """One dispatch per chunk; the chunk loop's own device-resident state is
+    a second stretch of ``train.prepare``."""
+    trainer = FleetTrainer(feedforward_hourglass(n_features=F), epoch_chunk=2)
+    spans = traced_fit(tmp_path, monkeypatch, trainer)
+    assert [name for name, _, _ in spans] == [
+        "train.fit", "train.prepare", "train.prepare", "train.dispatch",
+        "train.first_sync", "train.dispatch", "train.collect", "train.report",
+    ]
+    assert [
+        (a["epoch"], a["n_epochs"]) for n, _, a in spans if n == "train.dispatch"
+    ] == [(0, 2), (2, 1)]
+    assert trainer.fit_telemetry_["n_dispatches"] == 2
+
+
+@pytest.mark.parametrize("epoch_chunk", [1, 2])
+def test_early_stopping_and_checkpoint_phases(tmp_path, monkeypatch, epoch_chunk):
+    """``train.decide`` and ``train.checkpoint`` exist only on their paths,
+    and their seconds and fetches are booked."""
+    trainer = FleetTrainer(
+        feedforward_hourglass(n_features=F), epoch_chunk=epoch_chunk
+    )
+    spans = traced_fit(
+        tmp_path, monkeypatch, trainer, early_stopping_patience=5,
+        checkpointer=FleetCheckpointer(str(tmp_path / "ckpt")),
+        checkpoint_every=2,
+    )
+    names = [name for name, _, _ in spans]
+    n_decisions = EPOCHS if epoch_chunk == 1 else 2  # chunks of 2 and 1
+    assert names.count("train.decide") == n_decisions
+    # the save after epoch 1 (a chunk boundary too) and the wait at the end
+    assert names.count("train.checkpoint") == 2
+    telemetry = trainer.fit_telemetry_
+    assert telemetry["decide_s"] > 0 and telemetry["checkpoint_s"] > 0
+    # the weights' fetch and one per decision; nothing is left to collect
+    assert telemetry["n_host_syncs"] == 1 + n_decisions
+
+
+def test_fit_telemetry_books_phases_with_tracing_off(monkeypatch):
+    """The perf_counter pairs run whether tracing is on or off, and the bytes
+    are those of the shapes that crossed: the effective weights once, then
+    the loss and healthy histories in the one bulk fetch."""
+    monkeypatch.delenv(TRACE_LOG_ENV_VAR, raising=False)
+    counter = get_registry().counter(
+        "gordo_train_host_fetch_bytes_total",
+        "Bytes fits brought from the device to the host", ("path",),
+    )
+    before = counter.value(path="fleet")
+    trainer = FleetTrainer(feedforward_hourglass(n_features=F))
+    trainer.fit(fleet_data(), trainer.machine_keys(M), epochs=EPOCHS, batch_size=BATCH)
+    telemetry = trainer.fit_telemetry_
+    for key in ("prepare_s", "collect_s", "report_s"):
+        assert telemetry[key] > 0, key
+    assert telemetry["decide_s"] == telemetry["checkpoint_s"] == 0.0
+    weights = M * N * 4
+    histories = EPOCHS * M * 4 + EPOCHS * M * 1  # float32 losses, bool healthy
+    assert telemetry["host_fetch_bytes"] == weights + histories
+    assert telemetry["n_host_syncs"] == 2
+    assert counter.value(path="fleet") - before == weights + histories
+    # the phases are parts of the call, not more than it
+    parts = sum(
+        telemetry[k] for k in ("prepare_s", "epoch_loop_s", "report_s")
+    )
+    assert parts <= telemetry["wall_time_s"] + telemetry["report_s"] + 1e-3
+
+
+# -- scope names inside the compiled programs ---------------------------------
+
+FLEET_SCOPES = (
+    "fleet.order", "fleet.step", "fleet.gather", "fleet.loss_grad",
+    "fleet.optimizer", "fleet.guard",
+)
+
+
+def op_paths(lowered):
+    """Every op_name path in a lowered program's debug text."""
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+def lowered_epoch(spec, shuffle):
+    trainer = FleetTrainer(spec, lookahead=0)
+    keys = trainer.machine_keys(M)
+    params = trainer.init_params(keys, F)
+    X = jnp.zeros((M, N, F))
+    return trainer._epoch_fn(N, BATCH, shuffle, quarantine=True).lower(
+        params, trainer.init_opt_state(params), keys, X, X, jnp.ones((M, N)),
+        jnp.ones((M,), bool),
+    )
+
+
+@pytest.fixture(scope="module")
+def epoch_paths():
+    return {
+        "lstm": op_paths(lowered_epoch(recurrent_spec(), shuffle=False)),
+        "feedforward": op_paths(
+            lowered_epoch(feedforward_hourglass(n_features=F), shuffle=True)
+        ),
+    }
+
+
+@pytest.mark.parametrize("kind", ["lstm", "feedforward"])
+def test_epoch_program_names_the_trainers_scopes(epoch_paths, kind):
+    for scope in FLEET_SCOPES:
+        assert any(scope in path for path in epoch_paths[kind]), scope
+
+
+def test_lstm_scan_is_a_path_forward_and_backward(epoch_paths):
+    scans = [p for p in epoch_paths["lstm"] if "/scan/" in p]
+    forward = [p for p in scans if "transpose(" not in p]
+    backward = [p for p in scans if "transpose(" in p]
+    assert forward and backward
+    for layer in ("FusedLSTMLayer_0", "FusedLSTMLayer_1"):
+        assert any(f"{layer}/scan/" in p for p in forward), layer
+        assert any(f"{layer}/scan/" in p for p in backward), layer
+    # and nests under the trainer's own scope
+    assert all("fleet.loss_grad" in p for p in scans)
+    assert not any("/scan/" in p for p in epoch_paths["feedforward"])
+
+
+@pytest.mark.parametrize(
+    "factory,kwargs,module",
+    [(gru_model, {}, "FusedGRULayer_0"), (lstm_model, {"schedule": "stacked"}, "LSTMNet._stacked_scan")],
+    ids=["gru", "lstm-stacked"],
+)
+def test_other_fused_scans_carry_the_scope(factory, kwargs, module):
+    paths = op_paths(lowered_epoch(recurrent_spec(factory, **kwargs), shuffle=False))
+    assert any(f"{module}/scan/" in p for p in paths)
+
+
+def test_validation_program_names_its_gather():
+    trainer = FleetTrainer(recurrent_spec(), lookahead=0)
+    keys = trainer.machine_keys(M)
+    params = trainer.init_params(keys, F)
+    X = jnp.zeros((M, N, F))
+    paths = op_paths(trainer._val_fn(N, BATCH).lower(params, X, X, jnp.ones((M, N))))
+    for scope in ("fleet.gather", "fleet.val_loss"):
+        assert any(scope in p for p in paths), scope
+
+
+def test_scopes_change_no_arithmetic(monkeypatch):
+    """The scopes are metadata: with ``jax.named_scope`` made a no-op the
+    lowered epoch program is the same text but for its locations."""
+    import contextlib
+
+    def stripped(lowered):
+        text = lowered.as_text()
+        return re.sub(r"\s*loc\(.*?\)$", "", text, flags=re.M)
+
+    with_scopes = stripped(lowered_epoch(recurrent_spec(), shuffle=False))
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    without = stripped(lowered_epoch(recurrent_spec(), shuffle=False))
+    assert with_scopes == without
+
+
+def test_every_scope_of_the_benchmark_is_one_the_program_names(epoch_paths):
+    """``chipbench/scopes.json`` sums device time by path fragments; each has
+    to be one that the lowered programs above really carry."""
+    table = json.loads(SCOPES_FILE.read_text())
+    every = epoch_paths["lstm"] | epoch_paths["feedforward"]
+    for scope in table["scopes"]:
+        holds, lacks = scope["holds"], scope.get("lacks", [])
+        assert any(
+            all(h in p for h in holds) and not any(l in p for l in lacks)
+            for p in every
+        ), scope["name"]
+    assert {s["name"] for s in table["scopes"] if s["name"].startswith("fleet.")} == set(
+        FLEET_SCOPES
+    )

@@ -120,3 +120,53 @@ def test_enable_compile_cache_env_resolution(monkeypatch):
     finally:
         real_update("jax_compilation_cache_dir", prior_dir)
         real_update("jax_persistent_cache_min_compile_time_secs", prior_floor)
+
+
+def test_compile_cache_keeps_programs_apart_by_their_scope_names(tmp_path):
+    """Two programs that differ only in a ``jax.named_scope`` are two cache
+    entries once ``enable_compile_cache`` has run: a profiler trace of an
+    executable loaded from the cache shows the names of the source that
+    compiled it, so an entry of another version must not be taken for it
+    (JAX's default key leaves metadata out)."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from gordo_tpu.utils import enable_compile_cache
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_include_metadata_in_key",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    prior = {name: getattr(jax.config, name) for name in names}
+
+    def program(scope):
+        def body(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2.0
+
+        return jax.jit(body)
+
+    def entries():
+        return {f for f in os.listdir(tmp_path) if f.startswith("jit_body")}
+
+    try:
+        enable_compile_cache(min_compile_seconds=0.0)
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        program("fleet.gather")(jnp.ones(4)).block_until_ready()
+        first = entries()
+        assert len(first) == 1
+        program("fleet.order")(jnp.ones(4)).block_until_ready()
+        assert len(entries()) == 2
+    finally:
+        for name, value in prior.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
