@@ -441,6 +441,34 @@ class FleetTrainer:
         return max(1, int(valid.sum(axis=1).max()))
 
     # -- the compiled epoch ---------------------------------------------
+    def _choose_row_fetch(
+        self, data: StackedData, batch_size: int, sample_cap: Optional[int]
+    ) -> str:
+        """
+        How this fit's steps get their rows, from what the trainer can see
+        (docs/observability.md has the table): ``"permute_epoch"`` — every
+        machine's rows permuted into batch order once an epoch from on-chip
+        memory (``ops/row_permute.py``) — where each row is read once an
+        epoch (no windows), every machine has rows of its own on ONE chip,
+        the chip is a TPU, the rows are float32 and a machine's table fits
+        the kernel's vector memory; ``"gather"``, the per-step row gathers,
+        everywhere else.
+        """
+        if (
+            self.spec.windowed
+            or self.broadcast_data
+            or self.mesh is not None
+            or jax.default_backend() != "tpu"
+        ):
+            return "gather"
+        # only a fit that may use the kernel pays for importing Pallas
+        from gordo_tpu.ops import row_permute
+
+        n_batches = self._n_batches(data.n_timesteps, batch_size, sample_cap)
+        if row_permute.serves(data.X, data.y, n_batches * batch_size):
+            return "permute_epoch"
+        return "gather"
+
     def _n_batches(
         self, n: int, batch_size: int, sample_cap: Optional[int]
     ) -> int:
@@ -462,6 +490,7 @@ class FleetTrainer:
         quarantine: bool = False,
         inject: bool = False,
         masked: bool = False,
+        row_fetch: str = "gather",
     ):
         """
         Build (and cache) the jitted fleet-epoch function for a given
@@ -503,17 +532,22 @@ class FleetTrainer:
         means over REAL output columns only, so pad columns never move
         params or stopping decisions. Unmasked programs carry no trace
         of the feature, keeping exact-policy fits bit-identical.
+
+        ``row_fetch`` is how a step's rows reach it (``_choose_row_fetch``,
+        which ``fit`` asks once per fit): the default traces the per-step
+        gathers.
         """
         n_batches = self._n_batches(n, batch_size, sample_cap)
         cache_key = (
             n, batch_size, shuffle, gated, n_batches, quarantine, inject,
-            masked,
+            masked, row_fetch,
         )
 
         def build():
             fleet_epoch = self._epoch_callable(
                 n, batch_size, shuffle, gated, n_batches,
                 quarantine=quarantine, inject=inject, masked=masked,
+                row_fetch=row_fetch,
             )
             n_args = 6 + int(gated) + int(quarantine) + int(inject) + int(masked)
             jit_kwargs: dict = {}
@@ -541,6 +575,7 @@ class FleetTrainer:
         quarantine: bool = False,
         inject: bool = False,
         masked: bool = False,
+        row_fetch: str = "gather",
     ):
         """
         The RAW (un-jitted) vmapped fleet-epoch callable for a geometry,
@@ -557,13 +592,14 @@ class FleetTrainer:
         """
         cache_key = (
             "epoch_raw", n, batch_size, shuffle, gated, n_batches,
-            quarantine, inject, masked,
+            quarantine, inject, masked, row_fetch,
         )
         return self._programs.get_or_build(
             cache_key,
             lambda: self._build_epoch_callable(
                 n, batch_size, shuffle, gated, n_batches,
                 quarantine=quarantine, inject=inject, masked=masked,
+                row_fetch=row_fetch,
             ),
         )
 
@@ -577,8 +613,21 @@ class FleetTrainer:
         quarantine: bool = False,
         inject: bool = False,
         masked: bool = False,
+        row_fetch: str = "gather",
     ):
-        """The uncached body of :meth:`_epoch_callable`."""
+        """
+        The uncached body of :meth:`_epoch_callable`.
+
+        What the scope ``fleet.gather`` holds depends on ``row_fetch``.
+        ``"gather"``: inside every step, the three row gathers ``Xi[sel]``,
+        ``yi[sel]``, ``wb_all[sel]`` (for a windowed spec, the gather of
+        the step's windows). ``"permute_epoch"``: once an epoch, before the
+        step loop, every machine's rows permuted into batch order from
+        on-chip memory (``ops/row_permute.py``: the packing of a group of
+        machines, the kernel, the laying back as per-step slabs); a step
+        then takes its batch as the loop's own slice, and its weights from
+        the sort in ``fleet.order`` that made the order.
+        """
         n_samples = self._n_samples(n)
         spec = self.spec
         optimizer = self._optimizer
@@ -597,6 +646,15 @@ class FleetTrainer:
         loss_name = spec.loss
         module = spec.module
         windowed = spec.windowed
+        permute = row_fetch == "permute_epoch"
+        if permute:
+            if windowed or self.broadcast_data:
+                raise ValueError(
+                    "the permuting row fetch is for stacked, non-windowed data"
+                )
+            from gordo_tpu.ops import row_permute
+
+            fetch_epoch = row_permute.epoch_batches(n_batches)
 
         def sample_weights(wi):
             """Per-sample effective weight for every grid sample: a window
@@ -659,13 +717,28 @@ class FleetTrainer:
                     # scan cap.
                     ar = jnp.arange(n_samples, dtype=jnp.int32)
                     sort_key = jnp.where(real, ar, n_samples + ar)
-                order = jnp.argsort(sort_key).astype(jnp.int32)
+                if permute:
+                    # the weights ride the sort: ONE stable sort gives the
+                    # order argsort gives and the weights already in it
+                    _, order, w_sorted = jax.lax.sort(
+                        (sort_key, jnp.arange(n_samples, dtype=jnp.int32), wb_all),
+                        num_keys=1, is_stable=True,
+                    )
+                else:
+                    order = jnp.argsort(sort_key).astype(jnp.int32)
                 if n_pad > n_samples:
                     order = jnp.concatenate(
                         [order, jnp.zeros(n_pad - n_samples, dtype=jnp.int32)]
                     )
                 sel_all = order[:n_pad].reshape(n_batches, batch_size)
                 pm_all = jnp.asarray(pm_all_np)
+                if permute:
+                    # overflow slots fetch sample 0 and weigh nothing
+                    w_all = jnp.pad(w_sorted, (0, max(0, n_pad - n_samples)))
+                    w_all = w_all[:n_pad].reshape(n_batches, batch_size)
+            if permute:
+                with jax.named_scope("fleet.gather"):
+                    xb_all, yb_all = fetch_epoch(Xi, yi, order[:n_pad])
 
             def loss_fn(p, xb, yb, wb, dropout_key):
                 out, penalty = module.apply(
@@ -683,10 +756,13 @@ class FleetTrainer:
 
             def step(carry, batch):
                 p, o = carry
-                sel, pm, idx = batch
-                with jax.named_scope("fleet.gather"):
-                    xb, yb = gather(Xi, yi, sel)
-                    wb = wb_all[sel] * pm
+                if permute:
+                    xb, yb, wb, idx = batch
+                else:
+                    sel, pm, idx = batch
+                    with jax.named_scope("fleet.gather"):
+                        xb, yb = gather(Xi, yi, sel)
+                        wb = wb_all[sel] * pm
                 dkey = jax.random.fold_in(key, idx)
                 # the model's own Flax scopes nest under this one, the
                 # backward pass under transpose(jvp(...))
@@ -716,7 +792,9 @@ class FleetTrainer:
                 (new_params, new_opt), (loss_sums, w_sums) = jax.lax.scan(
                     step,
                     (params, opt_state),
-                    (sel_all, pm_all, step_ids),
+                    (xb_all, yb_all, w_all, step_ids)
+                    if permute
+                    else (sel_all, pm_all, step_ids),
                     unroll=min(self.scan_unroll, n_batches),
                 )
             with jax.named_scope("fleet.guard"):
@@ -889,6 +967,7 @@ class FleetTrainer:
         quarantine: bool = False,
         inject: bool = False,
         masked: bool = False,
+        row_fetch: str = "gather",
     ):
         """
         Build (and cache) the fused multi-epoch program: an outer
@@ -909,7 +988,7 @@ class FleetTrainer:
             "chunk", n, batch_size, shuffle, chunk_len, n_batches, with_val,
             val_lo, gated, track_best, monitor_val,
             float(es_delta), int(es_stop_at), int(es_start_from),
-            quarantine, inject, masked,
+            quarantine, inject, masked, row_fetch,
         )
         return self._programs.get_or_build(
             cache_key,
@@ -920,6 +999,7 @@ class FleetTrainer:
                 monitor_val=monitor_val, es_delta=es_delta,
                 es_stop_at=es_stop_at, es_start_from=es_start_from,
                 quarantine=quarantine, inject=inject, masked=masked,
+                row_fetch=row_fetch,
             ),
         )
 
@@ -942,11 +1022,13 @@ class FleetTrainer:
         quarantine: bool,
         inject: bool,
         masked: bool,
+        row_fetch: str = "gather",
     ):
         """The uncached body of :meth:`_chunk_fn`."""
         fleet_epoch = self._epoch_callable(
             n, batch_size, shuffle, gated, n_batches,
             quarantine=quarantine, inject=inject, masked=masked,
+            row_fetch=row_fetch,
         )
         fleet_val = (
             self._val_callable(n, batch_size, val_lo, masked)
@@ -1381,6 +1463,7 @@ class FleetTrainer:
                 rows_per_machine = (w_host > 0).sum(axis=1).astype(np.int64)
             sample_cap = self._sample_cap(w_host, data.n_timesteps)
             track_best = early_stopping and restore_best_weights
+            row_fetch = self._choose_row_fetch(data, batch_size, sample_cap)
 
             if self.epoch_chunk <= 1:
                 epoch_fn = self._epoch_fn(
@@ -1392,6 +1475,7 @@ class FleetTrainer:
                     quarantine=quarantine,
                     inject=inj is not None,
                     masked=masked,
+                    row_fetch=row_fetch,
                 )
                 val_fn = (
                     self._val_fn(
@@ -1419,6 +1503,7 @@ class FleetTrainer:
                 m=m, rows_per_machine=rows_per_machine, fit_start=fit_start,
                 quarantine=quarantine, inj=inj, healthy_np=healthy_np,
                 machine_names=machine_names, fmask=fmask, phases=phases,
+                row_fetch=row_fetch,
             )
 
         best_params = None  # set at the first monitored improvement
@@ -1663,6 +1748,7 @@ class FleetTrainer:
             n_dispatches=epochs_run,
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
+            row_fetch=row_fetch,
         )
         return params, losses_out
 
@@ -1702,6 +1788,7 @@ class FleetTrainer:
         machine_names: Optional[List[str]] = None,
         fmask: Optional[jnp.ndarray] = None,
         phases: "_FitPhases",
+        row_fetch: str = "gather",
     ) -> Tuple[Any, np.ndarray]:
         """
         The ``epoch_chunk > 1`` fit loop: dispatch ONE fused program per
@@ -1811,7 +1898,7 @@ class FleetTrainer:
                     monitor_val=monitor_val, es_delta=es_delta,
                     es_stop_at=es_stop_at, es_start_from=es_start_from,
                     quarantine=quarantine, inject=inj is not None,
-                    masked=masked,
+                    masked=masked, row_fetch=row_fetch,
                 )
                 epoch_vec = prefetched_epochs.pop((e, k), None)
                 if epoch_vec is None:
@@ -2040,6 +2127,7 @@ class FleetTrainer:
             n_dispatches=n_dispatches,
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
+            row_fetch=row_fetch,
         )
         return params, losses_out
 
@@ -2180,6 +2268,7 @@ class FleetTrainer:
         phases: "_FitPhases",
         dispatch_times: Optional[list] = None,
         n_quarantined: int = 0,
+        row_fetch: str = "gather",
     ) -> None:
         """
         Derive and publish one fit's telemetry: ``self.fit_telemetry_``
@@ -2263,6 +2352,9 @@ class FleetTrainer:
             "n_machines_early_stopped": n_stopped,
             "n_machines_quarantined": n_quarantined,
             "epoch_chunk": self.epoch_chunk,
+            # how the steps' rows reached them (_choose_row_fetch), and the
+            # epochs dispatched on that path
+            "row_fetch": {"path": row_fetch, "epochs": epochs_dispatched},
             "n_dispatches": n_dispatches,
             "n_host_syncs": n_host_syncs,
             "epochs_per_sync": epochs_per_sync,

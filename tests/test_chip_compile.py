@@ -26,6 +26,7 @@ from gordo_tpu.models.factories.gru import gru_model
 from gordo_tpu.models.factories.lstm import lstm_model
 from gordo_tpu.models.factories.transformer import transformer_model
 from gordo_tpu.ops import flash_attention as flash_module
+from gordo_tpu.ops import row_permute
 from gordo_tpu.ops.flash_attention import flash_attention
 from gordo_tpu.parallel.fleet import FleetTrainer
 
@@ -64,11 +65,12 @@ def _cache_off(no_persistent_compile_cache):
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     """Under a described-topology compile the backend still reads "cpu",
-    which selects the Pallas interpreter; compile the Mosaic kernel, as
+    which selects the Pallas interpreter; compile the Mosaic kernels, as
     the chip would."""
-    monkeypatch.setattr(
-        flash_module, "_interpret_for_backend", lambda backend: False
-    )
+    for module in (flash_module, row_permute):
+        monkeypatch.setattr(
+            module, "_interpret_for_backend", lambda backend: False
+        )
 
 
 def on_chip(tree, sharding):
@@ -110,6 +112,18 @@ def test_flash_attention_fwd_bwd_compiles(chip, seq):
     )
     # forward, dq, dk/dv
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_row_permute_kernel_compiles(chip):
+    """The feedforward fleet's fetch at ff50.fit1000's widths: one group of
+    40 machines, 16,384 packed rows of 128 lanes each, 33.5 MB of VMEM."""
+    table = jax.ShapeDtypeStruct((40, N_TIMESTEPS, 128), jnp.float32, sharding=chip)
+    idx = jax.ShapeDtypeStruct((40, N_TIMESTEPS), jnp.int32, sharding=chip)
+    text = (
+        jax.jit(lambda t, i: row_permute.permute_rows(t, i, interpret=False))
+        .lower(table, idx).compile().as_text()
+    )
+    assert "tpu_custom_call" in text
 
 
 # -- the fused recurrent train steps -----------------------------------------
@@ -195,6 +209,29 @@ def test_chunked_epoch_program_compiles(chip):
         *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip), epoch_ids, healthy
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
+    """The epoch program a TPU's feedforward fleet gets (``row_fetch``
+    ``"permute_epoch"``), 48 machines of ff50.fit1000's 1000: two groups of
+    the fleet loop, the second stepping back over the first. Beside the
+    permuted rows the program holds one group's packed tables (4 x 8.4 MB,
+    in and out, and the copies that turn them), whatever the fleet's width."""
+    from gordo_tpu.models.factories.feedforward import feedforward_hourglass
+
+    n_machines = 48
+    trainer = FleetTrainer(feedforward_hourglass(n_features=N_TAGS))
+    healthy = jax.ShapeDtypeStruct((n_machines,), jnp.bool_, sharding=chip)
+    compiled = trainer._epoch_fn(
+        N_TIMESTEPS, BATCH, True, quarantine=True, row_fetch="permute_epoch"
+    ).lower(
+        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=n_machines),
+        healthy,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    permuted = 2 * n_machines * N_TIMESTEPS * N_TAGS * 4
+    one_group = 4 * N_TIMESTEPS * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < permuted + 4 * one_group
 
 
 def test_fleet_epoch_program_compiles_for_four_chips(topology):

@@ -154,12 +154,12 @@ def op_paths(lowered):
     return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
 
 
-def lowered_epoch(spec, shuffle):
+def lowered_epoch(spec, shuffle, **kwargs):
     trainer = FleetTrainer(spec, lookahead=0)
     keys = trainer.machine_keys(M)
     params = trainer.init_params(keys, F)
     X = jnp.zeros((M, N, F))
-    return trainer._epoch_fn(N, BATCH, shuffle, quarantine=True).lower(
+    return trainer._epoch_fn(N, BATCH, shuffle, quarantine=True, **kwargs).lower(
         params, trainer.init_opt_state(params), keys, X, X, jnp.ones((M, N)),
         jnp.ones((M,), bool),
     )
@@ -172,13 +172,50 @@ def epoch_paths():
         "feedforward": op_paths(
             lowered_epoch(feedforward_hourglass(n_features=F), shuffle=True)
         ),
+        # the fetch a TPU gets (the kernel interpreted here, on the CPU)
+        "feedforward-permuting": op_paths(lowered_epoch(
+            feedforward_hourglass(n_features=F), shuffle=True,
+            row_fetch="permute_epoch",
+        )),
     }
 
 
-@pytest.mark.parametrize("kind", ["lstm", "feedforward"])
+@pytest.mark.parametrize("kind", ["lstm", "feedforward", "feedforward-permuting"])
 def test_epoch_program_names_the_trainers_scopes(epoch_paths, kind):
     for scope in FLEET_SCOPES:
         assert any(scope in path for path in epoch_paths[kind]), scope
+
+
+def test_permuting_fetch_lies_under_the_gather_scope_before_the_steps(epoch_paths):
+    """Once an epoch: the kernel and the packing around it carry
+    ``fleet.gather`` and not ``fleet.step``, no step gathers any more, and
+    nothing of it could be taken for a model scan (``scopes.json`` asks for
+    ``/scan/`` first)."""
+    paths = epoch_paths["feedforward-permuting"]
+    fetch = [p for p in paths if "fleet.gather" in p]
+    # the fleet loop, and in its body the kernel's own jitted call
+    assert any(p.endswith("fleet.gather)/while") for p in fetch)
+    assert any("_permute_stack" in p for p in paths)
+    assert not any("fleet.step" in p or "/scan/" in p for p in fetch)
+    assert not any(p.endswith("/gather") for p in paths)
+    assert "fleet.gather/gather" in epoch_paths["feedforward"]
+
+
+def test_windowed_epoch_program_is_untouched_by_the_row_fetch(monkeypatch):
+    """A windowed spec never takes the permuting fetch: asked as on a TPU
+    the chooser keeps the gather, the program it then gets is the text the
+    default builds, and the permuting program cannot be asked for."""
+    spec = recurrent_spec()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X = jnp.zeros((M, N, F))
+    data = StackedData(X, X, jnp.ones((M, N)))
+    chosen = FleetTrainer(spec, lookahead=0)._choose_row_fetch(data, BATCH, None)
+    assert chosen == "gather"
+    monkeypatch.undo()
+    default = lowered_epoch(spec, shuffle=False).as_text()
+    assert lowered_epoch(spec, shuffle=False, row_fetch=chosen).as_text() == default
+    with pytest.raises(ValueError, match="non-windowed"):
+        lowered_epoch(spec, shuffle=False, row_fetch="permute_epoch")
 
 
 def test_lstm_scan_is_a_path_forward_and_backward(epoch_paths):
@@ -233,7 +270,7 @@ def test_every_scope_of_the_benchmark_is_one_the_program_names(epoch_paths):
     """``chipbench/scopes.json`` sums device time by path fragments; each has
     to be one that the lowered programs above really carry."""
     table = json.loads(SCOPES_FILE.read_text())
-    every = epoch_paths["lstm"] | epoch_paths["feedforward"]
+    every = set().union(*epoch_paths.values())
     for scope in table["scopes"]:
         holds, lacks = scope["holds"], scope.get("lacks", [])
         assert any(
