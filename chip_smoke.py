@@ -704,10 +704,10 @@ def child_kernel(args) -> None:
     losses = np.asarray(losses)
 
     # the compiled step itself: the same epoch program fit dispatched
-    w_host = np.asarray(data.sample_weight)
+    _, valid = trainer._fit_facts(data.sample_weight)
     epoch_fn = trainer._epoch_fn(
         data.n_timesteps, batch, True,
-        sample_cap=trainer._sample_cap(w_host, data.n_timesteps),
+        sample_cap=max(1, int(np.max(valid))),
         quarantine=True,
     )
     text = epoch_fn.lower(

@@ -125,6 +125,38 @@ def host_fetch(x):
     return jax.device_get(x)
 
 
+@functools.partial(jax.jit, static_argnames=("lookback", "lookahead"))
+def _weight_facts(w, lookback: Optional[int] = None, lookahead: int = 0):
+    """
+    What a fit must know of its effective ``(M, n)`` sample weights, as two
+    ``(M,)`` int32 vectors computed where the weights are: the REAL rows a
+    machine (``w > 0``) and its VALID samples — the same count without a
+    ``lookback``; with one, a sample counts iff its whole window and its
+    target row at ``lookahead`` are real. Exact for any weight pattern.
+    """
+    real = w > 0
+    rows = real.sum(axis=1, dtype=jnp.int32)
+    if lookback is None:
+        return rows, rows
+    n_samples = w.shape[1] - lookback + 1 - lookahead
+    c = jnp.pad(jnp.cumsum(real, axis=1, dtype=jnp.int32), ((0, 0), (1, 0)))
+    window_real = (c[:, lookback:] - c[:, :-lookback]) == lookback
+    target = lookback - 1 + lookahead
+    valid = window_real[:, :n_samples] & real[:, target : target + n_samples]
+    return rows, valid.sum(axis=1, dtype=jnp.int32)
+
+
+@jax.jit
+def _split_masks(w, train_cut, n_train):
+    """``validation_split``'s two ``(M, n)`` float32 masks from the ``(M,)``
+    cuts: the bare train-cut indicator, and the holdout tail carrying the
+    effective weights ``w`` (it is used standalone as the eval weight)."""
+    t = jnp.arange(w.shape[1], dtype=jnp.int32)[None, :]
+    train_mask = (t < train_cut[:, None]).astype(jnp.float32)
+    val_mask = (t >= n_train[:, None]).astype(jnp.float32) * w
+    return train_mask, val_mask
+
+
 class _FitPhases:
     """
     What one fit measures at its own host-side boundaries, tracing on or
@@ -379,8 +411,13 @@ class FleetTrainer:
         return self._shard(params)
 
     def init_opt_state(self, params: Any) -> Any:
-        opt_state = jax.vmap(self._optimizer.init)(params)
-        return self._shard(opt_state)
+        """Fresh stacked optimizer state, made in ONE dispatch: the vmapped
+        ``init`` under ``jax.jit`` (one compiled program per param-tree
+        shape) instead of an eager dispatch per leaf."""
+        init = self._programs.get_or_build(
+            ("opt_init",), lambda: jax.jit(jax.vmap(self._optimizer.init))
+        )
+        return self._shard(init(params))
 
     def _shard(self, tree: Any) -> Any:
         if self.mesh is None:
@@ -420,25 +457,18 @@ class FleetTrainer:
             )
         return n_samples
 
-    def _sample_cap(self, w_host: np.ndarray, n: int) -> int:
+    def _fit_facts(self, w):
         """
-        Fleet-wide max of per-machine REAL sample counts, from the
-        effective (M, n) HOST-side weights (fetched once by ``fit``) —
-        the scan-length cap that keeps each machine's optimizer-step
-        count at the solo path's ``ceil(n_train / batch_size)`` instead
-        of the padded grid's. Exact for any weight pattern (a windowed
-        sample counts iff its whole window and target row are real).
+        ``(rows, valid)`` of the effective ``(M, n)`` weights ``w``, on the
+        device (:func:`_weight_facts` under this trainer's window). ``fit``
+        fetches the two ``(M,)`` vectors: ``rows`` is its timestep
+        accounting, and the fleet-wide ``max(valid)`` is the scan-length cap
+        that keeps each machine's optimizer-step count at the solo path's
+        ``ceil(n_train / batch_size)`` instead of the padded grid's.
         """
-        lb = self.spec.lookback_window if self.spec.windowed else 1
-        la = self.lookahead
-        n_samples = self._n_samples(n)
-        r = (np.asarray(w_host) > 0).astype(np.int64)
-        if not self.spec.windowed:
-            return max(1, int(r.sum(axis=1).max()))
-        c = np.concatenate([np.zeros((r.shape[0], 1), dtype=np.int64), r.cumsum(axis=1)], axis=1)
-        win_all = (c[:, lb:] - c[:, :-lb]) == lb      # (M, n - lb + 1)
-        valid = win_all[:, :n_samples] & (r[:, lb - 1 + la : lb - 1 + la + n_samples] > 0)
-        return max(1, int(valid.sum(axis=1).max()))
+        self._n_samples(w.shape[1])  # fails loudly when the grid fits no window
+        lookback = self.spec.lookback_window if self.spec.windowed else None
+        return _weight_facts(w, lookback=lookback, lookahead=self.lookahead)
 
     # -- the compiled epoch ---------------------------------------------
     def _choose_row_fetch(
@@ -1173,8 +1203,8 @@ class FleetTrainer:
         return jax.jit(chunk_program, **jit_kwargs)
 
     def _validation_masks(
-        self, w_host: np.ndarray, n: int, validation_split: float
-    ) -> Tuple[jnp.ndarray, jnp.ndarray, np.ndarray, int, np.ndarray]:
+        self, w, rows: np.ndarray, validation_split: float
+    ) -> Tuple[jnp.ndarray, jnp.ndarray, np.ndarray, int]:
         """
         Per-machine Keras ``validation_split`` semantics as timestep masks:
         the LAST fraction of each machine's samples (windows, for sequence
@@ -1186,24 +1216,24 @@ class FleetTrainer:
         train cut) and validates iff s >= n_train with its whole window
         inside the real region.
 
-        Returns (train_mask, val_mask, has_val, val_lo, train_mask_host):
-        the (M, n) float32 masks (sharded), a (M,) bool marking machines
-        whose split actually yields validation samples (a machine too
-        small for ``n_val >= 1`` has none — its monitored metric must
-        fall back to the training loss, like the solo path with
-        ``n_val == 0``), the smallest first-validation-sample index
-        across machines (so the eval only walks the holdout tail, not
-        the whole dataset), and the host-side train mask so the caller
-        can keep its host weight copy in sync without a second device
-        fetch. ``w_host`` is the caller's already-fetched effective
-        weights.
+        ``w`` is the effective (M, n) weights on the device and ``rows``
+        their fetched real-row counts (``_fit_facts``): the per-machine
+        cuts are float64/int64 host arithmetic on the (M,) counts, and the
+        masks are built on the device from the uploaded cuts.
+
+        Returns (train_mask, val_mask, has_val, val_lo): the (M, n) float32
+        masks (sharded), a (M,) bool marking machines whose split actually
+        yields validation samples (a machine too small for ``n_val >= 1``
+        has none — its monitored metric must fall back to the training
+        loss, like the solo path with ``n_val == 0``), and the smallest
+        first-validation-sample index across machines (so the eval only
+        walks the holdout tail, not the whole dataset).
         """
         lb = self.spec.lookback_window if self.spec.windowed else 1
         la = self.lookahead
-        w_host = np.asarray(w_host, dtype=np.float64)
         # count rows, not weight mass: fractional sample weights must not
         # shift the split boundary
-        n_real = (w_host > 0).sum(axis=1).astype(np.int64)
+        n_real = np.asarray(rows, dtype=np.int64)
         n_samples = np.maximum(n_real - lb + 1 - la, 0)
         n_val = (n_samples * validation_split).astype(np.int64)
         n_train = n_samples - n_val
@@ -1212,28 +1242,19 @@ class FleetTrainer:
                 f"validation_split={validation_split} leaves no training "
                 "samples for at least one machine"
             )
-        t = np.arange(n, dtype=np.int64)[None, :]
         # last timestep a training window touches is s + lb - 1 + la for
         # s = n_train - 1, so the cut excludes exactly samples >= n_train.
         # train_mask is the bare cut indicator — the caller multiplies it
         # into the effective weights, so folding w in here would SQUARE
-        # every non-binary weight
-        train_cut = (n_train + lb - 1 + la)[:, None]
-        train_mask = (t < train_cut).astype(np.float32)
-        # val_mask is used standalone as the eval weight, so it does carry
-        # the effective weights (once)
-        val_mask = (t >= n_train[:, None]).astype(np.float32) * w_host.astype(
-            np.float32
+        # every non-binary weight; val_mask is used standalone as the eval
+        # weight, so it does carry the effective weights (once)
+        train_cut = n_train + lb - 1 + la
+        train_mask, val_mask = _split_masks(
+            w, train_cut.astype(np.int32), n_train.astype(np.int32)
         )
         has_val = n_val > 0
         val_lo = int(n_train[has_val].min()) if has_val.any() else 0
-        return (
-            self._shard(jnp.asarray(train_mask)),
-            self._shard(jnp.asarray(val_mask)),
-            has_val,
-            val_lo,
-            train_mask,
-        )
+        return self._shard(train_mask), self._shard(val_mask), has_val, val_lo
 
     # -- public API ------------------------------------------------------
     @_under_fit_span
@@ -1337,9 +1358,9 @@ class FleetTrainer:
                 )
             if extra_weight is not None:
                 w = w * self._shard(jnp.asarray(extra_weight))
-            # the ONE device->host weight transfer per fit: the validation
-            # split and the sample cap both work from this copy
-            w_host = np.asarray(phases.fetched(host_fetch(w)), dtype=np.float64)
+            # what the fit must know of its weights is counted where they
+            # are; only the two (M,) count vectors cross to the host
+            rows, valid = phases.fetched(host_fetch(self._fit_facts(w)))
 
             val_w = None
             has_val = None
@@ -1349,13 +1370,11 @@ class FleetTrainer:
                 # computed from the EFFECTIVE weights so a CV fold's extra
                 # mask shrinks the split's base, exactly like a solo fold fit
                 # on that fold's rows would
-                train_mask, val_w, has_val, val_lo, train_mask_host = (
-                    self._validation_masks(
-                        w_host, data.n_timesteps, float(validation_split)
-                    )
+                train_mask, val_w, has_val, val_lo = self._validation_masks(
+                    w, rows, float(validation_split)
                 )
                 w = w * train_mask
-                w_host = w_host * train_mask_host
+                rows, valid = phases.fetched(host_fetch(self._fit_facts(w)))
             monitor_val = (
                 val_w is not None
                 if early_stopping_on_val is None
@@ -1458,10 +1477,10 @@ class FleetTrainer:
 
             if self.broadcast_data:
                 # every fleet member trains on the one shared dataset
-                rows_per_machine = np.full(m, int((w_host > 0).sum()), dtype=np.int64)
+                rows_per_machine = np.full(m, int(rows.sum()), dtype=np.int64)
             else:
-                rows_per_machine = (w_host > 0).sum(axis=1).astype(np.int64)
-            sample_cap = self._sample_cap(w_host, data.n_timesteps)
+                rows_per_machine = np.asarray(rows, dtype=np.int64)
+            sample_cap = max(1, int(valid.max()))
             track_best = early_stopping and restore_best_weights
             row_fetch = self._choose_row_fetch(data, batch_size, sample_cap)
 

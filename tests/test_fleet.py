@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from gordo_tpu.builder.fleet_build import FleetModelBuilder
 from gordo_tpu.machine import Machine
@@ -358,12 +359,12 @@ def test_fleet_validation_split_windowed_masks():
     trainer = FleetTrainer(spec, lookahead=0, donate=False)
     w = np.zeros((1, 60), dtype=np.float32)
     w[0, :50] = 1.0  # 50 real rows -> 46 windows
-    import jax.numpy as jnp
-
-    train_m, val_m, has_val, val_lo, train_m_host = trainer._validation_masks(
-        w, 60, 0.25
+    rows, _ = jax.device_get(trainer._fit_facts(jnp.asarray(w)))
+    assert rows.tolist() == [50]
+    train_m, val_m, has_val, val_lo = trainer._validation_masks(
+        jnp.asarray(w), rows, 0.25
     )
-    np.testing.assert_array_equal(train_m_host, np.asarray(train_m))
+    assert train_m.dtype == val_m.dtype == jnp.float32
     train_m, val_m = np.asarray(train_m), np.asarray(val_m)
     assert has_val.tolist() == [True]
     assert val_lo == 35
@@ -372,6 +373,237 @@ def test_fleet_validation_split_windowed_masks():
     # val windows start at sample 35, inside the real region
     assert val_m[0, 35:50].all() and not val_m[0, :35].any()
     assert not val_m[0, 50:].any()
+
+
+# -- what a fit learns of its weights, counted on the device -----------------
+# The oracle is the host arithmetic ``fit`` used before the counts moved to
+# the device: a float64 copy of the fetched (M, n) weights, numpy passes.
+
+
+def oracle_facts(w_host, lookback=None, lookahead=0):
+    """(rows, valid) per machine by the old numpy rule."""
+    r = (np.asarray(w_host, dtype=np.float64) > 0).astype(np.int64)
+    rows = r.sum(axis=1)
+    if lookback is None:
+        return rows, rows
+    n_samples = r.shape[1] - lookback + 1 - lookahead
+    c = np.concatenate(
+        [np.zeros((r.shape[0], 1), dtype=np.int64), r.cumsum(axis=1)], axis=1
+    )
+    win_all = (c[:, lookback:] - c[:, :-lookback]) == lookback
+    target = lookback - 1 + lookahead
+    valid = win_all[:, :n_samples] & (r[:, target : target + n_samples] > 0)
+    return rows, valid.sum(axis=1)
+
+
+def oracle_validation_masks(w_host, validation_split, lookback=None, lookahead=0):
+    """(train_mask, val_mask, has_val, val_lo) by the old numpy rule."""
+    lb = lookback or 1
+    w_host = np.asarray(w_host, dtype=np.float64)
+    n_real = (w_host > 0).sum(axis=1).astype(np.int64)
+    n_samples = np.maximum(n_real - lb + 1 - lookahead, 0)
+    n_val = (n_samples * validation_split).astype(np.int64)
+    n_train = n_samples - n_val
+    t = np.arange(w_host.shape[1], dtype=np.int64)[None, :]
+    train_cut = (n_train + lb - 1 + lookahead)[:, None]
+    train_mask = (t < train_cut).astype(np.float32)
+    val_mask = (t >= n_train[:, None]).astype(np.float32) * w_host.astype(
+        np.float32
+    )
+    has_val = n_val > 0
+    val_lo = int(n_train[has_val].min()) if has_val.any() else 0
+    return train_mask, val_mask, has_val, val_lo
+
+
+class OracleTrainer(FleetTrainer):
+    """A trainer whose fit learns its facts and split masks the old way:
+    the whole effective weights on the host, numpy in float64."""
+
+    def _window(self):
+        if not self.spec.windowed:
+            return {}
+        return {"lookback": self.spec.lookback_window, "lookahead": self.lookahead}
+
+    def _fit_facts(self, w):
+        rows, valid = oracle_facts(jax.device_get(w), **self._window())
+        return rows.astype(np.int32), valid.astype(np.int32)
+
+    def _validation_masks(self, w, rows, validation_split):
+        train_mask, val_mask, has_val, val_lo = oracle_validation_masks(
+            jax.device_get(w), validation_split, **self._window()
+        )
+        return (
+            self._shard(jnp.asarray(train_mask)),
+            self._shard(jnp.asarray(val_mask)),
+            has_val,
+            val_lo,
+        )
+
+
+FACTS_M, FACTS_N = 5, 200
+
+
+def facts_weights(pattern):
+    """Effective (M, n) float32 weights of one named shape."""
+    w = np.ones((FACTS_M, FACTS_N), dtype=np.float32)
+    if pattern == "ragged-prefix":
+        for i in range(FACTS_M):
+            w[i, FACTS_N - 31 * i :] = 0.0
+    elif pattern == "holes":
+        w[0, 50:53] = 0.0
+        w[1, ::7] = 0.0
+        w[2, 0] = 0.0
+        w[3, -1] = 0.0
+        w[4, 100:] = 0.0
+        w[4, 150:160] = 1.0
+    elif pattern == "weightless-machine":
+        w[2] = 0.0
+        w[3, 120:] = 0.0
+    elif pattern == "fractional":
+        w = np.random.default_rng(7).random((FACTS_M, FACTS_N)).astype(np.float32)
+        w[w < 0.2] = 0.0
+        w[1, 140:] = 0.0
+    elif pattern == "fold-mask":
+        # base ragged weights times a CV fold's train mask (extra_weight)
+        w = facts_weights("ragged-prefix")
+        w[:, 60:100] *= 0.0
+    elif pattern == "broadcast":
+        w = w[:1]
+        w[0, 170:] = 0.0
+        w[0, 20:24] = 0.0
+    else:
+        assert pattern == "all-ones", pattern
+    return w
+
+
+FACTS_PATTERNS = (
+    "all-ones", "ragged-prefix", "holes", "weightless-machine", "fractional",
+    "fold-mask", "broadcast",
+)
+FACTS_WINDOWS = (None, (1, 0), (1, 1), (8, 0), (8, 1), (64, 0), (64, 1))
+
+
+@pytest.mark.parametrize("pattern", FACTS_PATTERNS)
+@pytest.mark.parametrize(
+    "window", FACTS_WINDOWS, ids=lambda w: "flat" if w is None else f"lb{w[0]}-la{w[1]}"
+)
+def test_device_weight_facts_equal_the_numpy_rule(window, pattern):
+    """The two (M,) int32 count vectors a fit fetches are the old host
+    arithmetic's, for any weight pattern and window."""
+    from gordo_tpu.models.factories.lstm import lstm_model
+
+    w = facts_weights(pattern)
+    if window is None:
+        trainer = FleetTrainer(feedforward_hourglass(n_features=3))
+        want = oracle_facts(w)
+    else:
+        lookback, lookahead = window
+        trainer = FleetTrainer(
+            lstm_model(n_features=3, lookback_window=lookback),
+            lookahead=lookahead,
+        )
+        want = oracle_facts(w, lookback=lookback, lookahead=lookahead)
+    rows, valid = trainer._fit_facts(jnp.asarray(w))
+    assert rows.dtype == valid.dtype == jnp.int32
+    assert rows.shape == valid.shape == (w.shape[0],)
+    np.testing.assert_array_equal(np.asarray(rows), want[0])
+    np.testing.assert_array_equal(np.asarray(valid), want[1])
+
+
+def test_fit_facts_refuse_a_grid_too_short_for_one_window():
+    from gordo_tpu.models.factories.lstm import lstm_model
+
+    trainer = FleetTrainer(lstm_model(n_features=3, lookback_window=8), lookahead=1)
+    with pytest.raises(ValueError, match="Not enough timesteps"):
+        trainer._fit_facts(jnp.ones((2, 8)))
+
+
+def ragged_fit_case(case):
+    """(data, trainer kwargs, fit kwargs) of one named fit."""
+    Xs, ys = make_fleet_data(m=3, n=90)
+    data = StackedData.from_ragged(Xs, ys, n_timesteps=128)
+    fit_kwargs = {}
+    trainer_kwargs = {}
+    if case in ("extra-weight", "split-under-a-fold"):
+        fold = np.ones((3, 128), dtype=np.float32)
+        fold[:, 30:50] = 0.0
+        fold[1, ::9] = 0.5  # fractional weights must not move any count
+        fit_kwargs["extra_weight"] = fold
+    if case in ("validation-split", "split-under-a-fold", "chunked-split"):
+        fit_kwargs["validation_split"] = 0.2
+    if case in ("epoch-chunk", "chunked-split"):
+        trainer_kwargs["epoch_chunk"] = 2
+    return data, trainer_kwargs, fit_kwargs
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "ragged", "extra-weight", "validation-split", "split-under-a-fold",
+        "epoch-chunk", "chunked-split",
+    ],
+)
+def test_fit_is_bit_identical_to_a_fit_from_host_side_facts(case):
+    """Counting on the device changes no number a fit returns: parameters,
+    losses and ``val_losses_`` equal those of a fit driven by the old host
+    arithmetic (whole weights fetched, float64 numpy, masks uploaded)."""
+    data, trainer_kwargs, fit_kwargs = ragged_fit_case(case)
+    spec = feedforward_hourglass(n_features=3)
+    results = []
+    for cls in (FleetTrainer, OracleTrainer):
+        trainer = cls(spec, donate=False, **trainer_kwargs)
+        params, losses = trainer.fit(
+            data, trainer.machine_keys(3), epochs=3, batch_size=16, **fit_kwargs
+        )
+        results.append((jax.device_get(params), losses, trainer.val_losses_,
+                        trainer.fit_telemetry_["sensor_timesteps_trained"]))
+    (p_new, l_new, v_new, t_new), (p_old, l_old, v_old, t_old) = results
+    for a, b in zip(jax.tree.leaves(p_new), jax.tree.leaves(p_old)):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(l_new, l_old)
+    assert t_new == t_old
+    if "validation_split" in fit_kwargs:
+        np.testing.assert_array_equal(v_new, v_old)
+        assert np.isfinite(v_new).all()
+    else:
+        assert v_new is None and v_old is None
+
+
+@pytest.mark.parametrize("lookahead", [0, 1])
+def test_windowed_split_masks_equal_the_numpy_rule(lookahead):
+    """``_validation_masks`` builds on the device, bit for bit, the masks
+    the host rule built: ragged prefixes, fractional weights, a machine
+    too small for any validation sample, a weightless one."""
+    from gordo_tpu.models.factories.lstm import lstm_model
+
+    lb = 5
+    w = np.zeros((5, 60), dtype=np.float32)
+    w[0, :50] = 1.0
+    w[1, :60] = np.linspace(0.1, 1.0, 60, dtype=np.float32)
+    w[2, :lb + lookahead + 1] = 1.0   # two samples: int(2 * 0.25) == 0 held out
+    w[4, :33] = 0.5                   # machine 3 stays weightless
+    trainer = FleetTrainer(
+        lstm_model(n_features=3, lookback_window=lb), lookahead=lookahead,
+        donate=False,
+    )
+    rows, _ = jax.device_get(trainer._fit_facts(jnp.asarray(w)))
+    train_m, val_m, has_val, val_lo = trainer._validation_masks(
+        jnp.asarray(w), rows, 0.25
+    )
+    want = oracle_validation_masks(w, 0.25, lookback=lb, lookahead=lookahead)
+    np.testing.assert_array_equal(np.asarray(train_m), want[0])
+    np.testing.assert_array_equal(np.asarray(val_m), want[1])
+    np.testing.assert_array_equal(has_val, want[2])
+    assert has_val.tolist() == [True, True, False, False, True]
+    assert val_lo == want[3]
+
+
+def test_validation_split_that_leaves_no_training_samples_is_refused():
+    """The refusal is host arithmetic on the fetched counts: a machine with
+    samples, all of them held out. (``fit`` itself admits no split of 1.)"""
+    trainer = FleetTrainer(feedforward_hourglass(n_features=3), donate=False)
+    with pytest.raises(ValueError, match="leaves no training samples"):
+        trainer._validation_masks(jnp.ones((2, 20)), np.array([20, 2]), 1.0)
 
 
 def test_fleet_val_monitored_early_stopping():

@@ -115,8 +115,10 @@ def test_early_stopping_and_checkpoint_phases(tmp_path, monkeypatch, epoch_chunk
 
 def test_fit_telemetry_books_phases_with_tracing_off(monkeypatch):
     """The perf_counter pairs run whether tracing is on or off, and the bytes
-    are those of the shapes that crossed: the effective weights once, then
-    the loss and healthy histories in the one bulk fetch."""
+    are those of the shapes that crossed: the two (M,) int32 count vectors
+    the fit learns of its weights (real rows, valid samples; the weights
+    themselves stay on the device), then the loss and healthy histories in
+    the one bulk fetch."""
     monkeypatch.delenv(TRACE_LOG_ENV_VAR, raising=False)
     counter = get_registry().counter(
         "gordo_train_host_fetch_bytes_total",
@@ -129,16 +131,98 @@ def test_fit_telemetry_books_phases_with_tracing_off(monkeypatch):
     for key in ("prepare_s", "collect_s", "report_s"):
         assert telemetry[key] > 0, key
     assert telemetry["decide_s"] == telemetry["checkpoint_s"] == 0.0
-    weights = M * N * 4
+    counts = 2 * M * 4
     histories = EPOCHS * M * 4 + EPOCHS * M * 1  # float32 losses, bool healthy
-    assert telemetry["host_fetch_bytes"] == weights + histories
+    assert telemetry["host_fetch_bytes"] == counts + histories
     assert telemetry["n_host_syncs"] == 2
-    assert counter.value(path="fleet") - before == weights + histories
+    assert counter.value(path="fleet") - before == counts + histories
     # the phases are parts of the call, not more than it
     parts = sum(
         telemetry[k] for k in ("prepare_s", "epoch_loop_s", "report_s")
     )
     assert parts <= telemetry["wall_time_s"] + telemetry["report_s"] + 1e-3
+
+
+def test_validation_split_fetches_the_counts_twice():
+    """A split's cuts are host arithmetic on the first counts, and the
+    scan cap needs the counts after the cut: two (M,) pairs, no (M, n)."""
+    trainer = FleetTrainer(feedforward_hourglass(n_features=F))
+    trainer.fit(
+        fleet_data(), trainer.machine_keys(M), epochs=EPOCHS, batch_size=BATCH,
+        validation_split=0.25,
+    )
+    telemetry = trainer.fit_telemetry_
+    histories = EPOCHS * M * (4 + 1 + 4)  # losses, healthy, val losses
+    assert telemetry["host_fetch_bytes"] == 2 * (2 * M * 4) + histories
+    assert telemetry["n_host_syncs"] == 3
+
+
+# -- the optimizer's state, made in one dispatch ------------------------------
+
+
+def counting_optimizer():
+    """An Adam whose ``init`` counts how often it is traced or run."""
+    import optax
+
+    inner = optax.adam(1e-3)
+    calls = []
+
+    def init(params):
+        calls.append(1)
+        return inner.init(params)
+
+    return optax.GradientTransformation(init, inner.update), inner, calls
+
+
+@pytest.mark.parametrize("kind", ["feedforward", "lstm"])
+def test_init_opt_state_is_the_vmapped_init_compiled_once(kind):
+    spec = feedforward_hourglass(n_features=F) if kind == "feedforward" else recurrent_spec()
+    optimizer, inner, calls = counting_optimizer()
+    trainer = FleetTrainer(spec, optimizer=optimizer)
+    params = trainer.init_params(trainer.machine_keys(M), F)
+    want = jax.vmap(inner.init)(params)
+    got = trainer.init_opt_state(params)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.weak_type == b.weak_type
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert len(calls) == 1
+    # the same shapes again: the compiled program, no second trace
+    trainer.init_opt_state(jax.tree.map(lambda leaf: leaf + 1, params))
+    assert len(calls) == 1
+    # another fleet size is another program
+    trainer.init_opt_state(trainer.init_params(trainer.machine_keys(M + 1), F))
+    assert len(calls) == 2
+
+
+@pytest.mark.parametrize(
+    "kind,kwargs",
+    [
+        ("lstm", {"shuffle": False}),
+        ("feedforward-permuting", {"shuffle": True, "row_fetch": "permute_epoch"}),
+    ],
+)
+def test_epoch_program_text_does_not_depend_on_how_the_state_was_made(kind, kwargs):
+    """The epoch program lowers to the same text from the compiled
+    ``init_opt_state`` as from the eager leaf-by-leaf init it replaced (a
+    changed dtype or weak type on any moment would change it)."""
+    spec = recurrent_spec() if kind == "lstm" else feedforward_hourglass(n_features=F)
+    trainer = FleetTrainer(spec, lookahead=0)
+    keys = trainer.machine_keys(M)
+    params = trainer.init_params(keys, F)
+    X = jnp.zeros((M, N, F))
+    epoch_fn = trainer._epoch_fn(N, BATCH, quarantine=True, **kwargs)
+    texts = [
+        epoch_fn.lower(
+            params, opt_state, keys, X, X, jnp.ones((M, N)), jnp.ones((M,), bool)
+        ).as_text()
+        for opt_state in (
+            trainer.init_opt_state(params),
+            jax.vmap(trainer._optimizer.init)(params),
+        )
+    ]
+    assert texts[0] == texts[1]
 
 
 # -- scope names inside the compiled programs ---------------------------------
