@@ -80,7 +80,7 @@ bench-streaming:
 # output-delta evidence
 bench-precision:
 	python benchmarks/fleet_throughput.py --machines 8 --epochs 3 \
-		--sequential-sample 2 --epoch-chunk-sweep "" \
+		--sequential-sample 2 \
 		--precision-sweep float32,bf16 --prefetch-sweep 0,2 \
 		--donation-arms > benchmarks/results_precision_cpu_r15.json
 

@@ -95,15 +95,7 @@ def bench_jax(n_timesteps: int, epochs: int) -> dict:
         # scan); math is identical either way (tests/test_fused_lstm.py)
         schedule=os.environ.get("BENCH_SCHEDULE", "layer"),
     )
-    # BENCH_EPOCH_CHUNK > 1 fuses K epochs into one compiled program (one
-    # dispatch and at most one host sync per chunk) — bit-identical math,
-    # pure scheduling. The timed run's
-    # own dispatch telemetry (fit_telemetry_) lands in the result JSON so
-    # the overhead the chunk amortizes is recorded, not inferred.
-    epoch_chunk = int(os.environ.get("BENCH_EPOCH_CHUNK", "1"))
-    trainer = FleetTrainer(
-        spec, lookahead=0, donate=True, epoch_chunk=epoch_chunk
-    )
+    trainer = FleetTrainer(spec, lookahead=0, donate=True)
     keys = trainer.machine_keys(1)
 
     # compile + warmup
@@ -134,10 +126,8 @@ def bench_jax(n_timesteps: int, epochs: int) -> dict:
         "epochs": epochs,
         "platform": dev.platform,
         "device_kind": dev.device_kind,
-        "epoch_chunk": epoch_chunk,
         # the system's own numbers for the timed fit: how many host
-        # round-trips it paid and what the per-dispatch host overhead was
-        "epochs_per_sync": fit_telemetry.get("epochs_per_sync"),
+        # round-trips it paid and what the host's dispatches cost
         "n_host_syncs": fit_telemetry.get("n_host_syncs"),
         "dispatch_overhead_s": fit_telemetry.get("dispatch_overhead_s"),
         "internal_steady_state_epoch_s": fit_telemetry.get(
@@ -311,8 +301,6 @@ def main():
                 "device_kind": result["device_kind"],
                 "n_timesteps": result["n_timesteps"],
                 "epochs": result["epochs"],
-                "epoch_chunk": result.get("epoch_chunk", 1),
-                "epochs_per_sync": result.get("epochs_per_sync"),
                 "dispatch_overhead_s": result.get("dispatch_overhead_s"),
                 "internal_steady_state_epoch_s": result.get(
                     "internal_steady_state_epoch_s"

@@ -51,7 +51,6 @@ HEADLINE_METRICS = (
 #: knob settings copied from the file's top level into the entry, so
 #: trajectory.json rows remain usable tuning observations
 _KNOB_KEYS = (
-    "epoch_chunk",
     "batch_wait_ms",
     "queue_limit",
     "batch_queue_limit",

@@ -88,62 +88,6 @@ def reconstruction_mae(model, machine) -> float:
     return float(np.abs(np.asarray(predicted) - target).mean())
 
 
-def epoch_chunk_sweep(chunks, n_machines=8, n_rows=512, n_features=4,
-                      epochs=24, batch_size=32):
-    """
-    Sweep ``FleetTrainer(epoch_chunk=K)`` over the given chunk sizes on a
-    synthetic fleet and report each configuration FROM THE SYSTEM'S OWN
-    TELEMETRY (``fit_telemetry_`` — per the roadmap, perf benchmarks
-    consume internal numbers instead of re-measuring externally):
-    steady-state epoch time, steady-state sensor-timesteps/s, and the
-    host-side dispatch overhead the chunking amortizes (one dispatch per
-    K epochs instead of per epoch). Chunking is scheduling-only, so the
-    loss histories are also cross-checked for bit-equality against the
-    K=1 run — a mismatch is reported as a finding, not silently dropped.
-    """
-    import numpy as np
-
-    from gordo_tpu.models.factories.feedforward import feedforward_hourglass
-    from gordo_tpu.parallel.fleet import FleetTrainer, StackedData
-
-    rng = np.random.default_rng(0)
-    Xs = [rng.random((n_rows, n_features)).astype("float32")
-          for _ in range(n_machines)]
-    data = StackedData.from_ragged(Xs, [x.copy() for x in Xs])
-    spec = feedforward_hourglass(n_features=n_features)
-
-    rows = []
-    baseline_losses = None
-    # smallest chunk runs first so every row compares against a real
-    # baseline (an unsorted request would otherwise compare against None)
-    for chunk in sorted(chunks):
-        trainer = FleetTrainer(spec, epoch_chunk=chunk)
-        keys = trainer.machine_keys(n_machines)
-        _, losses = trainer.fit(data, keys, epochs=epochs, batch_size=batch_size)
-        if baseline_losses is None:
-            baseline_losses = losses
-        t = trainer.fit_telemetry_
-        rows.append(
-            {
-                "epoch_chunk": chunk,
-                "epochs_run": t["epochs_run"],
-                "n_dispatches": t["n_dispatches"],
-                "n_host_syncs": t["n_host_syncs"],
-                "epochs_per_sync": t["epochs_per_sync"],
-                "steady_state_epoch_s": t["steady_state_epoch_s"],
-                "steady_state_sensor_timesteps_per_s": t[
-                    "steady_state_sensor_timesteps_per_s"
-                ],
-                "dispatch_overhead_s": t["dispatch_overhead_s"],
-                "dispatch_gap_s_mean": t["dispatch_gap_s_mean"],
-                "losses_bitequal_vs_smallest_chunk": bool(
-                    np.array_equal(losses, baseline_losses)
-                ),
-            }
-        )
-    return rows
-
-
 def _ms_summary(times):
     """mean/p50/p99 of a list of millisecond latencies."""
     ordered = sorted(times)
@@ -328,8 +272,7 @@ def prefetch_sweep(depths, n_machines=8, n_rows=2048, n_features=8,
     Sweep ``prefetch_depth`` over a direct :class:`FleetTrainer` fit:
     depth 0 is the historical single-``device_put`` baseline; depth K
     slices the stacked tensors' host->device transfer
-    (``transfer.device_put_sliced``) and pre-issues the next epoch
-    chunk's batch-order vector. Prefetching moves bytes, never math, so
+    (``transfer.device_put_sliced``). Prefetching moves bytes, never math, so
     loss histories are cross-checked for bit-equality against depth 0.
     ``transfer_overlap_ratio`` is the wall-time fraction the pipelining
     recovered vs depth 0 (clamped at 0 — on CPU "transfer" is a memcpy
@@ -368,7 +311,7 @@ def prefetch_sweep(depths, n_machines=8, n_rows=2048, n_features=8,
         data = StackedData.from_ragged(
             Xs, [x.copy() for x in Xs], prefetch_depth=depth
         )
-        trainer = FleetTrainer(spec, prefetch_depth=depth)
+        trainer = FleetTrainer(spec)
         keys = trainer.machine_keys(n_machines)
         _, losses = trainer.fit(data, keys, epochs=epochs,
                                 batch_size=batch_size)
@@ -509,20 +452,6 @@ def main():
         "transformer/tcn).",
     )
     parser.add_argument(
-        "--epoch-chunk",
-        type=int,
-        default=1,
-        help="epoch_chunk for the fleet build's trainers (K epochs fused "
-        "into one compiled program, one host sync per chunk).",
-    )
-    parser.add_argument(
-        "--epoch-chunk-sweep",
-        default="1,4,8",
-        help="Comma-separated epoch_chunk sizes for the direct "
-        "FleetTrainer sweep reported from fit_telemetry_ "
-        "('' disables it).",
-    )
-    parser.add_argument(
         "--precision-sweep",
         default="",
         metavar="MODE[,MODE...]",
@@ -559,15 +488,9 @@ def main():
     machines = make_machines(args.machines, args.epochs, args.buckets, args.kind)
 
     start = time.perf_counter()
-    fleet_builder = FleetModelBuilder(machines, epoch_chunk=args.epoch_chunk)
+    fleet_builder = FleetModelBuilder(machines)
     fleet_results = fleet_builder.build()
     fleet_s = time.perf_counter() - start
-
-    chunk_sweep = None
-    if args.epoch_chunk_sweep:
-        chunk_sweep = epoch_chunk_sweep(
-            [int(c) for c in args.epoch_chunk_sweep.split(",")]
-        )
 
     prec_sweep = None
     if args.precision_sweep:
@@ -656,10 +579,6 @@ def main():
                 "buckets": args.buckets,
                 "epochs": args.epochs,
                 "kind": args.kind,
-                "epoch_chunk": args.epoch_chunk,
-                # per-chunk-size fit telemetry (steady epoch time, host
-                # dispatch overhead, epochs-per-sync) from fit_telemetry_
-                **({"epoch_chunk_sweep": chunk_sweep} if chunk_sweep else {}),
                 # per-precision-mode build/calibration/dispatch arms,
                 # float32 first (the parity baseline)
                 **({"precision_sweep": prec_sweep} if prec_sweep else {}),

@@ -913,8 +913,8 @@ def collect_metric_names(tree: ast.Module) -> typing.Set[str]:
     ``check_metric_registrations`` disciplines. Used by the catalogue
     sync check (tests/test_static.py): a metric registered in code but
     absent from docs/observability.md's catalogue is a doc drift, the
-    failure mode that would otherwise let new telemetry (e.g. the
-    epoch-chunk dispatch/sync metrics) ship undocumented.
+    failure mode that would otherwise let new telemetry (e.g. a fit's
+    dispatch/sync metrics) ship undocumented.
     """
     names: typing.Set[str] = set()
     for node in ast.walk(tree):

@@ -22,8 +22,8 @@ hand:
                          ``jax.device_get``, ``block_until_ready``, and
                          ``float()/int()``/``np.asarray`` applied to
                          values produced by a jitted handle. Each one
-                         stalls the dispatch pipeline per iteration —
-                         the budget ``epoch_chunk`` exists to protect.
+                         stalls the asynchronous dispatch pipeline
+                         once per iteration.
 - ``prng-reuse``         a key name passed to two or more consuming
                          calls without an intervening ``split``/
                          ``fold_in`` rebinding — correlated streams.
@@ -430,7 +430,7 @@ def check_host_sync(tree: ast.Module) -> typing.List[str]:
                     f"device->host once per loop iteration — batch the "
                     f"fetch after the loop (or route it through "
                     f"host_fetch outside the hot loop); per-iteration "
-                    f"syncs regress the epoch_chunk sync budget"
+                    f"syncs stall the asynchronous dispatch pipeline"
                 )
     return problems
 

@@ -135,13 +135,6 @@ class FleetModelBuilder:
         Device mesh to shard fleets over; None = single default device.
     data_threads
         Thread-pool width for the I/O-bound data-fetch phase.
-    epoch_chunk
-        Default number of epochs fused into one compiled program per
-        bucket fit (``FleetTrainer(epoch_chunk=...)``): chunked fits pay
-        one host sync per K epochs instead of per epoch — the lever that
-        matters on DCN-attached backends. A machine config may
-        override it per bucket with an ``epoch_chunk`` fit arg on its
-        estimator. Scheduling only; results are bit-identical.
     on_error
         Per-machine failure policy (docs/robustness.md). ``"raise"``
         (default, the reference's semantics): the first machine whose
@@ -207,8 +200,8 @@ class FleetModelBuilder:
     prefetch_depth
         Host->device transfer pipelining depth (default 0 = off, the
         historical bit-identical path). >0 double-buffers the builder's
-        per-bucket stacked-data transfer and the trainer's per-chunk
-        transfers (docs/performance.md "transfer pipelining").
+        per-bucket stacked-data transfer (docs/performance.md "transfer
+        pipelining").
     """
 
     def __init__(
@@ -217,7 +210,6 @@ class FleetModelBuilder:
         mesh=None,
         data_threads: int = 8,
         auto_mesh: bool = False,
-        epoch_chunk: int = 1,
         on_error: str = "raise",
         fetch_retries: int = 2,
         fetch_timeout: Optional[float] = None,
@@ -235,7 +227,6 @@ class FleetModelBuilder:
             mesh = auto_device_mesh()
         self.mesh = mesh
         self.data_threads = data_threads
-        self.epoch_chunk = max(1, int(epoch_chunk))
         if on_error not in ("raise", "skip"):
             raise ValueError(
                 f"on_error must be 'raise' or 'skip', got {on_error!r}"
@@ -1124,23 +1115,12 @@ class FleetModelBuilder:
         epochs = int(fit_args.get("epochs", 1))
         batch_size = int(fit_args.get("batch_size", 32))
         es_kwargs = self._early_stopping_kwargs(fit_args)
-        # machine-level epoch_chunk (uniform per bucket: buckets are keyed
-        # by the model definition) wins over the builder-wide default —
-        # including a config's explicit 0/1 ("this bucket trains
-        # per-epoch"), which `or` would silently discard
-        config_chunk = fit_args.get("epoch_chunk")
-        epoch_chunk = max(
-            1,
-            int(self.epoch_chunk if config_chunk is None else config_chunk),
-        )
 
         trainer = FleetTrainer(
             spec,
             lookahead=lookahead,
             mesh=self.mesh,
-            epoch_chunk=epoch_chunk,
             fault_sites=self.fault_sites,
-            prefetch_depth=self.prefetch_depth,
         )
         # Per-machine PRNG keys are the SOLO path's init key for the
         # machine's evaluation seed (models/core.py: solo_init_key) —

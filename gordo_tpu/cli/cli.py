@@ -296,19 +296,6 @@ def build(
     "a runtime crash completes the fleet instead of restarting it.",
 )
 @click.option(
-    "--epoch-chunk",
-    type=click.IntRange(min=1),
-    default=1,
-    envvar="GORDO_EPOCH_CHUNK",
-    show_default=True,
-    help="Fuse this many training epochs into ONE compiled program per "
-    "bucket fit (one host sync per chunk instead of per epoch — the "
-    "lever where dispatch latency dominates, e.g. DCN-attached "
-    "backends). Results are "
-    "bit-identical to per-epoch dispatch; a machine config may override "
-    "per bucket with an 'epoch_chunk' fit arg.",
-)
-@click.option(
     "--on-error",
     type=click.Choice(["raise", "skip"]),
     default="raise",
@@ -396,15 +383,13 @@ def build(
     help="Host->device transfer pipelining depth (docs/performance.md "
     "'transfer pipelining'): 0 is the historical single-transfer path "
     "(bit-identical); >0 double-buffers the builder's stacked-data "
-    "transfer and the trainer's per-chunk transfers so transfer k+1 "
-    "rides under dispatch k.",
+    "transfer so later slices stream while the first is consumed.",
 )
 @_with_build_options
 def build_fleet(
     machines_config: list,
     output_dir: str,
     resume: bool,
-    epoch_chunk: int,
     on_error: str,
     bucket_policy: str,
     precision: str,
@@ -464,7 +449,6 @@ def build_fleet(
             # (max_attempts, fetch retries/timeouts) never get profile
             # recommendations, by registry declaration
             {
-                "epoch_chunk": "epoch_chunk",
                 "bucket_policy": "bucket_policy",
                 "build_workers": "workers",
                 "lease_ttl": "lease_ttl",
@@ -473,7 +457,6 @@ def build_fleet(
             },
             subsystem="builder",
         )
-        epoch_chunk = profile_overrides.get("epoch_chunk", epoch_chunk)
         bucket_policy = profile_overrides.get("bucket_policy", bucket_policy)
         lease_ttl = profile_overrides.get("lease_ttl", lease_ttl)
         precision = profile_overrides.get("precision", precision)
@@ -497,7 +480,6 @@ def build_fleet(
                 "--workers", str(n_workers),
                 "--lease-ttl", str(lease_ttl),
                 "--max-attempts", str(max_attempts),
-                "--epoch-chunk", str(epoch_chunk),
                 "--on-error", on_error,
                 "--fetch-retries", str(fetch_retries),
                 "--bucket-policy", bucket_policy,
@@ -560,7 +542,6 @@ def build_fleet(
             machines.append(machine)
         builder = FleetModelBuilder(
             machines,
-            epoch_chunk=epoch_chunk,
             on_error=on_error,
             fetch_retries=fetch_retries,
             fetch_timeout=fetch_timeout,
@@ -758,15 +739,6 @@ def get_all_score_strings(machine) -> List[str]:
 @click.option("--epochs", type=int, default=None, help="Override model epochs")
 @click.option("--batch-size", type=int, default=None, help="Override batch size")
 @click.option(
-    "--epoch-chunk",
-    type=click.IntRange(min=1),
-    default=None,
-    envvar="GORDO_EPOCH_CHUNK",
-    help="Fuse this many epochs into one compiled program (default: the "
-    "machine config's 'epoch_chunk' fit arg, else per-epoch dispatch). "
-    "Bit-identical results, one host sync per chunk.",
-)
-@click.option(
     "--exceptions-reporter-file",
     envvar="EXCEPTIONS_REPORTER_FILE",
     help="JSON output file for exception information",
@@ -783,7 +755,6 @@ def sweep_cli(
     grid_params,
     epochs,
     batch_size,
-    epoch_chunk,
     exceptions_reporter_file,
     exceptions_report_level,
 ):
@@ -851,11 +822,6 @@ def sweep_cli(
             grid,
             lookahead=estimator.lookahead if spec.windowed else 0,
             mesh=auto_device_mesh(),
-            epoch_chunk=(
-                epoch_chunk
-                if epoch_chunk is not None
-                else int(estimator.kwargs.get("epoch_chunk", 1))
-            ),
         )
         # same regime as build/build-fleet (core.py fit defaults), so the
         # winning hyperparameters transfer to the build that uses them

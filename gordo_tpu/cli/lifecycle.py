@@ -29,7 +29,6 @@ def _config_from_params(params: dict):
         ratio_threshold=params["ratio_threshold"],
         exceedance_threshold=params["exceedance_threshold"],
         min_observations=params["min_observations"],
-        epoch_chunk=params["epoch_chunk"],
         fetch_retries=params["fetch_retries"],
         fetch_timeout=params["fetch_timeout"],
         stream_observations=params["stream_observations"],
@@ -85,14 +84,6 @@ _tick_options = [
     click.option(
         "--min-observations", type=int, default=1, show_default=True,
         help="Observations before a machine may be declared drifted.",
-    ),
-    click.option(
-        "--epoch-chunk",
-        type=int,
-        default=1,
-        envvar="GORDO_EPOCH_CHUNK",
-        show_default=True,
-        help="Epochs fused per refit dispatch (FleetTrainer epoch_chunk).",
     ),
     click.option(
         "--fetch-retries",

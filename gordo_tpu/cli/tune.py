@@ -40,18 +40,6 @@ def tune_cli():
     knob defaults from recorded telemetry."""
 
 
-def _comma_ints(raw: str, flag: str) -> typing.List[int]:
-    try:
-        values = [int(part) for part in raw.split(",") if part.strip()]
-    except ValueError:
-        raise click.BadParameter(
-            f"{flag} must be comma-separated integers, got {raw!r}"
-        )
-    if not values:
-        raise click.BadParameter(f"{flag} lists no values")
-    return values
-
-
 def _comma_floats(raw: str, flag: str) -> typing.List[float]:
     try:
         values = [float(part) for part in raw.split(",") if part.strip()]
@@ -250,45 +238,10 @@ def tune_fit(corpus: typing.Tuple[str, ...], out: str):
     type=click.Path(exists=False, file_okay=False, dir_okay=True),
 )
 @click.option(
-    "--epoch-chunks",
-    default="1,4,8",
-    show_default=True,
-    help="epoch_chunk arms to sweep on the synthetic calibration fleet.",
-)
-@click.option(
-    "--machines",
-    type=click.IntRange(min=1),
-    default=4,
-    show_default=True,
-    help="Synthetic fleet size for the training sweep.",
-)
-@click.option(
-    "--rows",
-    type=click.IntRange(min=16),
-    default=256,
-    show_default=True,
-    help="Sensor rows per synthetic machine.",
-)
-@click.option(
-    "--epochs",
-    type=click.IntRange(min=2),
-    default=8,
-    show_default=True,
-    help="Training epochs per sweep arm.",
-)
-@click.option(
-    "--batch-size",
-    type=click.IntRange(min=1),
-    default=32,
-    show_default=True,
-    help="Training batch size.",
-)
-@click.option(
     "--batch-wait-sweep",
-    default=None,
-    help="Optional --batch-wait-ms arms (comma-separated ms) to sweep "
-    "against an in-process server under open-loop load; heavier, so "
-    "off by default.",
+    required=True,
+    help="The --batch-wait-ms arms (comma-separated ms) to sweep against "
+    "an in-process server under open-loop load.",
 )
 @click.option(
     "--rps",
@@ -314,20 +267,14 @@ def tune_fit(corpus: typing.Tuple[str, ...], out: str):
 )
 def tune_calibrate(
     output_dir: str,
-    epoch_chunks: str,
-    machines: int,
-    rows: int,
-    epochs: int,
-    batch_size: int,
     batch_wait_sweep: str,
     rps: float,
     duration: float,
     do_fit: bool,
 ):
     """
-    Measure a fresh corpus for a fleet that has none: a short
-    ``epoch_chunk`` sweep (fleet_throughput's machinery as a library),
-    optionally a ``--batch-wait-ms`` open-loop serving sweep, written to
+    Measure a fresh corpus for a fleet that has none: a
+    ``--batch-wait-ms`` open-loop serving sweep, written to
     OUTPUT-DIR/results_calibration.json — then (by default) fit the
     profile from it.
     """
@@ -336,21 +283,11 @@ def tune_calibrate(
         run_calibration,
     )
 
-    chunks = _comma_ints(epoch_chunks, "--epoch-chunks")
-    waits = (
-        _comma_floats(batch_wait_sweep, "--batch-wait-sweep")
-        if batch_wait_sweep
-        else None
-    )
+    waits = _comma_floats(batch_wait_sweep, "--batch-wait-sweep")
     Path(output_dir).mkdir(parents=True, exist_ok=True)
     try:
         path, _ = run_calibration(
             output_dir,
-            epoch_chunks=chunks,
-            n_machines=machines,
-            n_rows=rows,
-            epochs=epochs,
-            batch_size=batch_size,
             batch_wait_sweep=waits,
             rps=rps,
             duration=duration,

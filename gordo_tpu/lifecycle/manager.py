@@ -67,8 +67,6 @@ class LifecycleConfig:
     ratio_threshold: float = 1.0
     exceedance_threshold: float = 0.5
     min_observations: int = 1
-    #: refit fit fusion (FleetTrainer epoch_chunk), like build-fleet
-    epoch_chunk: int = 1
     fetch_retries: int = 1
     #: per-machine cap (seconds) on BOTH the drift-scan window fetch
     #: and the refit build's fetches — one hung data-source connection
@@ -641,7 +639,6 @@ class LifecycleManager:
 
         builder = FleetModelBuilder(
             refit_machines,
-            epoch_chunk=self.config.epoch_chunk,
             on_error="skip",  # one poisoned machine must not kill the cycle
             fetch_retries=self.config.fetch_retries,
             fetch_timeout=self.config.fetch_timeout,

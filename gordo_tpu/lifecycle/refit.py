@@ -3,11 +3,10 @@ Warm-start refit + shadow scoring (docs/lifecycle.md).
 
 The refit itself is just a :class:`FleetModelBuilder` run over the
 drifted subset with ``initial_params`` = the served revision's stacked
-params (``FleetTrainer.fit(params=...)``, ``epoch_chunk``-fused like
-any other build) and ``fault_sites=("train", "refit")`` so the chaos
-harness can poison refit builds specifically. This module holds the
-pieces around it: extracting warm params from served artifacts, and the
-shadow-scoring gate that decides promotion.
+params (``FleetTrainer.fit(params=...)``) and ``fault_sites=("train",
+"refit")`` so the chaos harness can poison refit builds specifically.
+This module holds the pieces around it: extracting warm params from
+served artifacts, and the shadow-scoring gate that decides promotion.
 """
 
 import dataclasses

@@ -131,9 +131,9 @@ class BaseJaxEstimator(GordoBase, BaseEstimator):
         "callbacks",
         "validation_split",
         "shuffle",
-        # fleet-only scheduling knob (FleetTrainer epoch fusion): listed
-        # here so machine configs can carry it without it leaking into
-        # the model factory's kwargs; the solo per-epoch fit ignores it
+        # accepted from older machine configs and ignored (it once chose
+        # a training schedule): listed here so that a config carrying it
+        # still builds and the key never reaches a model factory
         "epoch_chunk",
         "class_weight",
         "initial_epoch",
@@ -390,8 +390,8 @@ class BaseJaxEstimator(GordoBase, BaseEstimator):
             )
             # the solo path syncs per epoch BY CONTRACT: the Keras-style
             # callback protocol below consumes host floats every epoch
-            # (early stopping, checkpoints). The fleet path is the one
-            # that amortizes syncs (FleetTrainer epoch_chunk).
+            # (early stopping, checkpoints). The fleet path keeps its
+            # losses on the device and fetches them once after the loop.
             losses.append(float(epoch_loss))  # lint: disable=host-sync
             logs = {"loss": losses[-1]}
             if n_val:

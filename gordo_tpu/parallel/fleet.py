@@ -64,9 +64,7 @@ def _keep_better(mask, new_tree, old_tree):
     Module-level and jitted ONCE: it used to be redefined inside every
     ``fit`` call, so each fit re-traced it; the jit cache is keyed on
     tree structure/shapes, so all fits sharing a geometry now reuse one
-    compiled select. This is the host-path early-stopping fallback — the
-    chunked path (``epoch_chunk > 1``) does the same masked snapshot
-    in-program.
+    compiled select (``restore_best_weights``' per-machine snapshot).
     """
 
     def select(new_leaf, old_leaf):
@@ -195,7 +193,7 @@ def _under_fit_span(fit):
     def traced_fit(self, data, keys, epochs=1, batch_size=32, *args, **kwargs):
         with tracing.start_span(
             "train.fit", n_machines=len(keys), epochs=epochs,
-            batch_size=batch_size, epoch_chunk=self.epoch_chunk,
+            batch_size=batch_size,
         ):
             return fit(self, data, keys, epochs, batch_size, *args, **kwargs)
 
@@ -273,8 +271,6 @@ class StackedData:
         # needlessly special-case the masked loss's normalizer
         fw[len(Xs):] = 1.0
         if prefetch_depth > 0:
-            from gordo_tpu.parallel import transfer
-
             return cls(
                 transfer.device_put_sliced(X, prefetch_depth, plane="build"),
                 transfer.device_put_sliced(y, prefetch_depth, plane="build"),
@@ -326,23 +322,6 @@ class FleetTrainer:
         (hyperparameter sweeps): ``fit`` takes a single-machine
         StackedData and the epoch vmaps with ``in_axes=None`` for the
         data, so device memory holds one copy instead of M.
-    prefetch_depth
-        When > 0, a chunked fit issues chunk k+1's per-chunk
-        host->device transfer (the epoch-index vector) while chunk k's
-        program is still running (docs/performance.md "transfer
-        pipelining"). Scheduling only — bits are identical to the
-        default 0.
-    epoch_chunk
-        Number of epochs fused into ONE compiled program (an outer
-        ``lax.scan`` over the per-epoch program). With the default 1,
-        ``fit`` dispatches one program per epoch from a Python loop;
-        with K > 1 the whole training loop — per-epoch ``fold_in`` key
-        derivation, validation loss, the early-stopping state machine
-        and the ``restore_best_weights`` snapshot — lives on device, and
-        a monitored fit syncs to host once per CHUNK instead of once per
-        epoch (an unmonitored fit syncs only at fit end). Scheduling
-        only: results are bit-identical to ``epoch_chunk=1``; a stopped
-        fleet wastes at most K-1 gated (no-op) epochs of device work.
     quarantine_nonfinite
         In-program non-finite guard (docs/robustness.md): a per-machine
         ``healthy`` flag rides the compiled program, and a machine whose
@@ -365,10 +344,8 @@ class FleetTrainer:
         scan_unroll: int = 1,
         optimizer: Optional[Any] = None,
         broadcast_data: bool = False,
-        epoch_chunk: int = 1,
         quarantine_nonfinite: bool = True,
         fault_sites: Tuple[str, ...] = ("train",),
-        prefetch_depth: int = 0,
     ):
         self.spec = spec
         self.lookahead = int(lookahead) if spec.windowed else 0
@@ -376,19 +353,13 @@ class FleetTrainer:
         self.donate = donate
         self.scan_unroll = max(1, int(scan_unroll))
         self.broadcast_data = broadcast_data
-        self.epoch_chunk = max(1, int(epoch_chunk))
         self.quarantine_nonfinite = bool(quarantine_nonfinite)
-        #: double-buffer the per-chunk host->device transfers of a
-        #: chunked fit: chunk k+1's argument transfer is issued while
-        #: chunk k's program runs (parallel/transfer.py). 0 = off, the
-        #: historical (bit-identical) path.
-        self.prefetch_depth = max(0, int(prefetch_depth))
         #: GORDO_FAULT_INJECT sites whose nan-mode specs poison this
         #: trainer's fits ("train" everywhere; lifecycle warm-start
         #: refits add "refit" so refit:nan targets refit builds only)
         self.fault_sites = tuple(fault_sites)
         self._optimizer = optimizer if optimizer is not None else spec.make_optimizer()
-        # ALL compiled/raw program handles (epoch, val, chunk, predict)
+        # ALL compiled program handles (epoch, val, predict, opt_init)
         # live in the one ProgramCache (docs/performance.md "AOT
         # executable cache") — LRU + HBM-aware bounded, hit/miss/evict
         # telemetry for free, and no per-site ad-hoc dicts
@@ -574,7 +545,7 @@ class FleetTrainer:
         )
 
         def build():
-            fleet_epoch = self._epoch_callable(
+            fleet_epoch = self._build_epoch_callable(
                 n, batch_size, shuffle, gated, n_batches,
                 quarantine=quarantine, inject=inject, masked=masked,
                 row_fetch=row_fetch,
@@ -595,44 +566,6 @@ class FleetTrainer:
 
         return self._programs.get_or_build(cache_key, build)
 
-    def _epoch_callable(
-        self,
-        n: int,
-        batch_size: int,
-        shuffle: bool,
-        gated: bool,
-        n_batches: int,
-        quarantine: bool = False,
-        inject: bool = False,
-        masked: bool = False,
-        row_fetch: str = "gather",
-    ):
-        """
-        The RAW (un-jitted) vmapped fleet-epoch callable for a geometry,
-        cached so the per-epoch jit wrapper (``_epoch_fn``) and the fused
-        multi-epoch chunk program (``_chunk_fn``) trace the IDENTICAL
-        computation — chunking must be a scheduling change, not a
-        numerics change.
-
-        Per-machine extras ride after the data args in a fixed order:
-        ``active`` (``gated``), ``healthy`` (``quarantine``), the
-        NaN-poison flag (``inject``), and the (f_out,) feature-column
-        weight (``masked``); quarantine variants return the updated
-        ``healthy`` as a fourth output.
-        """
-        cache_key = (
-            "epoch_raw", n, batch_size, shuffle, gated, n_batches,
-            quarantine, inject, masked, row_fetch,
-        )
-        return self._programs.get_or_build(
-            cache_key,
-            lambda: self._build_epoch_callable(
-                n, batch_size, shuffle, gated, n_batches,
-                quarantine=quarantine, inject=inject, masked=masked,
-                row_fetch=row_fetch,
-            ),
-        )
-
     def _build_epoch_callable(
         self,
         n: int,
@@ -646,7 +579,14 @@ class FleetTrainer:
         row_fetch: str = "gather",
     ):
         """
-        The uncached body of :meth:`_epoch_callable`.
+        The vmapped fleet-epoch callable for a geometry, un-jitted
+        (:meth:`_epoch_fn` jits and caches it).
+
+        Per-machine extras ride after the data args in a fixed order:
+        ``active`` (``gated``), ``healthy`` (``quarantine``), the
+        NaN-poison flag (``inject``), and the (f_out,) feature-column
+        weight (``masked``); quarantine variants return the updated
+        ``healthy`` as a fourth output.
 
         What the scope ``fleet.gather`` holds depends on ``row_fetch``.
         ``"gather"``: inside every step, the three row gathers ``Xi[sel]``,
@@ -874,13 +814,13 @@ class FleetTrainer:
         self, n: int, batch_size: int, lo: int = 0, masked: bool = False
     ):
         """
-        Jitted per-machine validation loss over the fleet (the raw
-        callable, ``_val_callable``, is shared with the chunk program).
+        Build (and cache) the jitted per-machine validation loss over the
+        fleet (:meth:`_build_val_callable`).
         """
         cache_key = ("val", n, batch_size, lo, masked)
 
         def build():
-            fleet_val = self._val_callable(n, batch_size, lo, masked)
+            fleet_val = self._build_val_callable(n, batch_size, lo, masked)
             jit_kwargs: dict = {}
             if self.mesh is not None:
                 fs = fleet_sharding(self.mesh)
@@ -895,11 +835,11 @@ class FleetTrainer:
 
         return self._programs.get_or_build(cache_key, build)
 
-    def _val_callable(
+    def _build_val_callable(
         self, n: int, batch_size: int, lo: int = 0, masked: bool = False
     ):
         """
-        The raw vmapped per-machine validation loss: deterministic
+        The vmapped per-machine validation loss, un-jitted: deterministic
         forward, per-sample loss weighted by a (M, n) validation mask —
         chunked like the training scan so the windowed gather never
         materializes more than (batch, lb, f) at once (mirrors the solo
@@ -911,16 +851,6 @@ class FleetTrainer:
         same per-machine (f_out,) feature-column weight the training
         epoch does, so a padded machine's val loss ignores pad columns.
         """
-        cache_key = ("val_raw", n, batch_size, lo, masked)
-        return self._programs.get_or_build(
-            cache_key,
-            lambda: self._build_val_callable(n, batch_size, lo, masked),
-        )
-
-    def _build_val_callable(
-        self, n: int, batch_size: int, lo: int = 0, masked: bool = False
-    ):
-        """The uncached body of :meth:`_val_callable`."""
         spec = self.spec
         lb = spec.lookback_window if spec.windowed else 1
         la = self.lookahead
@@ -977,230 +907,6 @@ class FleetTrainer:
         if masked:
             in_axes = in_axes + (0,)
         return jax.vmap(machine_val, in_axes=in_axes)
-
-    def _chunk_fn(
-        self,
-        n: int,
-        batch_size: int,
-        shuffle: bool,
-        *,
-        chunk_len: int,
-        sample_cap: Optional[int],
-        with_val: bool,
-        val_lo: int,
-        gated: bool,
-        track_best: bool,
-        monitor_val: bool,
-        es_delta: float = 0.0,
-        es_stop_at: int = 1,
-        es_start_from: int = 0,
-        quarantine: bool = False,
-        inject: bool = False,
-        masked: bool = False,
-        row_fetch: str = "gather",
-    ):
-        """
-        Build (and cache) the fused multi-epoch program: an outer
-        ``lax.scan`` over ``chunk_len`` epoch indices around the SAME raw
-        epoch callable the per-epoch path jits, with per-epoch PRNG key
-        derivation (``fold_in``), the validation pass, the early-stopping
-        state machine (``best``/``wait``/``active``/``last_loss`` as
-        device arrays) and the ``restore_best_weights`` masked param
-        snapshot all inside the one jitted program. The host syncs once
-        per chunk (early stopping) or never (plain fits) — see ``fit``.
-
-        The program takes the chunk's absolute epoch ids as a dynamic
-        (chunk_len,) array, so every same-length chunk of a fit reuses
-        one compiled program regardless of position in the schedule.
-        """
-        n_batches = self._n_batches(n, batch_size, sample_cap)
-        cache_key = (
-            "chunk", n, batch_size, shuffle, chunk_len, n_batches, with_val,
-            val_lo, gated, track_best, monitor_val,
-            float(es_delta), int(es_stop_at), int(es_start_from),
-            quarantine, inject, masked, row_fetch,
-        )
-        return self._programs.get_or_build(
-            cache_key,
-            lambda: self._build_chunk_fn(
-                n, batch_size, shuffle,
-                chunk_len=chunk_len, n_batches=n_batches, with_val=with_val,
-                val_lo=val_lo, gated=gated, track_best=track_best,
-                monitor_val=monitor_val, es_delta=es_delta,
-                es_stop_at=es_stop_at, es_start_from=es_start_from,
-                quarantine=quarantine, inject=inject, masked=masked,
-                row_fetch=row_fetch,
-            ),
-        )
-
-    def _build_chunk_fn(
-        self,
-        n: int,
-        batch_size: int,
-        shuffle: bool,
-        *,
-        chunk_len: int,
-        n_batches: int,
-        with_val: bool,
-        val_lo: int,
-        gated: bool,
-        track_best: bool,
-        monitor_val: bool,
-        es_delta: float,
-        es_stop_at: int,
-        es_start_from: int,
-        quarantine: bool,
-        inject: bool,
-        masked: bool,
-        row_fetch: str = "gather",
-    ):
-        """The uncached body of :meth:`_chunk_fn`."""
-        fleet_epoch = self._epoch_callable(
-            n, batch_size, shuffle, gated, n_batches,
-            quarantine=quarantine, inject=inject, masked=masked,
-            row_fetch=row_fetch,
-        )
-        fleet_val = (
-            self._val_callable(n, batch_size, val_lo, masked)
-            if with_val
-            else None
-        )
-
-        def chunk_program(params, opt_state, keys, X, y, w, epoch_ids, *rest):
-            rest = list(rest)
-            val_w = rest.pop(0) if with_val else None
-            fm_all = rest.pop(0) if masked else None  # (M, f_out)
-            carry = {"params": params, "opt": opt_state}
-            has_val = None
-            if quarantine:
-                carry["healthy"] = rest.pop(0)  # (M,) bool
-            if gated:
-                carry["es"] = {
-                    "active": rest.pop(0),  # (M,) bool
-                    "best": rest.pop(0),    # (M,) f32
-                    "wait": rest.pop(0),    # (M,) i32
-                    "last": rest.pop(0),    # (M,) f32
-                }
-                if monitor_val:
-                    has_val = rest.pop(0)   # (M,) bool
-            inj_mask = inj_epoch = None
-            if inject:
-                inj_mask = rest.pop(0)      # (M,) bool
-                inj_epoch = rest.pop(0)     # scalar i32
-            if track_best:
-                carry["best_params"] = rest.pop(0)
-                carry["ever_improved"] = rest.pop(0)  # scalar bool
-
-            def step(carry, epoch_id):
-                # the in-program replica of the host loop's per-epoch key
-                # derivation (fold_in is trace-invariant, so the streams
-                # are bit-identical to the host-side vmap dispatch)
-                epoch_keys = jax.vmap(
-                    lambda k: jax.random.fold_in(k, epoch_id)
-                )(keys)
-                new = dict(carry)
-                outs = {}
-                extras = []
-                if gated:
-                    es = carry["es"]
-                    extras.append(es["active"].astype(jnp.float32))
-                if quarantine:
-                    extras.append(carry["healthy"])
-                if inject:
-                    # same per-machine flag the per-epoch loop computes
-                    # on host: poison only at the configured epoch
-                    extras.append(inj_mask & (epoch_id == inj_epoch))
-                if masked:
-                    extras.append(fm_all)
-                result = fleet_epoch(
-                    carry["params"], carry["opt"], epoch_keys,
-                    X, y, w, *extras,
-                )
-                if quarantine:
-                    p, o, loss, healthy_out = result
-                    new["healthy"] = healthy_out
-                    outs["healthy"] = healthy_out
-                else:
-                    p, o, loss = result
-                new["params"], new["opt"] = p, o
-                vloss = None
-                if with_val:
-                    vloss = (
-                        fleet_val(p, X, y, val_w, fm_all)
-                        if masked
-                        else fleet_val(p, X, y, val_w)
-                    )
-                    outs["val"] = vloss
-                if gated:
-                    # a stopped machine's computed loss reflects a
-                    # discarded would-be update; report its last active
-                    # loss instead (same select as the host loop)
-                    report = jnp.where(es["active"], loss, es["last"])
-                    monitored = (
-                        jnp.where(has_val, vloss, loss) if monitor_val else loss
-                    )
-                    do_update = epoch_id >= es_start_from
-                    improved = (
-                        es["active"]
-                        & (monitored < es["best"] - es_delta)
-                        & do_update
-                    )
-                    best = jnp.where(improved, monitored, es["best"])
-                    wait = jnp.where(
-                        do_update,
-                        jnp.where(improved, 0, es["wait"] + 1),
-                        es["wait"],
-                    )
-                    active = jnp.where(
-                        do_update, es["active"] & (wait < es_stop_at),
-                        es["active"],
-                    )
-                    new["es"] = {
-                        "active": active, "best": best,
-                        "wait": wait, "last": report,
-                    }
-                    outs["loss"] = report
-                    outs["active"] = active
-                    if track_best:
-                        # host semantics: until the first improving epoch
-                        # best_params "is None" and the fallback for
-                        # non-improved machines is the CURRENT params;
-                        # afterwards it is the carried snapshot
-                        ever = carry["ever_improved"]
-                        base = jax.tree.map(
-                            lambda bp, pl: jnp.where(ever, bp, pl),
-                            carry["best_params"], p,
-                        )
-                        # the same masked per-machine select the host
-                        # path uses (inlines under this trace)
-                        new["best_params"] = _keep_better(improved, p, base)
-                        new["ever_improved"] = ever | improved.any()
-                else:
-                    outs["loss"] = loss
-                return new, outs
-
-            return jax.lax.scan(step, carry, epoch_ids)
-
-        jit_kwargs: dict = {}
-        if self.donate:
-            donate = [0, 1]
-            if track_best:
-                # best_params rides the carry; its input buffer is dead
-                # after the call exactly like params/opt_state
-                donate.append(
-                    7
-                    + (1 if with_val else 0)
-                    + (1 if masked else 0)
-                    + (1 if quarantine else 0)
-                    + 4  # track_best implies gated (the ES state args)
-                    + (1 if monitor_val else 0)
-                    + (2 if inject else 0)
-                )
-            jit_kwargs["donate_argnums"] = tuple(donate)
-        # shardings propagate from the committed inputs (params/data are
-        # device_put with fleet/replicated shardings by fit's setup), so
-        # no explicit in_shardings are needed here
-        return jax.jit(chunk_program, **jit_kwargs)
 
     def _validation_masks(
         self, w, rows: np.ndarray, validation_split: float
@@ -1310,13 +1016,10 @@ class FleetTrainer:
         improved by ``early_stopping_min_delta`` for that many epochs gets
         zero sample weights from then on — its params freeze while the
         rest of the fleet trains — and the loop ends early once every
-        machine has stopped. With the default ``epoch_chunk=1`` this
-        syncs the (M,) losses to host each epoch (the cost of the
-        decision); with ``epoch_chunk=K`` the state machine runs on
-        device and the sync happens once per K-epoch chunk (at the price
-        of up to K-1 gated no-op epochs after the fleet stops). Stopped
-        machines still ride along in the compiled program (gated, not
-        compacted). Monitored metric is the training loss.
+        machine has stopped. This syncs the (M,) losses to host each
+        epoch (the cost of the decision). Stopped machines still ride
+        along in the compiled program (gated, not compacted). Monitored
+        metric is the training loss.
 
         ``restore_best_weights`` (early stopping only) keeps a device-side
         per-machine snapshot of the params at each machine's best epoch —
@@ -1484,45 +1187,23 @@ class FleetTrainer:
             track_best = early_stopping and restore_best_weights
             row_fetch = self._choose_row_fetch(data, batch_size, sample_cap)
 
-            if self.epoch_chunk <= 1:
-                epoch_fn = self._epoch_fn(
-                    data.n_timesteps,
-                    batch_size,
-                    shuffle,
-                    gated=early_stopping,
-                    sample_cap=sample_cap,
-                    quarantine=quarantine,
-                    inject=inj is not None,
-                    masked=masked,
-                    row_fetch=row_fetch,
-                )
-                val_fn = (
-                    self._val_fn(
-                        data.n_timesteps, batch_size, lo=val_lo, masked=masked
-                    )
-                    if val_w is not None
-                    else None
-                )
-
-        if self.epoch_chunk > 1:
-            # device-resident loop: K epochs per compiled program, one
-            # host sync per chunk (early stopping) or per fit (plain)
-            return self._fit_chunked(
-                data=data, keys=keys, epochs=epochs, batch_size=batch_size,
-                shuffle=shuffle, params=params, opt_state=opt_state,
-                X_arg=X_arg, y_arg=y_arg, w_arg=w_arg, val_arg=val_arg,
-                sample_cap=sample_cap, has_val=has_val, val_lo=val_lo,
-                monitor_val=monitor_val, early_stopping=early_stopping,
-                es_state=es_state if early_stopping else None,
-                es_stop_at=es_stop_at if early_stopping else 1,
-                es_delta=es_delta if early_stopping else 0.0,
-                es_start_from=int(early_stopping_start_from_epoch),
-                track_best=track_best, checkpointer=checkpointer,
-                checkpoint_every=checkpoint_every, start_epoch=start_epoch,
-                m=m, rows_per_machine=rows_per_machine, fit_start=fit_start,
-                quarantine=quarantine, inj=inj, healthy_np=healthy_np,
-                machine_names=machine_names, fmask=fmask, phases=phases,
+            epoch_fn = self._epoch_fn(
+                data.n_timesteps,
+                batch_size,
+                shuffle,
+                gated=early_stopping,
+                sample_cap=sample_cap,
+                quarantine=quarantine,
+                inject=inj is not None,
+                masked=masked,
                 row_fetch=row_fetch,
+            )
+            val_fn = (
+                self._val_fn(
+                    data.n_timesteps, batch_size, lo=val_lo, masked=masked
+                )
+                if val_w is not None
+                else None
             )
 
         best_params = None  # set at the first monitored improvement
@@ -1548,7 +1229,7 @@ class FleetTrainer:
             # the host's share of one epoch: key fold-in, the per-machine
             # flags and the enqueue of the epoch program (the device work
             # is asynchronous and not inside)
-            with tracing.start_span("train.dispatch", epoch=epoch, n_epochs=1):
+            with tracing.start_span("train.dispatch", epoch=epoch):
                 epoch_keys = jax.vmap(
                     lambda k: jax.random.fold_in(k, epoch)
                 )(keys)
@@ -1562,8 +1243,7 @@ class FleetTrainer:
                 if quarantine:
                     extras.append(healthy_dev)
                 if inj is not None:
-                    # the host-side twin of the chunk program's in-scan
-                    # flag: poison only at the configured epoch
+                    # poison only at the configured epoch
                     extras.append(
                         _put_fleet_arr(inj[0] & (epoch == inj[1]), self.mesh)
                     )
@@ -1656,12 +1336,10 @@ class FleetTrainer:
                     else:
                         monitored = loss_np
                     if epoch >= int(early_stopping_start_from_epoch):
-                        # the improvement test runs in float32 — the same
-                        # arithmetic the device-resident (epoch_chunk > 1)
-                        # state machine uses — so both paths take bit-identical
-                        # stopping decisions (the state itself stays float64
-                        # for checkpoint-format stability; the values are
-                        # exact float32s either way)
+                        # the improvement test runs in float32, the losses'
+                        # own precision (the state itself stays float64 for
+                        # checkpoint-format stability; the values are exact
+                        # float32s either way)
                         improved = es_state["active"] & (
                             monitored.astype(np.float32)
                             < es_state["best"].astype(np.float32)
@@ -1751,10 +1429,8 @@ class FleetTrainer:
             phases,
             wall_time_s=time.perf_counter() - fit_start,
             loop_time_s=time.perf_counter() - loop_start,
-            first_sync_s=first_epoch_s,
-            first_sync_epochs=1,
+            first_epoch_s=first_epoch_s,
             epochs_run=epochs_run,
-            epochs_dispatched=epochs_run,
             epochs_configured=epochs,
             start_epoch=start_epoch,
             timesteps_trained=timesteps_trained,
@@ -1764,386 +1440,6 @@ class FleetTrainer:
             n_stopped=(
                 int((~es_state["active"]).sum()) if early_stopping else 0
             ),
-            n_dispatches=epochs_run,
-            dispatch_times=dispatch_times,
-            n_quarantined=n_quarantined,
-            row_fetch=row_fetch,
-        )
-        return params, losses_out
-
-    def _fit_chunked(
-        self,
-        *,
-        data: StackedData,
-        keys: jnp.ndarray,
-        epochs: int,
-        batch_size: int,
-        shuffle: bool,
-        params: Any,
-        opt_state: Any,
-        X_arg: Any,
-        y_arg: Any,
-        w_arg: Any,
-        val_arg: Any,
-        sample_cap: int,
-        has_val: Optional[np.ndarray],
-        val_lo: int,
-        monitor_val: bool,
-        early_stopping: bool,
-        es_state: Optional[dict],
-        es_stop_at: int,
-        es_delta: float,
-        es_start_from: int,
-        track_best: bool,
-        checkpointer: Optional[Any],
-        checkpoint_every: int,
-        start_epoch: int,
-        m: int,
-        rows_per_machine: np.ndarray,
-        fit_start: float,
-        quarantine: bool = False,
-        inj: Optional[Tuple[np.ndarray, int]] = None,
-        healthy_np: Optional[np.ndarray] = None,
-        machine_names: Optional[List[str]] = None,
-        fmask: Optional[jnp.ndarray] = None,
-        phases: "_FitPhases",
-        row_fetch: str = "gather",
-    ) -> Tuple[Any, np.ndarray]:
-        """
-        The ``epoch_chunk > 1`` fit loop: dispatch ONE fused program per
-        K-epoch chunk (``_chunk_fn``) and sync to host once per chunk
-        (early stopping — the (K, M) reported losses, per-epoch activity
-        and the end-of-chunk ES state come back in a single transfer) or
-        not at all until fit end (no early stopping: chunk dispatches
-        pipeline and the whole loss/val history is one final fetch, so a
-        plain fit performs exactly 2 device->host syncs: the setup's
-        weight fetch and this one).
-
-        A checkpoint boundary forces a chunk boundary, so
-        ``checkpoint_every`` cadence and resume semantics are preserved
-        exactly; an early stop inside a chunk is detected from the
-        per-epoch activity history and the history is truncated at the
-        stop epoch, so reported losses, stop epochs and final params are
-        bit-identical to the per-epoch loop (the chunk's remaining
-        epochs ran gated — all machines inactive — and changed nothing).
-        """
-        with_val = val_arg is not None
-        masked = fmask is not None
-        # the monitored-metric select only exists inside the gated (ES)
-        # program; normalizing here keeps a plain fit-with-validation from
-        # minting a distinct (but identical) compiled chunk program
-        monitor_val = monitor_val and early_stopping
-        n_timesteps = data.n_timesteps
-        chunk = self.epoch_chunk
-        ce = max(1, checkpoint_every)
-
-        def put_fleet(x):
-            return _put_fleet_arr(x, self.mesh)
-
-        # the device-resident state the chunk program carries
-        with phases.timed("prepare_s"), tracing.start_span("train.prepare"):
-            if healthy_np is None:
-                healthy_np = np.ones(m, dtype=bool)
-            healthy_entry = healthy_np.copy()
-            healthy_dev = put_fleet(healthy_np) if quarantine else None
-            inj_mask_dev = inj_epoch_dev = None
-            if inj is not None:
-                inj_mask_dev = put_fleet(inj[0])
-                inj_epoch_dev = jnp.asarray(np.int32(inj[1]))
-            es_dev: Optional[dict] = None
-            has_val_dev = None
-            if early_stopping:
-                es_dev = {
-                    "active": put_fleet(es_state["active"]),
-                    "best": put_fleet(es_state["best"].astype(np.float32)),
-                    "wait": put_fleet(es_state["wait"].astype(np.int32)),
-                    "last": put_fleet(es_state["last_loss"].astype(np.float32)),
-                }
-                if monitor_val:
-                    has_val_dev = put_fleet(np.asarray(has_val, dtype=bool))
-            best_params_dev = None
-            ever_dev = None
-            ever_improved = False
-            if track_best:
-                # garbage until the first improving epoch (ever_improved
-                # gates its use), but it must be a DISTINCT buffer: params is
-                # donated, and aliasing a donated arg is not allowed
-                best_params_dev = self._shard(
-                    jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
-                )
-                ever_dev = jnp.asarray(False)
-
-        healthy_chunks: list = []
-        loss_chunks: list = []
-        val_chunks: list = []
-        first_sync_s: Optional[float] = None
-        first_sync_epochs = 0
-        epochs_run = 0
-        epochs_dispatched = 0
-        timesteps_trained = 0
-        early_stop_epoch: Optional[int] = None
-        n_dispatches = 0
-        dispatch_times: list = []
-        loop_start = time.perf_counter()
-
-        def chunk_len(e0: int) -> int:
-            k0 = min(chunk, epochs - e0)
-            if checkpointer is not None:
-                # the next epoch whose completion is a checkpoint: the
-                # chunk must not run past it (checkpoints happen at chunk
-                # boundaries only, so cadence survives chunking exactly)
-                next_cp = ((e0 + ce) // ce) * ce - 1
-                k0 = min(k0, next_cp - e0 + 1)
-            return k0
-
-        # chunk k+1's epoch-index transfer, issued while chunk k's
-        # program still runs (prefetch_depth > 0); keyed by (epoch,
-        # length) so a vector prefetched for a chunk that never runs
-        # (early stop) is simply dropped
-        prefetched_epochs: dict = {}
-
-        e = start_epoch
-        while e < epochs:
-            k = chunk_len(e)
-            chunk_start = time.perf_counter()
-            # one fused K-epoch program per dispatch: the span is the unit
-            # the sync-budget telemetry counts, and covers the host's whole
-            # share of it (program lookup, arguments, enqueue)
-            with tracing.start_span("train.dispatch", epoch=e, n_epochs=k):
-                chunk_fn = self._chunk_fn(
-                    n_timesteps, batch_size, shuffle,
-                    chunk_len=k, sample_cap=sample_cap, with_val=with_val,
-                    val_lo=val_lo, gated=early_stopping, track_best=track_best,
-                    monitor_val=monitor_val, es_delta=es_delta,
-                    es_stop_at=es_stop_at, es_start_from=es_start_from,
-                    quarantine=quarantine, inject=inj is not None,
-                    masked=masked, row_fetch=row_fetch,
-                )
-                epoch_vec = prefetched_epochs.pop((e, k), None)
-                if epoch_vec is None:
-                    if self.prefetch_depth > 0:
-                        transfer.count_transfer("train", "direct")
-                    epoch_vec = jnp.arange(e, e + k, dtype=jnp.int32)
-                args = [
-                    params, opt_state, keys, X_arg, y_arg, w_arg, epoch_vec,
-                ]
-                if with_val:
-                    args.append(val_arg)
-                if masked:
-                    args.append(fmask)
-                if quarantine:
-                    args.append(healthy_dev)
-                if early_stopping:
-                    args += [
-                        es_dev["active"], es_dev["best"],
-                        es_dev["wait"], es_dev["last"],
-                    ]
-                    if monitor_val:
-                        args.append(has_val_dev)
-                if inj is not None:
-                    args += [inj_mask_dev, inj_epoch_dev]
-                if track_best:
-                    args += [best_params_dev, ever_dev]
-                t_disp = time.perf_counter()
-                final, outs = chunk_fn(*args)
-                attribution.record(
-                    "train", "device", time.perf_counter() - t_disp
-                )
-            if self.prefetch_depth > 0:
-                # the dispatch above is asynchronous: issue the NEXT
-                # chunk's argument transfer now so it rides under the
-                # running program instead of on the next iteration's
-                # critical path
-                e_next = e + k
-                if e_next < epochs:
-                    k_next = chunk_len(e_next)
-                    if (e_next, k_next) not in prefetched_epochs:
-                        t_put = time.perf_counter()
-                        prefetched_epochs[(e_next, k_next)] = jax.device_put(
-                            np.arange(e_next, e_next + k_next, dtype=np.int32)
-                        )
-                        attribution.record(
-                            "train", "transfer",
-                            time.perf_counter() - t_put,
-                        )
-                        transfer.count_transfer("train", "prefetched")
-            params, opt_state = final["params"], final["opt"]
-            if quarantine:
-                healthy_dev = final["healthy"]
-            if early_stopping:
-                es_dev = final["es"]
-            if track_best:
-                best_params_dev = final["best_params"]
-                ever_dev = final["ever_improved"]
-            dispatch_times.append(time.perf_counter() - chunk_start)
-            n_dispatches += 1
-            epochs_dispatched += k
-
-            if early_stopping:
-                with phases.timed("decide_s"), tracing.start_span(
-                    "train.decide", epoch=e, n_epochs=k
-                ):
-                    # the ONE host sync per chunk: reported losses, per-epoch
-                    # activity, end-of-chunk ES state (and the snapshot flag)
-                    # come back in a single transfer
-                    fetch = {"loss": outs["loss"], "active": outs["active"],
-                             "es": final["es"]}
-                    if with_val:
-                        fetch["val"] = outs["val"]
-                    if track_best:
-                        fetch["ever"] = final["ever_improved"]
-                    if quarantine:
-                        fetch["healthy"] = outs["healthy"]
-                    fetched = phases.fetched(host_fetch(fetch))
-                    if first_sync_s is None:
-                        first_sync_s = time.perf_counter() - chunk_start
-                        first_sync_epochs = k
-                    loss_rep = np.asarray(fetched["loss"], dtype=np.float64)
-                    active_out = np.asarray(fetched["active"], dtype=bool)
-                    # activity ENTERING each epoch: the chunk-entry state,
-                    # then the previous epoch's post-update state
-                    active_in = np.concatenate(
-                        [es_state["active"][None, :], active_out[:-1]], axis=0
-                    )
-                    stopped = ~active_out.any(axis=1)
-                    n_rep = int(np.argmax(stopped)) + 1 if stopped.any() else k
-                    loss_chunks.append(loss_rep[:n_rep])
-                    if with_val:
-                        val_chunks.append(
-                            np.asarray(fetched["val"], dtype=np.float64)[:n_rep]
-                        )
-                    if quarantine:
-                        healthy_out_rows = np.asarray(
-                            fetched["healthy"], dtype=bool
-                        )[:n_rep]
-                        healthy_chunks.append(healthy_out_rows)
-                        if len(healthy_out_rows):
-                            healthy_np = healthy_out_rows[-1]
-                    if track_best:
-                        ever_improved = bool(fetched["ever"])
-                    timesteps_trained += int(
-                        (active_in[:n_rep] * rows_per_machine[None, :]).sum()
-                    )
-                    epochs_run += n_rep
-                    # host mirror of the device ES state (checkpoint extra +
-                    # telemetry); when the fleet stopped mid-chunk the mirror
-                    # includes the gated no-op tail epochs, but then no
-                    # checkpoint is written and only `active` (all False
-                    # either way) is read again
-                    es_state["best"] = np.asarray(
-                        fetched["es"]["best"], dtype=np.float64
-                    )
-                    es_state["wait"] = np.asarray(
-                        fetched["es"]["wait"], dtype=np.int64
-                    )
-                    es_state["active"] = np.asarray(
-                        fetched["es"]["active"], dtype=bool
-                    )
-                    es_state["last_loss"] = np.asarray(
-                        fetched["es"]["last"], dtype=np.float64
-                    )
-                    for j in range(n_rep):
-                        emit_event(
-                            "epoch", path="fleet", epoch=e + j,
-                            mean_loss=float(np.mean(loss_rep[j])),
-                            n_active=int(active_out[j].sum()),
-                        )
-                    if stopped.any():
-                        early_stop_epoch = e + n_rep - 1
-                        logger.info(
-                            "Fleet early stop: all %d machines stopped at epoch "
-                            "%d/%d (chunked: %d gated no-op epochs discarded)",
-                            m, early_stop_epoch, epochs, k - n_rep,
-                        )
-                        emit_event(
-                            "early_stop", path="fleet",
-                            epoch=early_stop_epoch, n_machines=m,
-                        )
-            else:
-                loss_chunks.append(outs["loss"])
-                if with_val:
-                    val_chunks.append(outs["val"])
-                if quarantine:
-                    # device-resident (k, M) history block; the end-of-fit
-                    # bulk fetch pulls it with the losses
-                    healthy_chunks.append(outs["healthy"])
-                if first_sync_s is None:
-                    # sync ONCE (a readiness wait, not a transfer) so
-                    # compile+first-chunk cost separates from steady state
-                    with tracing.start_span("train.first_sync", epoch=e):
-                        jax.block_until_ready(outs["loss"])  # lint: disable=host-sync
-                    first_sync_s = time.perf_counter() - chunk_start
-                    first_sync_epochs = k
-                timesteps_trained += int(rows_per_machine.sum()) * k
-                epochs_run += k
-                for j in range(k):
-                    emit_event("epoch", path="fleet", epoch=e + j)
-
-            if (
-                checkpointer is not None
-                and (e + k) % ce == 0
-                and (early_stop_epoch is None or early_stop_epoch == e + k - 1)
-            ):
-                # chunk boundaries were forced onto the checkpoint cadence
-                # above; a mid-chunk early stop means the per-epoch loop
-                # would have broken before this boundary, so skip it
-                with phases.timed("checkpoint_s"), tracing.start_span(
-                    "train.checkpoint", epoch=e + k - 1
-                ):
-                    extra: Optional[dict] = None
-                    if quarantine or early_stopping:
-                        extra = {}
-                        if quarantine:
-                            if not early_stopping:
-                                # plain chunked fits keep healthy on device;
-                                # the checkpoint write is already a sync point
-                                healthy_np = np.asarray(
-                                    phases.fetched(host_fetch(healthy_dev)),
-                                    dtype=bool,
-                                )
-                            extra["healthy"] = healthy_np
-                        if early_stopping:
-                            extra.update(es_state)
-                    checkpointer.save(
-                        e + k - 1, params, opt_state, extra=extra
-                    )
-            if early_stop_epoch is not None:
-                break
-            e += k
-
-        if checkpointer is not None:
-            with phases.timed("checkpoint_s"), tracing.start_span(
-                "train.checkpoint"
-            ):
-                checkpointer.wait()
-        if track_best and ever_improved:
-            params = best_params_dev
-        # a plain chunked fit's ONLY sync after set-up: the whole
-        # (epochs, M) loss/val history in one transfer
-        losses_out, n_quarantined = self._collect_fit(
-            phases, losses=loss_chunks, val_losses=val_chunks,
-            healthy_rows=healthy_chunks, has_val=has_val,
-            healthy_entry=healthy_entry, start_epoch=start_epoch,
-            machine_names=machine_names, m=m,
-        )
-        self._report_fit(
-            phases,
-            wall_time_s=time.perf_counter() - fit_start,
-            loop_time_s=time.perf_counter() - loop_start,
-            first_sync_s=first_sync_s,
-            first_sync_epochs=first_sync_epochs,
-            epochs_run=epochs_run,
-            epochs_dispatched=epochs_dispatched,
-            epochs_configured=epochs,
-            start_epoch=start_epoch,
-            timesteps_trained=timesteps_trained,
-            n_machines=m,
-            early_stopping=early_stopping,
-            early_stop_epoch=early_stop_epoch,
-            n_stopped=(
-                int((~es_state["active"]).sum()) if early_stopping else 0
-            ),
-            n_dispatches=n_dispatches,
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
             row_fetch=row_fetch,
@@ -2169,16 +1465,14 @@ class FleetTrainer:
         history — comes back in ONE bulk transfer. Early stopping already
         host-materialized its history (its decision IS the sync), and
         fetching that again would make ``process_allgather`` treat the
-        replicated host copy as per-process data. The histories (rows of
-        (M,) or blocks of (k, M), in epoch order) are stacked, and the
-        quarantine bookkeeping runs. Sets ``val_losses_``; returns (losses
-        (epochs, M), how many machines ended quarantined).
+        replicated host copy as per-process data. The histories (one (M,)
+        row an epoch) are stacked, and the quarantine bookkeeping runs.
+        Sets ``val_losses_``; returns (losses (epochs, M), how many
+        machines ended quarantined).
         """
 
         def stacked(rows, dtype=None):
-            return np.concatenate(
-                [np.atleast_2d(np.asarray(r, dtype=dtype)) for r in rows]
-            )
+            return np.stack([np.asarray(r, dtype=dtype) for r in rows])
 
         with phases.timed("collect_s"), tracing.start_span("train.collect"):
             history = {"loss": losses, "val": val_losses, "healthy": healthy_rows}
@@ -2224,15 +1518,13 @@ class FleetTrainer:
     ) -> int:
         """
         Post-fit quarantine bookkeeping from the already-fetched healthy
-        history (rows of (M,) or (k, M) blocks, in epoch order): sets
+        history (one (M,) row an epoch): sets
         ``healthy_`` / ``quarantine_epoch_`` / ``healthy_history_``,
         emits one ``machine_quarantined`` event per casualty, and
         returns how many machines ended the fit quarantined.
         """
         if healthy_rows:
-            hist = np.concatenate(
-                [np.atleast_2d(np.asarray(r, dtype=bool)) for r in healthy_rows]
-            )
+            hist = np.stack([np.asarray(r, dtype=bool) for r in healthy_rows])
         else:
             hist = np.ones((0, m), dtype=bool)
         self.healthy_history_ = hist
@@ -2272,10 +1564,8 @@ class FleetTrainer:
         *,
         wall_time_s: float,
         loop_time_s: float,
-        first_sync_s: Optional[float],
-        first_sync_epochs: int,
+        first_epoch_s: Optional[float],
         epochs_run: int,
-        epochs_dispatched: int,
         epochs_configured: int,
         start_epoch: int,
         timesteps_trained: int,
@@ -2283,33 +1573,29 @@ class FleetTrainer:
         early_stopping: bool,
         early_stop_epoch: Optional[int],
         n_stopped: int,
-        n_dispatches: int,
         phases: "_FitPhases",
-        dispatch_times: Optional[list] = None,
-        n_quarantined: int = 0,
-        row_fetch: str = "gather",
+        dispatch_times: list,
+        n_quarantined: int,
+        row_fetch: str,
     ) -> None:
         """
         Derive and publish one fit's telemetry: ``self.fit_telemetry_``
         (the builder copies it into bucket reports), the process metrics
         registry, and a ``fit_finished`` event.
 
-        Compile time is estimated as (first synced dispatch unit) -
-        (steady-state cost of that many epochs): the first dispatch — one
-        epoch in the per-epoch loop, one K-epoch chunk under
-        ``epoch_chunk`` — is the only one that pays XLA compilation (per
-        geometry), and all later dispatches reuse the program. When
-        nothing ran after the first unit there is no steady state to
-        subtract, so ``compile_time_s`` degrades to the whole first-unit
-        cost (an upper bound).
+        Compile time is estimated as (the first epoch, synced) - (one
+        steady-state epoch): the first dispatch is the only one that pays
+        XLA compilation (per geometry), and all later ones reuse the
+        program. When nothing ran after the first epoch there is no steady
+        state to subtract, so ``compile_time_s`` degrades to the whole
+        first epoch's cost (an upper bound).
 
         ``dispatch_times`` are the HOST-side seconds spent issuing each
-        dispatch (key derivation + program submission, not the device
-        work): their steady-state mean is ``dispatch_gap_s_mean`` — the
-        per-dispatch host overhead that ``epoch_chunk`` amortizes over K
-        epochs. The first dispatch is excluded (it carries tracing and
-        compile time). ``epochs_per_sync`` is how many epochs each
-        device->host round-trip bought.
+        epoch (key derivation + program submission, not the device work),
+        one dispatch an epoch: their steady-state mean is
+        ``dispatch_gap_s_mean``. The first dispatch is excluded (it carries
+        tracing and compile time). ``epochs_per_sync`` is how many epochs
+        each device->host round-trip bought.
 
         ``phases`` carries what the fit measured at its own boundaries
         (:class:`_FitPhases`): the seconds of each host phase outside the
@@ -2317,19 +1603,14 @@ class FleetTrainer:
         """
         n_host_syncs = phases.n_host_syncs
         steady = None
-        if epochs_dispatched > first_sync_epochs and first_sync_s is not None:
-            steady = max(
-                0.0,
-                (loop_time_s - first_sync_s)
-                / (epochs_dispatched - first_sync_epochs),
-            )
+        if epochs_run > 1 and first_epoch_s is not None:
+            steady = max(0.0, (loop_time_s - first_epoch_s) / (epochs_run - 1))
         compile_s = None
-        first_epoch_s = first_sync_s if first_sync_epochs == 1 else None
-        if first_sync_s is not None:
+        if first_epoch_s is not None:
             compile_s = (
-                max(0.0, first_sync_s - steady * first_sync_epochs)
+                max(0.0, first_epoch_s - steady)
                 if steady is not None
-                else first_sync_s
+                else first_epoch_s
             )
         throughput = (
             timesteps_trained / loop_time_s if loop_time_s > 0 else None
@@ -2339,13 +1620,13 @@ class FleetTrainer:
         steady_throughput = None
         if steady and epochs_run > 0:
             steady_throughput = (timesteps_trained / epochs_run) / steady
-        steady_dispatches = (dispatch_times or [])[1:]
+        steady_dispatches = dispatch_times[1:]
         dispatch_gap = (
             sum(steady_dispatches) / len(steady_dispatches)
             if steady_dispatches
             else None
         )
-        dispatch_overhead = sum(dispatch_times or []) or None
+        dispatch_overhead = sum(dispatch_times) or None
         epochs_per_sync = (
             epochs_run / n_host_syncs if n_host_syncs else None
         )
@@ -2354,13 +1635,11 @@ class FleetTrainer:
             "wall_time_s": wall_time_s,
             "epoch_loop_s": loop_time_s,
             "first_epoch_s": first_epoch_s,
-            "first_dispatch_s": first_sync_s,
-            "first_dispatch_epochs": first_sync_epochs,
+            "first_dispatch_s": first_epoch_s,
             "steady_state_epoch_s": steady,
             "compile_time_s": compile_s,
             "epochs_configured": epochs_configured,
             "epochs_run": epochs_run,
-            "epochs_dispatched": epochs_dispatched,
             "resumed_from_epoch": start_epoch if start_epoch else None,
             "n_machines": n_machines,
             "sensor_timesteps_trained": timesteps_trained,
@@ -2370,11 +1649,11 @@ class FleetTrainer:
             "early_stop_epoch": early_stop_epoch,
             "n_machines_early_stopped": n_stopped,
             "n_machines_quarantined": n_quarantined,
-            "epoch_chunk": self.epoch_chunk,
             # how the steps' rows reached them (_choose_row_fetch), and the
-            # epochs dispatched on that path
-            "row_fetch": {"path": row_fetch, "epochs": epochs_dispatched},
-            "n_dispatches": n_dispatches,
+            # epochs run on that path
+            "row_fetch": {"path": row_fetch, "epochs": epochs_run},
+            # one dispatch an epoch
+            "n_dispatches": epochs_run,
             "n_host_syncs": n_host_syncs,
             "epochs_per_sync": epochs_per_sync,
             "dispatch_overhead_s": dispatch_overhead,

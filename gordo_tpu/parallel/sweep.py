@@ -45,10 +45,9 @@ class HyperparamSweep:
         share one length (the number of variants). Names must be accepted
         by the underlying optax constructor (e.g. ``learning_rate``,
         ``b1``, ``weight_decay`` for adamw).
-    lookahead, mesh, scan_unroll, epoch_chunk
+    lookahead, mesh, scan_unroll
         Passed through to FleetTrainer — a sweep shards over the mesh's
-        fleet axis like any other fleet, and ``epoch_chunk > 1`` fuses K
-        epochs into one compiled program (one host sync per chunk).
+        fleet axis like any other fleet.
     """
 
     def __init__(
@@ -58,7 +57,6 @@ class HyperparamSweep:
         lookahead: int = 0,
         mesh: Optional[Any] = None,
         scan_unroll: int = 1,
-        epoch_chunk: int = 1,
     ):
         if not grid:
             raise ValueError("grid must name at least one hyperparameter")
@@ -110,7 +108,6 @@ class HyperparamSweep:
             scan_unroll=scan_unroll,
             optimizer=optimizer,
             broadcast_data=True,
-            epoch_chunk=epoch_chunk,
         )
 
     def _inject(self, opt_state: Any) -> Any:

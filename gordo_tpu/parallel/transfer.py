@@ -1,8 +1,8 @@
 """
 Pipelined host->device transfer — double-buffering for the builder's
-per-bucket data path, the trainer's chunked fit, and the streaming
-plane's window updates (docs/performance.md "Mixed precision, buffer
-donation, and transfer pipelining").
+per-bucket data path and the streaming plane's window updates
+(docs/performance.md "Mixed precision, buffer donation, and transfer
+pipelining").
 
 JAX dispatch is asynchronous, but a transfer only overlaps compute if
 it is ISSUED before the compute that hides it. The helpers here make

@@ -8,7 +8,7 @@ models it dominates every fresh process).
 Three layers:
 
 - :mod:`cache` — :class:`ProgramCache`, the in-memory LRU of live
-  compiled programs (trainer epoch/val/chunk programs, the fleet
+  compiled programs (trainer epoch/val/predict programs, the fleet
   scorer's vmapped apply, AOT-loaded serving executables), bounded by
   the HBM watermark sampler's headroom when the device reports real
   numbers and by a count bound on CPU/null devices. All

@@ -11,8 +11,7 @@ Closes the loop from recorded observability to measured knob defaults:
   normalizing ``telemetry_report*.json`` / JSONL event logs /
   ``benchmarks/results_*.json`` / ``trajectory.json`` into observations.
 - :mod:`model <gordo_tpu.tuning.model>` — the simple per-fleet cost
-  model: best measured arm with piecewise interpolation, monotonic
-  analytic fallback where the corpus is thin.
+  model: best measured arm with piecewise interpolation.
 - :mod:`profile <gordo_tpu.tuning.profile>` — the versioned
   ``tuning_profile.json`` that ``build-fleet``/``run-server`` load by
   default (explicit CLI/env always wins).

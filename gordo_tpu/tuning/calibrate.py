@@ -1,10 +1,9 @@
 """
 Calibration sweeps: when a fleet has NO recorded telemetry corpus yet,
-``gordo-tpu tune calibrate`` measures one — a short ``epoch_chunk``
-sweep on a synthetic fleet (``benchmarks/fleet_throughput.py``'s
-``--epoch-chunk-sweep`` machinery, used as a library) and optionally a
-``--batch-wait-ms`` sweep against an in-process server under open-loop
-Poisson load (``benchmarks/load_test.py``'s ``--open-loop`` machinery).
+``gordo-tpu tune calibrate`` measures one — a ``--batch-wait-ms`` sweep
+against an in-process server under open-loop Poisson load
+(``benchmarks/load_test.py``'s ``--open-loop`` machinery, used as a
+library).
 
 The sweep result is written as an ordinary corpus file
 (``results_calibration.json``, stamped ``bench_schema_version``) so the
@@ -49,28 +48,6 @@ def _bench_module(name: str):
             f"benchmarks/{name}.py is not importable ({exc}); calibration "
             f"needs the repo checkout's benchmarks/ directory"
         )
-
-
-def epoch_chunk_calibration(
-    chunks: typing.Sequence[int],
-    n_machines: int = 4,
-    n_rows: int = 256,
-    n_features: int = 4,
-    epochs: int = 8,
-    batch_size: int = 32,
-) -> typing.List[dict]:
-    """The ``epoch_chunk`` sweep rows (fleet_throughput's own schema:
-    one row per chunk with ``steady_state_*`` + dispatch-overhead
-    telemetry from ``fit_telemetry_``)."""
-    fleet_throughput = _bench_module("fleet_throughput")
-    return fleet_throughput.epoch_chunk_sweep(
-        sorted(set(int(c) for c in chunks)),
-        n_machines=n_machines,
-        n_rows=n_rows,
-        n_features=n_features,
-        epochs=epochs,
-        batch_size=batch_size,
-    )
 
 
 def batch_wait_calibration(
@@ -185,35 +162,20 @@ def batch_wait_calibration(
 
 def run_calibration(
     output_dir: typing.Union[str, Path],
-    epoch_chunks: typing.Sequence[int] = (1, 4, 8),
-    n_machines: int = 4,
-    n_rows: int = 256,
-    n_features: int = 4,
-    epochs: int = 8,
-    batch_size: int = 32,
-    batch_wait_sweep: typing.Optional[typing.Sequence[float]] = None,
+    batch_wait_sweep: typing.Sequence[float],
     rps: float = 20.0,
     duration: float = 5.0,
 ) -> typing.Tuple[Path, dict]:
-    """Run the sweeps and publish ``results_calibration.json`` under
+    """Run the sweep and publish ``results_calibration.json`` under
     ``output_dir``; returns (path, payload)."""
     payload: dict = {
         "bench_schema_version": BENCH_SCHEMA_VERSION,
         "kind": "tune_calibration",
         "generated": datetime.now(timezone.utc).isoformat(),
-        "epoch_chunk_sweep": epoch_chunk_calibration(
-            epoch_chunks,
-            n_machines=n_machines,
-            n_rows=n_rows,
-            n_features=n_features,
-            epochs=epochs,
-            batch_size=batch_size,
+        "batch_wait_sweep": batch_wait_calibration(
+            batch_wait_sweep, rps=rps, duration=duration
         ),
     }
-    if batch_wait_sweep:
-        payload["batch_wait_sweep"] = batch_wait_calibration(
-            batch_wait_sweep, rps=rps, duration=duration
-        )
     path = Path(output_dir) / CALIBRATION_FILENAME
     atomic_write_json(path, payload, indent=2, sort_keys=True)
     logger.info("Calibration written to %s", path)

@@ -66,9 +66,6 @@ class Observation:
     metric: str  # canonical signal metric name
     metric_value: float
     source: str  # file the observation came from
-    context: typing.Mapping[str, float] = dataclasses.field(
-        default_factory=dict
-    )
 
 
 @dataclasses.dataclass
@@ -212,11 +209,6 @@ def _walk(
         for knob, signal in pairs:
             if knob.name not in local:
                 continue
-            ctx = {
-                k: v
-                for k, v in scalars.items()
-                if k != field and k in _CONTEXT_FIELDS
-            }
             out.append(
                 Observation(
                     knob=knob.name,
@@ -224,25 +216,11 @@ def _walk(
                     metric=signal.metric,
                     metric_value=scalars[field],
                     source=source,
-                    context=ctx,
                 )
             )
     for value in node.values():
         if isinstance(value, (dict, list)):
             _walk(value, local, value_fields, signal_fields, source, out)
-
-
-#: sibling scalar fields kept on each observation — the analytic
-#: fallbacks (model.py) read these (e.g. per-dispatch overhead needs
-#: n_dispatches next to dispatch_overhead_s)
-_CONTEXT_FIELDS: typing.FrozenSet[str] = frozenset(
-    {"n_dispatches", "epochs_run", "requests", "sheds", "mean_batch_size"}
-) | {
-    field
-    for knob in KNOBS
-    for signal in knob.signals
-    for field in signal.fields
-}
 
 
 # --------------------------------------------------------------------------

@@ -177,28 +177,6 @@ _GOODPUT = Signal(
 KNOBS: typing.Tuple[Knob, ...] = (
     # -- builder / training ------------------------------------------------
     Knob(
-        name="epoch_chunk",
-        flag="--epoch-chunk",
-        cli="build-fleet",
-        env_var="GORDO_EPOCH_CHUNK",
-        default=1,
-        subsystem="builder",
-        domain=IntRange(1, 512),
-        doc="Epochs fused into one compiled program (one host sync per "
-        "chunk); bit-identical to per-epoch dispatch",
-        data_keys=("epoch_chunk",),
-        signals=(
-            Signal(
-                "steady_state_sensor_timesteps_per_s",
-                "max",
-                ("steady_state_sensor_timesteps_per_s",),
-            ),
-            Signal("steady_state_epoch_s", "min", ("steady_state_epoch_s",)),
-            Signal("dispatch_overhead_s", "min", ("dispatch_overhead_s",)),
-        ),
-        tunable=True,
-    ),
-    Knob(
         name="bucket_policy",
         flag="--bucket-policy",
         cli="build-fleet",
@@ -326,8 +304,8 @@ KNOBS: typing.Tuple[Knob, ...] = (
         subsystem="builder",
         domain=IntRange(0, 8),
         doc="Host->device transfers kept in flight ahead of the "
-        "consuming dispatch (builder data path, chunked fit, stream "
-        "updates); 0 = transfer on the critical path, bit-identical",
+        "consuming dispatch (builder data path, stream updates); "
+        "0 = transfer on the critical path, bit-identical",
         data_keys=("prefetch_depth",),
         signals=(
             Signal(

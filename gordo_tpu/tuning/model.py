@@ -11,15 +11,8 @@ heuristics on TPU) and deliberately dependency-light (no scipy/sklearn):
   measured arm wins outright; predictions at unmeasured points (e.g.
   the current default) interpolate piecewise-linearly between arms.
   The model never extrapolates a recommendation past what was measured.
-- **Analytic fallback** — where the corpus is thin (0-1 arms), a knob
-  may define a monotonic analytic model over quantities ONE arm already
-  measured (e.g. ``epoch_chunk``: per-epoch cost ``steady + d/K`` with
-  ``d`` the measured per-dispatch overhead — monotonically improving in
-  K, saturating), recommending the knee of that curve. Fallback
-  recommendations are stamped ``source: analytic`` so ``tune plan``
-  readers can weigh them accordingly.
-- Otherwise: no recommendation — the default stands. The tuner only
-  ever speaks from evidence.
+- Otherwise (0-1 arms): no recommendation — the default stands. The
+  tuner only ever speaks from evidence.
 """
 
 import dataclasses
@@ -30,10 +23,6 @@ from gordo_tpu.tuning.corpus import Corpus, Observation
 from gordo_tpu.tuning.knobs import KNOBS, Knob
 
 logger = logging.getLogger(__name__)
-
-#: an analytic fallback stops raising the knob once the modeled
-#: overhead it removes drops below this fraction of steady-state cost
-DIMINISHING_RETURNS = 0.02
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,7 +48,7 @@ class Recommendation:
     knob: str
     value: typing.Any
     default: typing.Any
-    source: str  # "measured" | "analytic"
+    source: str  # "measured": every recommendation is a measured arm
     signal: str
     objective: str
     predicted: typing.Optional[float]
@@ -186,72 +175,6 @@ def _fit_measured(
 
 
 # --------------------------------------------------------------------------
-# analytic fallbacks (thin corpus)
-# --------------------------------------------------------------------------
-
-
-def _epoch_chunk_analytic(
-    knob: Knob, observations: typing.Sequence[Observation]
-) -> typing.Optional[Recommendation]:
-    """Monotonic fallback for ``epoch_chunk`` from ONE measured arm:
-    per-epoch cost ``T(K) = steady + d/K`` where ``d`` is the measured
-    per-dispatch host overhead — strictly improving in K with
-    diminishing returns, so recommend the smallest power-of-two K whose
-    remaining overhead share drops below :data:`DIMINISHING_RETURNS`."""
-    for obs in observations:
-        if obs.metric != "dispatch_overhead_s":
-            continue
-        steady = obs.context.get("steady_state_epoch_s")
-        n_dispatches = obs.context.get("n_dispatches")
-        if not steady or not n_dispatches or steady <= 0:
-            continue
-        # dispatch_overhead_s is the fit's TOTAL host-side dispatch
-        # overhead, so d is the per-dispatch cost regardless of which
-        # chunk size the arm ran at; at chunk K each dispatch covers K
-        # epochs, so per-epoch overhead is d/K
-        d = obs.metric_value / n_dispatches
-        if d <= 0:
-            return None
-        k = 1
-        while (
-            d / k > DIMINISHING_RETURNS * steady
-            and knob.domain.contains(k * 2)
-            and k < 64
-        ):
-            k *= 2
-        predicted = steady + d / k
-        return Recommendation(
-            knob=knob.name,
-            value=k,
-            default=knob.default,
-            source="analytic",
-            signal="steady_state_epoch_s",
-            objective="min",
-            predicted=predicted,
-            predicted_default=steady + d / max(int(knob.default), 1),
-            evidence=(
-                ArmEvidence(
-                    value=obs.value,
-                    mean=obs.metric_value,
-                    n=1,
-                    sources=(obs.source,),
-                ),
-            ),
-        )
-    return None
-
-
-_ANALYTIC_FALLBACKS: typing.Dict[
-    str,
-    typing.Callable[
-        [Knob, typing.Sequence[Observation]], typing.Optional[Recommendation]
-    ],
-] = {
-    "epoch_chunk": _epoch_chunk_analytic,
-}
-
-
-# --------------------------------------------------------------------------
 # entry point
 # --------------------------------------------------------------------------
 
@@ -268,10 +191,6 @@ def fit_recommendations(
             continue
         observations = corpus.for_knob(knob.name)
         rec = _fit_measured(knob, observations)
-        if rec is None:
-            fallback = _ANALYTIC_FALLBACKS.get(knob.name)
-            if fallback is not None and observations:
-                rec = fallback(knob, observations)
         if rec is None:
             continue
         if not knob.domain.contains(rec.value):

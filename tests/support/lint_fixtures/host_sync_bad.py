@@ -1,6 +1,6 @@
 """POSITIVE fixture for host-sync: device->host round-trips inside loop
-bodies — each shape stalls the dispatch pipeline once per iteration and
-regresses the epoch_chunk sync budget."""
+bodies — each shape stalls the asynchronous dispatch pipeline once per
+iteration."""
 
 import jax
 import numpy as np

@@ -10,7 +10,7 @@ import click
 
 def registered_knob_read():
     # a Knob's env_var in the registry (gordo_tpu/tuning/knobs.py)
-    return os.environ.get("GORDO_EPOCH_CHUNK")
+    return os.environ.get("GORDO_PREFETCH_DEPTH")
 
 
 def declared_non_knob_read():
@@ -42,14 +42,14 @@ def non_literal_read_out_of_scope():
 
 
 @click.option(
-    "--epoch-chunk",
-    envvar="GORDO_EPOCH_CHUNK",  # registered knob
-    default=1,
+    "--prefetch-depth",
+    envvar="GORDO_PREFETCH_DEPTH",  # registered knob
+    default=0,
 )
 @click.option(
     "--log-level",
     envvar="GORDO_LOG_LEVEL",  # declared non-knob
     default="INFO",
 )
-def command(epoch_chunk, log_level):
-    return epoch_chunk, log_level
+def command(prefetch_depth, log_level):
+    return prefetch_depth, log_level

@@ -52,6 +52,51 @@ def test_resume_matches_uninterrupted(tmp_path):
     ckpt.close()
 
 
+def test_early_stopping_resume_matches_uninterrupted(tmp_path):
+    """An early-stopping fit killed after a checkpoint and resumed is the
+    uninterrupted one: the per-machine stopping state rides the checkpoint,
+    so a machine that had stopped stays stopped, the others stop at their
+    own epochs, and losses and parameters come out bit for bit."""
+    trainer, data, keys = make_trainer_and_data()
+    es = dict(
+        epochs=25, batch_size=16,
+        early_stopping_patience=2, early_stopping_min_delta=2e-2,
+    )
+    straight_params, straight_losses = trainer.fit(data, keys, **es)
+    straight = dict(trainer.fit_telemetry_)
+    # the machines stop one by one, the last well before the budget
+    assert straight["n_machines_early_stopped"] == N_MACHINES
+    stop_epoch = straight["early_stop_epoch"]
+    killed_after = stop_epoch - 3
+    assert 0 < killed_after
+    # one machine has stopped by then: its loss row no longer moves
+    moved = np.diff(straight_losses[: killed_after + 1], axis=0) != 0
+    assert not moved[-1].all() and moved[-1].any()
+
+    ckpt = FleetCheckpointer(tmp_path / "ckpt")
+    trainer.fit(
+        data, keys, checkpointer=ckpt, **{**es, "epochs": killed_after + 1}
+    )
+    assert ckpt.latest_epoch() == killed_after
+
+    resumed_params, resumed_losses = trainer.fit(
+        data, keys, checkpointer=ckpt, **es
+    )
+    ckpt.close()
+    resumed = trainer.fit_telemetry_
+    assert resumed["resumed_from_epoch"] == killed_after + 1
+    assert resumed["early_stop_epoch"] == stop_epoch
+    assert resumed["n_machines_early_stopped"] == N_MACHINES
+    np.testing.assert_array_equal(
+        straight_losses[killed_after + 1 :], resumed_losses
+    )
+    for a, b in zip(
+        jax.tree_util.tree_leaves(straight_params),
+        jax.tree_util.tree_leaves(resumed_params),
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_checkpoint_every_n(tmp_path):
     trainer, data, keys = make_trainer_and_data()
     ckpt = FleetCheckpointer(tmp_path / "ckpt")

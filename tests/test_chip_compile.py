@@ -196,21 +196,6 @@ def test_fleet_epoch_program_compiles(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
-def test_chunked_epoch_program_compiles(chip):
-    """The fused multi-epoch program (``_fit_chunked``, epoch_chunk=3)."""
-    trainer = FleetTrainer(plant_spec(), lookahead=0, epoch_chunk=3)
-    healthy = jax.ShapeDtypeStruct((N_MACHINES,), jnp.bool_, sharding=chip)
-    epoch_ids = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=chip)
-    compiled = trainer._chunk_fn(
-        N_TIMESTEPS, BATCH, True, chunk_len=3, sample_cap=None,
-        with_val=False, val_lo=0, gated=False, track_best=False,
-        monitor_val=False, quarantine=True,
-    ).lower(
-        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip), epoch_ids, healthy
-    ).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
-
-
 def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
     """The epoch program a TPU's feedforward fleet gets (``row_fetch``
     ``"permute_epoch"``), 48 machines of ff50.fit1000's 1000: two groups of

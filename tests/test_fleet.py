@@ -284,6 +284,139 @@ def test_fleet_restore_best_weights():
     assert (mse_restored < mse_final).all(), (mse_restored, mse_final)
 
 
+@pytest.mark.parametrize("start_from", [0, 3])
+def test_early_stopping_start_from_epoch_and_restore_best(start_from):
+    """``early_stopping_start_from_epoch`` with ``restore_best_weights``
+    and a split: nothing is decided before the start epoch, each machine
+    stops ``patience`` epochs after its best monitored epoch, and leaves
+    with that epoch's parameters."""
+    Xs, ys = make_fleet_data(m=3, n=100)
+    data = StackedData.from_ragged(Xs, ys)
+    spec = feedforward_hourglass(n_features=3)
+    trainer = FleetTrainer(spec, donate=False)
+    keys = trainer.machine_keys(3)
+    es = dict(
+        batch_size=16,
+        early_stopping_patience=2,
+        early_stopping_min_delta=1e6,  # only the first decision improves
+        early_stopping_start_from_epoch=start_from,
+        validation_split=0.25,
+    )
+    params, losses = trainer.fit(
+        data, keys, epochs=12, restore_best_weights=True, **es
+    )
+    # improve@start_from, wait, stop: had epochs before start_from been
+    # judged, the fleet would have stopped at epoch 2 whatever start_from
+    assert losses.shape[0] == start_from + 3
+    assert trainer.val_losses_.shape == (start_from + 3, 3)
+    assert np.isfinite(trainer.val_losses_).all()
+    assert trainer.fit_telemetry_["early_stop_epoch"] == start_from + 2
+    assert trainer.fit_telemetry_["n_machines_early_stopped"] == 3
+    # every machine's best monitored epoch is start_from: the restored
+    # parameters are those the same program holds after that epoch
+    best_params, best_losses = trainer.fit(
+        data, keys, epochs=start_from + 1, **es
+    )
+    np.testing.assert_array_equal(losses[: start_from + 1], best_losses)
+    for restored, best in zip(
+        jax.tree.leaves(params), jax.tree.leaves(best_params)
+    ):
+        np.testing.assert_array_equal(np.asarray(restored), np.asarray(best))
+
+
+FIT_TELEMETRY_READ_BY_THE_BENCHMARK = (
+    # chipbench/layer_metrics/*.py: steady_epoch_ms, dispatch_overhead_ms,
+    # fit_prepare_ms, fit_collect_ms
+    "steady_state_epoch_s", "dispatch_overhead_s", "n_dispatches",
+    "prepare_s", "collect_s", "report_s",
+)
+
+
+@pytest.mark.parametrize("early_stopping", [False, True], ids=["plain", "early-stopping"])
+def test_fit_telemetry_carries_what_the_benchmark_reads(early_stopping):
+    """``fit_telemetry_`` of a plain and of an early-stopping fit carries
+    every key ``chipbench/layer_metrics`` reads, as numbers, with one
+    dispatch an epoch."""
+    Xs, ys = make_fleet_data(m=2, n=80)
+    data = StackedData.from_ragged(Xs, ys)
+    trainer = FleetTrainer(feedforward_hourglass(n_features=3), donate=False)
+    es = {"early_stopping_patience": 100} if early_stopping else {}
+    trainer.fit(data, trainer.machine_keys(2), epochs=4, batch_size=16, **es)
+    telemetry = trainer.fit_telemetry_
+    for key in FIT_TELEMETRY_READ_BY_THE_BENCHMARK:
+        assert isinstance(telemetry[key], (int, float)), (key, telemetry[key])
+        assert telemetry[key] >= 0
+    assert telemetry["n_dispatches"] == telemetry["epochs_run"] == 4
+    assert telemetry["row_fetch"] == {"path": "gather", "epochs": 4}
+    assert telemetry["first_epoch_s"] == telemetry["first_dispatch_s"] > 0
+    # a plain fit syncs for its facts and for its history; early stopping
+    # for its facts and once an epoch, its decision (which is the history)
+    assert telemetry["n_host_syncs"] == (1 + 4 if early_stopping else 2)
+    assert telemetry["decide_s"] > 0 if early_stopping else telemetry["decide_s"] == 0
+
+
+def legacy_epoch_chunk_machine(with_key):
+    """A machine config as PR 2 to PR 28 wrote them: ``epoch_chunk`` among
+    the estimator's fit args."""
+    fit_args = {"kind": "feedforward_hourglass", "epochs": 3, "batch_size": 16}
+    if with_key:
+        fit_args["epoch_chunk"] = 4
+    return dict(
+        name="legacy-m0",
+        project_name="p",
+        model={"gordo_tpu.models.AutoEncoder": fit_args},
+        dataset={
+            "type": "RandomDataset",
+            "train_start_date": "2017-12-25 06:00:00Z",
+            "train_end_date": "2017-12-26 06:00:00Z",
+            "tags": [["Tag 1", None], ["Tag 2", None]],
+        },
+    )
+
+
+@pytest.mark.parametrize("through", ["build-fleet", "sweep"])
+def test_machine_config_with_an_epoch_chunk_fit_arg_still_builds(through):
+    """An older machine config's ``epoch_chunk`` fit arg is accepted and
+    ignored (``BaseJaxEstimator.supported_fit_args``): the key reaches no
+    model factory, and the build and the sweep give what they give
+    without it, bit for bit."""
+    from gordo_tpu.builder.fleet_build import _find_jax_estimator
+
+    results = []
+    for with_key in (True, False):
+        config = legacy_epoch_chunk_machine(with_key)
+        if through == "build-fleet":
+            ((model, _),) = FleetModelBuilder([Machine(**config)]).build()
+            estimator = _find_jax_estimator(model)
+            results.append(
+                (np.asarray(estimator.history_["loss"]),
+                 jax.tree.leaves(jax.device_get(estimator.params_)))
+            )
+        else:
+            import json
+
+            from click.testing import CliRunner
+
+            from gordo_tpu.cli import gordo
+
+            out = CliRunner().invoke(
+                gordo,
+                ["sweep", json.dumps(config), "--param", "lr=0.001,0.01"],
+                catch_exceptions=False,
+            )
+            assert out.exit_code == 0, out.output
+            results.append(
+                [ln for ln in out.output.splitlines() if "loss=" in ln]
+            )
+    with_key, without = results
+    if through == "build-fleet":
+        np.testing.assert_array_equal(with_key[0], without[0])
+        for a, b in zip(with_key[1], without[1]):
+            np.testing.assert_array_equal(a, b)
+    else:
+        assert len(with_key) == 2 and with_key == without
+
+
 def test_fleet_build_honors_early_stopping_config():
     """Machines configured with EarlyStopping train fewer epochs."""
     machine = Machine(
@@ -519,39 +652,42 @@ def test_fit_facts_refuse_a_grid_too_short_for_one_window():
 
 
 def ragged_fit_case(case):
-    """(data, trainer kwargs, fit kwargs) of one named fit."""
+    """(data, fit kwargs) of one named fit."""
     Xs, ys = make_fleet_data(m=3, n=90)
     data = StackedData.from_ragged(Xs, ys, n_timesteps=128)
     fit_kwargs = {}
-    trainer_kwargs = {}
     if case in ("extra-weight", "split-under-a-fold"):
         fold = np.ones((3, 128), dtype=np.float32)
         fold[:, 30:50] = 0.0
         fold[1, ::9] = 0.5  # fractional weights must not move any count
         fit_kwargs["extra_weight"] = fold
-    if case in ("validation-split", "split-under-a-fold", "chunked-split"):
+    if case in ("validation-split", "split-under-a-fold", "early-stopping-split"):
         fit_kwargs["validation_split"] = 0.2
-    if case in ("epoch-chunk", "chunked-split"):
-        trainer_kwargs["epoch_chunk"] = 2
-    return data, trainer_kwargs, fit_kwargs
+    if case == "early-stopping-split":
+        # the split's validation loss decides: improve at epoch 0, stop at 1
+        fit_kwargs.update(
+            early_stopping_patience=1, early_stopping_min_delta=1e6,
+            restore_best_weights=True,
+        )
+    return data, fit_kwargs
 
 
 @pytest.mark.parametrize(
     "case",
     [
         "ragged", "extra-weight", "validation-split", "split-under-a-fold",
-        "epoch-chunk", "chunked-split",
+        "early-stopping-split",
     ],
 )
 def test_fit_is_bit_identical_to_a_fit_from_host_side_facts(case):
     """Counting on the device changes no number a fit returns: parameters,
     losses and ``val_losses_`` equal those of a fit driven by the old host
     arithmetic (whole weights fetched, float64 numpy, masks uploaded)."""
-    data, trainer_kwargs, fit_kwargs = ragged_fit_case(case)
+    data, fit_kwargs = ragged_fit_case(case)
     spec = feedforward_hourglass(n_features=3)
     results = []
     for cls in (FleetTrainer, OracleTrainer):
-        trainer = cls(spec, donate=False, **trainer_kwargs)
+        trainer = cls(spec, donate=False)
         params, losses = trainer.fit(
             data, trainer.machine_keys(3), epochs=3, batch_size=16, **fit_kwargs
         )
@@ -562,6 +698,8 @@ def test_fit_is_bit_identical_to_a_fit_from_host_side_facts(case):
         np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(l_new, l_old)
     assert t_new == t_old
+    if case == "early-stopping-split":
+        assert l_new.shape[0] == 2  # the validation loss stopped the fleet
     if "validation_split" in fit_kwargs:
         np.testing.assert_array_equal(v_new, v_old)
         assert np.isfinite(v_new).all()

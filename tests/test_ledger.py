@@ -827,7 +827,7 @@ def test_two_worker_crash_recovery_acceptance(tmp_path):
         [
             sys.executable, "-m", "gordo_tpu.cli", "build-fleet",
             json.dumps(configs), str(mw_out),
-            "--workers", "2", "--lease-ttl", "5", "--epoch-chunk", "2",
+            "--workers", "2", "--lease-ttl", "5",
         ],
         env=env, capture_output=True, text=True, timeout=500,
     )
@@ -849,7 +849,7 @@ def test_two_worker_crash_recovery_acceptance(tmp_path):
             serializer.from_definition(machine.model)
         )
     sw_out = tmp_path / "single"
-    builder = FleetModelBuilder(machines, epoch_chunk=2)
+    builder = FleetModelBuilder(machines)
     builder.build(output_dir_base=sw_out)
 
     # every machine exactly once, artifacts equivalent bit-for-bit at

@@ -117,25 +117,27 @@ def test_inject_attempts_budget(monkeypatch):
 # -- non-finite quarantine in the fused fleet program --------------------
 
 
-@pytest.mark.parametrize("epoch_chunk", [1, 4])
-def test_injected_nan_quarantines_exactly_one_machine(monkeypatch, epoch_chunk):
-    """train:nan at epoch 2 freezes exactly the targeted machine — its
-    params roll back to the last finite epoch — while the OTHER
-    machines' losses and params stay bit-identical to a fault-free run,
-    with the same host-sync budget."""
+@pytest.mark.parametrize("fault_epoch", [0, 2])
+def test_injected_nan_quarantines_exactly_one_machine(monkeypatch, fault_epoch):
+    """train:nan at the epoch the fault names (the first, or a later one)
+    freezes exactly the targeted machine — its params roll back to the
+    last finite epoch — while the OTHER machines' losses and params stay
+    bit-identical to a fault-free run, with the same host-sync budget."""
     data = make_fleet_data()
     spec = feedforward_hourglass(n_features=F)
     keys = FleetTrainer(spec).machine_keys(3)
     names = ["m-0", "m-1", "m-2"]
 
-    clean = FleetTrainer(spec, donate=False, epoch_chunk=epoch_chunk)
+    clean = FleetTrainer(spec, donate=False)
     p_clean, l_clean = clean.fit(
         data, keys, epochs=6, batch_size=16, machine_names=names
     )
     assert clean.healthy_.all()
     assert (clean.quarantine_epoch_ == -1).all()
 
-    monkeypatch.setenv(faults.FAULT_INJECT_ENV_VAR, "train:nan:m-1@epoch:2")
+    monkeypatch.setenv(
+        faults.FAULT_INJECT_ENV_VAR, f"train:nan:m-1@epoch:{fault_epoch}"
+    )
     import gordo_tpu.parallel.fleet as fleet_mod
 
     calls = {"n": 0}
@@ -146,7 +148,7 @@ def test_injected_nan_quarantines_exactly_one_machine(monkeypatch, epoch_chunk):
         return real(x)
 
     monkeypatch.setattr(fleet_mod, "host_fetch", counting)
-    faulted = FleetTrainer(spec, donate=False, epoch_chunk=epoch_chunk)
+    faulted = FleetTrainer(spec, donate=False)
     p_bad, l_bad = faulted.fit(
         data, keys, epochs=6, batch_size=16, machine_names=names
     )
@@ -155,9 +157,10 @@ def test_injected_nan_quarantines_exactly_one_machine(monkeypatch, epoch_chunk):
     assert calls["n"] <= 2
 
     assert list(faulted.healthy_) == [True, False, True]
-    assert list(faulted.quarantine_epoch_) == [-1, 2, -1]
+    assert list(faulted.quarantine_epoch_) == [-1, fault_epoch, -1]
     assert faulted.fit_telemetry_["n_machines_quarantined"] == 1
-    assert np.isnan(l_bad[2, 1])
+    assert np.isfinite(l_bad[:fault_epoch, 1]).all()
+    assert np.isnan(l_bad[fault_epoch, 1])
 
     # the OTHERS: bit-identical losses and params vs the no-fault run
     np.testing.assert_array_equal(l_clean[:, 0], l_bad[:, 0])
@@ -166,11 +169,11 @@ def test_injected_nan_quarantines_exactly_one_machine(monkeypatch, epoch_chunk):
         np.testing.assert_array_equal(np.asarray(lc)[0], np.asarray(lb)[0])
         np.testing.assert_array_equal(np.asarray(lc)[2], np.asarray(lb)[2])
 
-    # the casualty froze at its last finite epoch: entering epoch 2 ==
-    # a clean 2-epoch run's params
-    ref = FleetTrainer(spec, donate=False, epoch_chunk=epoch_chunk)
+    # the casualty froze at its last finite epoch: entering the fault's
+    # epoch == a clean run of that many epochs (none: the initial params)
+    ref = FleetTrainer(spec, donate=False)
     monkeypatch.delenv(faults.FAULT_INJECT_ENV_VAR)
-    p_ref, _ = ref.fit(data, keys, epochs=2, batch_size=16)
+    p_ref, _ = ref.fit(data, keys, epochs=fault_epoch, batch_size=16)
     for lr, lb in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_bad)):
         np.testing.assert_array_equal(np.asarray(lr)[1], np.asarray(lb)[1])
 

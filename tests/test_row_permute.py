@@ -163,7 +163,7 @@ def test_chooser_keeps_the_gather_off_the_chip_and_on_a_mesh(monkeypatch):
 # -- fits on the permuting path against the gather path -----------------------
 
 
-def fit_both_ways(monkeypatch, data, fit_kwargs=None, trainer_kwargs=None):
+def fit_both_ways(monkeypatch, data, fit_kwargs=None):
     """(params, losses, telemetry) of the same fit on the gather path and on
     the permuting one: the second with the chooser's question about the
     backend answered as the chip would, and only while it asks, so that the
@@ -180,7 +180,7 @@ def fit_both_ways(monkeypatch, data, fit_kwargs=None, trainer_kwargs=None):
     out = []
     for chooser in (real, choose_as_on_a_tpu):
         monkeypatch.setattr(FleetTrainer, "_choose_row_fetch", chooser)
-        trainer = FleetTrainer(spec, **(trainer_kwargs or {}))
+        trainer = FleetTrainer(spec)
         keys = trainer.machine_keys(data.n_machines, seed=3)
         params, losses = trainer.fit(data, keys, **fit_kwargs)
         out.append((jax.device_get(params), losses, trainer.fit_telemetry_))
@@ -230,18 +230,6 @@ def test_permuting_fit_with_a_machine_of_no_weight(monkeypatch):
     init = jax.device_get(trainer.init_params(trainer.machine_keys(3, seed=3), 6))
     for a, b in zip(jax.tree.leaves(init), jax.tree.leaves(both[1][0])):
         np.testing.assert_array_equal(a[1], b[1])
-
-
-def test_permuting_fit_through_epoch_chunk(monkeypatch):
-    """``_chunk_fn`` traces the same epoch callable: chunked and per-epoch
-    fits agree on the permuting path as they do on the gather path."""
-    data = stacked()
-    plain = fit_both_ways(monkeypatch, data, {"epochs": 4})
-    chunked = fit_both_ways(
-        monkeypatch, data, {"epochs": 4}, trainer_kwargs={"epoch_chunk": 2}
-    )
-    assert_same_fit(*chunked, epochs=4)
-    assert_same_fit(plain[0], chunked[1], epochs=4)
 
 
 def test_fit_on_the_cpu_reports_the_gather(monkeypatch):

@@ -322,8 +322,7 @@ def test_metric_registrations_disciplined():
 def test_metric_names_documented():
     """Every literal metric the package registers through the
     observability registry must appear in docs/observability.md's
-    catalogue — registering telemetry nobody can find (the epoch-chunk
-    dispatch/sync metrics being the newest additions) is how internal
+    catalogue — registering telemetry nobody can find is how internal
     numbers go unread."""
     from static_analysis import collect_metric_names
 

@@ -68,49 +68,28 @@ def test_fit_spans_in_order_under_one_root(tmp_path, monkeypatch):
     ]
     root, *phases = spans
     assert root[1] is None
-    assert root[2] == {
-        "n_machines": M, "epochs": EPOCHS, "batch_size": BATCH, "epoch_chunk": 1,
-    }
+    assert root[2] == {"n_machines": M, "epochs": EPOCHS, "batch_size": BATCH}
     assert {parent for _, parent, _ in phases} == {"train.fit"}
     assert [a["epoch"] for n, _, a in phases if n == "train.dispatch"] == [0, 1, 2]
 
 
-def test_chunked_fit_spans(tmp_path, monkeypatch):
-    """One dispatch per chunk; the chunk loop's own device-resident state is
-    a second stretch of ``train.prepare``."""
-    trainer = FleetTrainer(feedforward_hourglass(n_features=F), epoch_chunk=2)
-    spans = traced_fit(tmp_path, monkeypatch, trainer)
-    assert [name for name, _, _ in spans] == [
-        "train.fit", "train.prepare", "train.prepare", "train.dispatch",
-        "train.first_sync", "train.dispatch", "train.collect", "train.report",
-    ]
-    assert [
-        (a["epoch"], a["n_epochs"]) for n, _, a in spans if n == "train.dispatch"
-    ] == [(0, 2), (2, 1)]
-    assert trainer.fit_telemetry_["n_dispatches"] == 2
-
-
-@pytest.mark.parametrize("epoch_chunk", [1, 2])
-def test_early_stopping_and_checkpoint_phases(tmp_path, monkeypatch, epoch_chunk):
+def test_early_stopping_and_checkpoint_phases(tmp_path, monkeypatch):
     """``train.decide`` and ``train.checkpoint`` exist only on their paths,
     and their seconds and fetches are booked."""
-    trainer = FleetTrainer(
-        feedforward_hourglass(n_features=F), epoch_chunk=epoch_chunk
-    )
+    trainer = FleetTrainer(feedforward_hourglass(n_features=F))
     spans = traced_fit(
         tmp_path, monkeypatch, trainer, early_stopping_patience=5,
         checkpointer=FleetCheckpointer(str(tmp_path / "ckpt")),
         checkpoint_every=2,
     )
     names = [name for name, _, _ in spans]
-    n_decisions = EPOCHS if epoch_chunk == 1 else 2  # chunks of 2 and 1
-    assert names.count("train.decide") == n_decisions
-    # the save after epoch 1 (a chunk boundary too) and the wait at the end
+    assert names.count("train.decide") == EPOCHS
+    # the save after epoch 1 and the wait at the end
     assert names.count("train.checkpoint") == 2
     telemetry = trainer.fit_telemetry_
     assert telemetry["decide_s"] > 0 and telemetry["checkpoint_s"] > 0
     # the weights' fetch and one per decision; nothing is left to collect
-    assert telemetry["n_host_syncs"] == 1 + n_decisions
+    assert telemetry["n_host_syncs"] == 1 + EPOCHS
 
 
 def test_fit_telemetry_books_phases_with_tracing_off(monkeypatch):

@@ -1,11 +1,11 @@
 """
 Tests for the telemetry-driven autotuner (gordo_tpu/tuning/,
 docs/tuning.md): the schema-tolerant corpus reader (golden PR-1-era and
-current telemetry reports), the cost model's measured/analytic paths,
+current telemetry reports), the cost model's measured path,
 profile versioning (an unknown future profile_version refuses to load),
 the explicit-always-wins precedence through build-fleet and build_app,
 the strict no-profile no-op, and THE acceptance: a recorded CPU corpus
-with an epoch_chunk sweep and a batching queue-wait histogram yields a
+with a prefetch_depth sweep and a batching queue-wait histogram yields a
 tuning_profile.json whose recommendations match the best measured arms,
 which build-fleet and run-server then demonstrably apply (event +
 metric) while explicit flags override.
@@ -55,7 +55,7 @@ def _fresh_registry():
 # --------------------------------------------------------------------------
 
 #: the shape PR-1 builds wrote: no compile_cache block, no bucket-policy
-#: fields, no epoch_chunk/dispatch telemetry in the fit block
+#: fields, no knob or dispatch telemetry in the fit block
 PR1_ERA_REPORT = {
     "version": 1,
     "kind": "fleet_build",
@@ -78,8 +78,8 @@ PR1_ERA_REPORT = {
     ],
 }
 
-#: a current report: bucket policy, compile-cache block, and the
-#: epoch-chunk dispatch economics the tuner judges
+#: a current report: bucket policy, compile-cache block, and a fit block
+#: with the steady-state rate the tuner judges beside a knob's value
 CURRENT_REPORT = {
     "version": 1,
     "kind": "fleet_build",
@@ -95,8 +95,8 @@ CURRENT_REPORT = {
             "n_machines": 4,
             "epochs": 16,
             "fit": {
-                "epoch_chunk": 4,
-                "n_dispatches": 4,
+                "prefetch_depth": 2,
+                "n_dispatches": 16,
                 "epochs_run": 16,
                 "steady_state_epoch_s": 0.05,
                 "steady_state_sensor_timesteps_per_s": 52000.0,
@@ -120,7 +120,7 @@ def _write(path, payload):
 
 def test_pr1_era_report_parses_without_loss(tmp_path):
     """A PR-1-era telemetry report (no compile_cache, no bucket-policy
-    fields, no chunk telemetry) flows through the corpus reader without
+    fields, no knob telemetry) flows through the corpus reader without
     an error: it simply contributes no observations — missing fields
     are tolerance, never failure."""
     _write(tmp_path / "telemetry_report.json", PR1_ERA_REPORT)
@@ -134,10 +134,10 @@ def test_current_report_yields_observations(tmp_path):
     _write(tmp_path / "telemetry_report.json", CURRENT_REPORT)
     corpus = read_corpus([tmp_path])
     assert corpus.files[0].error is None
-    chunk_obs = corpus.for_knob("epoch_chunk")
-    assert chunk_obs, "current report's fit block must judge epoch_chunk"
-    assert {o.value for o in chunk_obs} == {4}
-    metrics = {o.metric for o in chunk_obs}
+    depth_obs = corpus.for_knob("prefetch_depth")
+    assert depth_obs, "current report's fit block must judge prefetch_depth"
+    assert {o.value for o in depth_obs} == {2}
+    metrics = {o.metric for o in depth_obs}
     assert "steady_state_sensor_timesteps_per_s" in metrics
     # bucket_policy stated at the top level inherits down to the
     # models_per_hour signal on the same object
@@ -153,7 +153,7 @@ def test_mixed_era_corpus_parses_both(tmp_path):
     corpus = read_corpus([tmp_path])
     assert corpus.n_files == 2
     assert not [f for f in corpus.files if f.error]
-    assert corpus.for_knob("epoch_chunk")
+    assert corpus.for_knob("prefetch_depth")
 
 
 def test_unreadable_file_is_note_not_crash(tmp_path):
@@ -162,7 +162,7 @@ def test_unreadable_file_is_note_not_crash(tmp_path):
     corpus = read_corpus([tmp_path])
     errors = [f for f in corpus.files if f.error]
     assert len(errors) == 1 and "torn" in errors[0].path
-    assert corpus.for_knob("epoch_chunk")  # the good file still counted
+    assert corpus.for_knob("prefetch_depth")  # the good file still counted
     assert corpus.meta()["skipped"][0]["path"] == errors[0].path
 
 
@@ -171,7 +171,7 @@ def test_jsonl_torn_tail_skipped(tmp_path):
         json.dumps(
             {
                 "event": "x",
-                "epoch_chunk": 8,
+                "prefetch_depth": 8,
                 "steady_state_sensor_timesteps_per_s": 80000.0,
             }
         ),
@@ -180,7 +180,7 @@ def test_jsonl_torn_tail_skipped(tmp_path):
     (tmp_path / "events.jsonl").write_text("\n".join(lines))
     corpus = read_corpus([tmp_path])
     assert corpus.files[0].error is None
-    assert [o.value for o in corpus.for_knob("epoch_chunk")] == [8]
+    assert [o.value for o in corpus.for_knob("prefetch_depth")] == [8]
 
 
 def test_queue_wait_histogram_derivation(tmp_path):
@@ -259,11 +259,15 @@ def test_trajectory_rows_are_observations(tmp_path):
 def test_context_inherits_downward(tmp_path):
     """A knob value stated on an ancestor object applies to signal
     fields on descendants (the telemetry-report nesting shape)."""
-    doc = {"epoch_chunk": 2, "nested": {"deeper": {"steady_state_epoch_s": 0.1}}}
+    doc = {
+        "prefetch_depth": 2,
+        "nested": {"deeper": {"steady_state_sensor_timesteps_per_s": 9e4}},
+    }
     _write(tmp_path / "results_x.json", doc)
     corpus = read_corpus([tmp_path])
-    obs = corpus.for_knob("epoch_chunk")
-    assert obs and obs[0].value == 2 and obs[0].metric == "steady_state_epoch_s"
+    obs = corpus.for_knob("prefetch_depth")
+    assert obs and obs[0].value == 2
+    assert obs[0].metric == "steady_state_sensor_timesteps_per_s"
 
 
 # --------------------------------------------------------------------------
@@ -280,19 +284,19 @@ def test_best_measured_arm_wins_max_objective(tmp_path):
     corpus = _sweep_corpus(
         tmp_path,
         [
-            {"epoch_chunk": 1, "steady_state_sensor_timesteps_per_s": 14000.0},
-            {"epoch_chunk": 4, "steady_state_sensor_timesteps_per_s": 52000.0},
-            {"epoch_chunk": 8, "steady_state_sensor_timesteps_per_s": 81000.0},
+            {"prefetch_depth": 0, "steady_state_sensor_timesteps_per_s": 14000.0},
+            {"prefetch_depth": 2, "steady_state_sensor_timesteps_per_s": 52000.0},
+            {"prefetch_depth": 4, "steady_state_sensor_timesteps_per_s": 81000.0},
         ],
     )
-    rec = fit_recommendations(corpus)["epoch_chunk"]
-    assert rec.value == 8 and rec.source == "measured"
+    rec = fit_recommendations(corpus)["prefetch_depth"]
+    assert rec.value == 4 and rec.source == "measured"
     assert rec.objective == "max"
     assert rec.predicted == pytest.approx(81000.0)
-    # default (1) was itself measured, so the delta is exact
+    # default (0) was itself measured, so the delta is exact
     assert rec.predicted_default == pytest.approx(14000.0)
     assert rec.improvement > 0
-    assert [arm.value for arm in rec.evidence] == [1, 4, 8]
+    assert [arm.value for arm in rec.evidence] == [0, 2, 4]
 
 
 def test_best_measured_arm_wins_min_objective(tmp_path):
@@ -325,53 +329,16 @@ def test_interpolation_at_unmeasured_default(tmp_path):
 
 
 def test_single_arm_no_measured_recommendation(tmp_path):
-    """One arm is not a sweep: no measured recommendation (and for
-    knobs without an analytic fallback, no recommendation at all)."""
-    corpus = _sweep_corpus(tmp_path, [{"batch_wait_ms": 5.0, "p99_ms": 22.0}])
-    assert "batch_wait_ms" not in fit_recommendations(corpus)
-
-
-def test_epoch_chunk_analytic_fallback(tmp_path):
-    """A thin corpus (one arm) still yields an epoch_chunk
-    recommendation through the monotonic analytic model over the
-    measured per-dispatch overhead, stamped source=analytic."""
+    """One arm is not a sweep: no recommendation, the default stands
+    (the tuner speaks only from measured arms)."""
     corpus = _sweep_corpus(
         tmp_path,
         [
-            {
-                "epoch_chunk": 1,
-                "n_dispatches": 10,
-                "steady_state_epoch_s": 0.05,
-                "dispatch_overhead_s": 0.5,  # 50ms/dispatch = 1x steady
-            }
+            {"batch_wait_ms": 5.0, "p99_ms": 22.0},
+            {"prefetch_depth": 2, "steady_state_sensor_timesteps_per_s": 9e4},
         ],
     )
-    rec = fit_recommendations(corpus)["epoch_chunk"]
-    assert rec.source == "analytic"
-    assert rec.value > 1 and rec.value & (rec.value - 1) == 0  # power of two
-    assert rec.predicted < rec.predicted_default  # modeled improvement
-
-
-def test_epoch_chunk_analytic_from_chunked_arm(tmp_path):
-    """dispatch_overhead_s is the fit's TOTAL dispatch overhead, so the
-    per-dispatch cost d is total/n_dispatches regardless of the chunk
-    size the arm ran at — an arm measured at epoch_chunk=4 must not
-    model 4x the true overhead."""
-    corpus = _sweep_corpus(
-        tmp_path,
-        [
-            {
-                "epoch_chunk": 4,
-                "n_dispatches": 4,
-                "steady_state_epoch_s": 0.05,
-                "dispatch_overhead_s": 0.2,  # d = 50ms/dispatch
-            }
-        ],
-    )
-    rec = fit_recommendations(corpus)["epoch_chunk"]
-    assert rec.source == "analytic"
-    # default (chunk 1): steady + d = 0.05 + 0.05, NOT 0.05 + 4*0.05
-    assert rec.predicted_default == pytest.approx(0.10)
+    assert fit_recommendations(corpus) == {}
 
 
 def test_empty_corpus_empty_recommendations(tmp_path):
@@ -396,18 +363,18 @@ def _minimal_profile(**recommendations):
 
 def test_profile_round_trip(tmp_path):
     path = _write(
-        tmp_path / TUNING_PROFILE_FILENAME, _minimal_profile(epoch_chunk=8)
+        tmp_path / TUNING_PROFILE_FILENAME, _minimal_profile(prefetch_depth=8)
     )
     profile = load_profile(path)
     assert validate_profile(profile) == []
-    assert recommended_values(profile) == {"epoch_chunk": 8}
+    assert recommended_values(profile) == {"prefetch_depth": 8}
 
 
 def test_future_profile_version_refuses_to_load(tmp_path):
     """The versioning pin: an unknown FUTURE profile_version refuses
     with a clear error instead of silently applying half-understood
     recommendations."""
-    payload = _minimal_profile(epoch_chunk=8)
+    payload = _minimal_profile(prefetch_depth=8)
     payload["profile_version"] = PROFILE_VERSION + 1
     path = _write(tmp_path / TUNING_PROFILE_FILENAME, payload)
     with pytest.raises(TuningProfileError) as err:
@@ -420,7 +387,7 @@ def test_future_profile_version_refuses_to_load(tmp_path):
 
 
 def test_unversioned_profile_refuses(tmp_path):
-    payload = _minimal_profile(epoch_chunk=8)
+    payload = _minimal_profile(prefetch_depth=8)
     del payload["profile_version"]
     path = _write(tmp_path / TUNING_PROFILE_FILENAME, payload)
     with pytest.raises(TuningProfileError, match="profile_version"):
@@ -430,7 +397,7 @@ def test_unversioned_profile_refuses(tmp_path):
 def test_validate_profile_catches_drift():
     """The tune plan --check body: renamed/removed knobs, out-of-domain
     values, and non-tunable recommendations are all named problems."""
-    profile = _minimal_profile(epoch_chunk=9999)  # outside int 1..512
+    profile = _minimal_profile(prefetch_depth=9999)  # outside int 0..8
     profile["recommendations"]["renamed_knob"] = {"value": 1}
     profile["recommendations"]["max_attempts"] = {"value": 3}  # non-tunable
     problems = validate_profile(profile)
@@ -443,9 +410,9 @@ def test_validate_profile_catches_drift():
 def test_recommended_values_skips_invalid_entries():
     """Serving must not fail on a drifted profile — invalid entries are
     skipped (the CI gate fails loudly instead)."""
-    profile = _minimal_profile(epoch_chunk=8, batch_wait_ms=-4.0)
+    profile = _minimal_profile(prefetch_depth=8, batch_wait_ms=-4.0)
     profile["recommendations"]["ghost"] = {"value": 1}
-    assert recommended_values(profile) == {"epoch_chunk": 8}
+    assert recommended_values(profile) == {"prefetch_depth": 8}
 
 
 def test_resolve_profile_path_env_override(tmp_path, monkeypatch):
@@ -464,10 +431,10 @@ def test_resolve_profile_path_env_override(tmp_path, monkeypatch):
 # tune CLI
 # --------------------------------------------------------------------------
 
-EPOCH_CHUNK_SWEEP = [
-    {"epoch_chunk": 1, "steady_state_sensor_timesteps_per_s": 14000.0},
-    {"epoch_chunk": 2, "steady_state_sensor_timesteps_per_s": 26000.0},
-    {"epoch_chunk": 4, "steady_state_sensor_timesteps_per_s": 21000.0},
+PREFETCH_SWEEP = [
+    {"prefetch_depth": 0, "steady_state_sensor_timesteps_per_s": 14000.0},
+    {"prefetch_depth": 2, "steady_state_sensor_timesteps_per_s": 26000.0},
+    {"prefetch_depth": 4, "steady_state_sensor_timesteps_per_s": 21000.0},
 ]
 
 BATCH_WAIT_SWEEP = [
@@ -486,13 +453,13 @@ BATCH_WAIT_SWEEP = [
 
 @pytest.fixture
 def recorded_corpus(tmp_path):
-    """THE acceptance corpus: an epoch_chunk sweep and a batching
+    """THE acceptance corpus: a prefetch_depth sweep and a batching
     queue-wait-histogram sweep, recorded the way the harnesses write
     them."""
     corpus_dir = tmp_path / "corpus"
     _write(
-        corpus_dir / "results_chunk_sweep.json",
-        {"bench_schema_version": 1, "epoch_chunk_sweep": EPOCH_CHUNK_SWEEP},
+        corpus_dir / "results_prefetch_sweep.json",
+        {"bench_schema_version": 1, "prefetch_sweep": PREFETCH_SWEEP},
     )
     _write(
         corpus_dir / "results_batch_sweep.json",
@@ -504,8 +471,9 @@ def recorded_corpus(tmp_path):
 def test_tune_plan_shows_evidence(runner, recorded_corpus):
     result = runner.invoke(gordo, ["tune", "plan", str(recorded_corpus)])
     assert result.exit_code == 0, result.output
-    assert "epoch_chunk" in result.output and "--epoch-chunk" in result.output
-    assert "1 -> 2" in result.output  # recommendation line
+    assert "prefetch_depth" in result.output
+    assert "--prefetch-depth" in result.output
+    assert "0 -> 2" in result.output  # recommendation line
     assert "<- best" in result.output  # evidence arm marker
     assert "batch_wait_ms" in result.output
 
@@ -516,22 +484,22 @@ def test_tune_plan_as_json(runner, recorded_corpus):
     )
     assert result.exit_code == 0, result.output
     payload = json.loads(result.output)
-    assert payload["recommendations"]["epoch_chunk"]["value"] == 2
+    assert payload["recommendations"]["prefetch_depth"]["value"] == 2
     assert payload["corpus"]["n_files"] == 2
 
 
 def test_tune_fit_acceptance(runner, recorded_corpus):
     """The acceptance pin: the recorded corpus yields a
-    tuning_profile.json whose recommended epoch_chunk and batch_wait_ms
+    tuning_profile.json whose recommended prefetch_depth and batch_wait_ms
     match the best measured arms."""
     result = runner.invoke(gordo, ["tune", "fit", str(recorded_corpus)])
     assert result.exit_code == 0, result.output
     profile = load_profile(recorded_corpus / TUNING_PROFILE_FILENAME)
     recs = profile["recommendations"]
-    assert recs["epoch_chunk"]["value"] == 2  # best measured arm
+    assert recs["prefetch_depth"]["value"] == 2  # best measured arm
     assert recs["batch_wait_ms"]["value"] == 5.0  # best measured arm
-    assert recs["epoch_chunk"]["source"] == "measured"
-    assert recs["epoch_chunk"]["evidence"]  # rows behind the call
+    assert recs["prefetch_depth"]["source"] == "measured"
+    assert recs["prefetch_depth"]["evidence"]  # rows behind the call
     assert validate_profile(profile) == []
 
 
@@ -540,13 +508,13 @@ def test_tune_plan_check_gate(runner, tmp_path):
     version or drifted knob fails with the problem count as exit
     code."""
     good = tmp_path / "good"
-    _write(good / TUNING_PROFILE_FILENAME, _minimal_profile(epoch_chunk=8))
+    _write(good / TUNING_PROFILE_FILENAME, _minimal_profile(prefetch_depth=8))
     result = runner.invoke(gordo, ["tune", "plan", "--check", str(good)])
     assert result.exit_code == 0, result.output
     assert "ok" in result.output
 
     bad = tmp_path / "bad"
-    payload = _minimal_profile(epoch_chunk=8)
+    payload = _minimal_profile(prefetch_depth=8)
     payload["profile_version"] = PROFILE_VERSION + 7
     _write(bad / TUNING_PROFILE_FILENAME, payload)
     drifted = _minimal_profile(removed_knob=3)
@@ -608,10 +576,10 @@ def _gauge_knobs():
 
 def test_build_fleet_applies_profile(runner, tmp_path):
     """build-fleet loads the collection's profile by default: the
-    recommended epoch_chunk reaches the trainer (telemetry report), and
-    the application is attributable (event + metric)."""
+    recommended prefetch_depth is applied, and the application is
+    attributable (event + metric)."""
     out_dir = tmp_path / "fleet-out"
-    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(epoch_chunk=2))
+    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(prefetch_depth=2))
     event_log = tmp_path / "events.jsonl"
     result = runner.invoke(
         gordo,
@@ -619,20 +587,18 @@ def test_build_fleet_applies_profile(runner, tmp_path):
         env={"GORDO_TPU_EVENT_LOG": str(event_log)},
     )
     assert result.exit_code == 0, result.output
-    report = json.loads((out_dir / "telemetry_report.json").read_text())
-    assert report["buckets"][0]["fit"]["epoch_chunk"] == 2
     events = _applied_events(event_log)
     assert len(events) == 1
-    assert events[0]["applied"] == {"epoch_chunk": 2}
+    assert events[0]["applied"] == {"prefetch_depth": 2}
     assert events[0]["subsystem"] == "builder"
-    assert "epoch_chunk" in _gauge_knobs()
+    assert _gauge_knobs() == {"prefetch_depth"}
 
 
 def test_build_fleet_explicit_flag_overrides_profile(runner, tmp_path):
-    """Precedence pin: an explicit --epoch-chunk beats the profile; the
+    """Precedence pin: an explicit --prefetch-depth beats the profile; the
     attribution event then names NO applied knobs."""
     out_dir = tmp_path / "fleet-out-explicit"
-    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(epoch_chunk=2))
+    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(prefetch_depth=2))
     event_log = tmp_path / "events.jsonl"
     result = runner.invoke(
         gordo,
@@ -640,33 +606,67 @@ def test_build_fleet_explicit_flag_overrides_profile(runner, tmp_path):
             "build-fleet",
             json.dumps(_fleet_machines()),
             str(out_dir),
-            "--epoch-chunk",
-            "1",
+            "--prefetch-depth",
+            "0",
         ],
         env={"GORDO_TPU_EVENT_LOG": str(event_log)},
     )
     assert result.exit_code == 0, result.output
-    report = json.loads((out_dir / "telemetry_report.json").read_text())
-    assert report["buckets"][0]["fit"]["epoch_chunk"] == 1
     # nothing applied -> no attribution event (a fully-explicit config,
     # e.g. every ledger worker child, must not spam empty events)
     assert _applied_events(event_log) == []
-    assert "epoch_chunk" not in _gauge_knobs()
+    assert _gauge_knobs() == set()
 
 
 def test_build_fleet_env_var_overrides_profile(runner, tmp_path):
     """The env-var spelling wins over the profile exactly like the
     flag (click's parameter-source view treats both as explicit)."""
     out_dir = tmp_path / "fleet-out-env"
-    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(epoch_chunk=2))
+    _write(out_dir / TUNING_PROFILE_FILENAME, _minimal_profile(prefetch_depth=2))
+    event_log = tmp_path / "events.jsonl"
     result = runner.invoke(
         gordo,
         ["build-fleet", json.dumps(_fleet_machines()), str(out_dir)],
-        env={"GORDO_EPOCH_CHUNK": "1"},
+        env={
+            "GORDO_PREFETCH_DEPTH": "0",
+            "GORDO_TPU_EVENT_LOG": str(event_log),
+        },
     )
     assert result.exit_code == 0, result.output
-    report = json.loads((out_dir / "telemetry_report.json").read_text())
-    assert report["buckets"][0]["fit"]["epoch_chunk"] == 1
+    assert _applied_events(event_log) == []
+    assert _gauge_knobs() == set()
+
+
+def test_profile_recommending_a_removed_knob_applies_the_rest(
+    runner, tmp_path, caplog
+):
+    """A profile fitted before PR 29 may recommend ``epoch_chunk``, which
+    the registry no longer has: the build skips that entry with the
+    unknown-knob warning and applies the live knob beside it, and
+    ``tune plan --check`` names the drift."""
+    out_dir = tmp_path / "fleet-out-legacy"
+    _write(
+        out_dir / TUNING_PROFILE_FILENAME,
+        _minimal_profile(epoch_chunk=4, prefetch_depth=2),
+    )
+    event_log = tmp_path / "events.jsonl"
+    with caplog.at_level("WARNING", logger="gordo_tpu.tuning.profile"):
+        result = runner.invoke(
+            gordo,
+            ["build-fleet", json.dumps(_fleet_machines()), str(out_dir)],
+            env={"GORDO_TPU_EVENT_LOG": str(event_log)},
+        )
+    assert result.exit_code == 0, result.output
+    (event,) = _applied_events(event_log)
+    assert event["applied"] == {"prefetch_depth": 2}
+    assert _gauge_knobs() == {"prefetch_depth"}
+    assert any(
+        "unknown/non-tunable" in r.getMessage() and "epoch_chunk" in r.getMessage()
+        for r in caplog.records
+    )
+    check = runner.invoke(gordo, ["tune", "plan", "--check", str(out_dir)])
+    assert check.exit_code == 1, check.output
+    assert "unknown knob 'epoch_chunk'" in check.output
 
 
 def test_build_fleet_no_profile_strict_noop(runner, tmp_path, monkeypatch):
@@ -688,8 +688,6 @@ def test_build_fleet_no_profile_strict_noop(runner, tmp_path, monkeypatch):
     assert result.exit_code == 0, result.output
     assert _applied_events(event_log) == []
     assert _gauge_knobs() == set()
-    report = json.loads((out_dir / "telemetry_report.json").read_text())
-    assert report["buckets"][0]["fit"]["epoch_chunk"] == 1  # built-in default
 
 
 def test_build_app_applies_profile(tmp_path, monkeypatch):
@@ -791,16 +789,25 @@ def test_run_server_cli_passes_only_explicit_knobs(runner, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# calibration (the no-corpus path) — real sweep, so marked slow
+# calibration (the no-corpus path)
 # --------------------------------------------------------------------------
+
+
+def test_tune_calibrate_without_a_sweep_is_a_usage_error(runner, tmp_path):
+    """The batch-wait sweep is all calibrate measures: asked for none it
+    says so and writes nothing."""
+    out = tmp_path / "calib"
+    result = runner.invoke(gordo, ["tune", "calibrate", str(out)])
+    assert result.exit_code == 2, result.output
+    assert "--batch-wait-sweep" in result.output
+    assert not out.exists()
 
 
 @pytest.mark.slow
 def test_tune_calibrate_end_to_end(runner, tmp_path):
-    """tune calibrate measures a fresh epoch_chunk corpus on a tiny
-    synthetic fleet (plus a short in-process batch-wait serving sweep)
-    and fits a profile from it — calibration is just a way of growing a
-    corpus."""
+    """tune calibrate measures a fresh batch_wait_ms corpus (a short
+    in-process serving sweep) and fits a profile from it — calibration is
+    just a way of growing a corpus. A real sweep, so marked slow."""
     collection_before = os.environ.get("MODEL_COLLECTION_DIR")
     out = tmp_path / "calib"
     result = runner.invoke(
@@ -809,14 +816,6 @@ def test_tune_calibrate_end_to_end(runner, tmp_path):
             "tune",
             "calibrate",
             str(out),
-            "--epoch-chunks",
-            "1,2",
-            "--machines",
-            "2",
-            "--rows",
-            "64",
-            "--epochs",
-            "4",
             "--batch-wait-sweep",
             "0,10",
             "--rps",
@@ -830,7 +829,6 @@ def test_tune_calibrate_end_to_end(runner, tmp_path):
     assert corpus_file.exists()
     payload = json.loads(corpus_file.read_text())
     assert payload["bench_schema_version"] == 1
-    assert {row["epoch_chunk"] for row in payload["epoch_chunk_sweep"]} == {1, 2}
     # the serving sweep's requests must have actually succeeded — a
     # wrong route/body shape would file everything under errors and
     # leave arms without latency evidence
@@ -841,7 +839,6 @@ def test_tune_calibrate_end_to_end(runner, tmp_path):
     profile = load_profile(out / TUNING_PROFILE_FILENAME)
     assert validate_profile(profile) == []
     corpus = read_corpus([out])
-    assert corpus.for_knob("epoch_chunk")
     assert corpus.for_knob("batch_wait_ms")
     # the sweep's throwaway collection env var must not leak
     assert os.environ.get("MODEL_COLLECTION_DIR") == collection_before
