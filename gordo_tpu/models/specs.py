@@ -15,6 +15,7 @@ float32 for stable optimizer math.
 """
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -178,6 +179,27 @@ class FeedForwardNet(nn.Module):
         return resolve_activation(self.out_func)(x).astype(jnp.float32), penalty
 
 
+def lstm_gates(h, z_t, w_h, b_h, dtype):
+    """
+    The four gates of one LSTM timestep before their activations, float32
+    (batch, 4h) in the order [i, f, g, o], from pre-projected input
+    ``z_t``: the recurrent matmul runs in ``dtype`` (MXU).
+    """
+    return (z_t + h.astype(dtype) @ w_h + b_h).astype(jnp.float32)
+
+
+def lstm_cell_update(c, gates, act):
+    """
+    The elementwise half of a timestep, in float32: sigmoid on i, f and o,
+    ``act`` on g and on the new cell state. Returns the new (c, h).
+    """
+    i, f, g, o = jnp.split(gates, 4, axis=-1)
+    i, f, o = nn.sigmoid(i), nn.sigmoid(f), nn.sigmoid(o)
+    c = f * c + i * act(g)
+    h = o * act(c)
+    return c, h
+
+
 def lstm_cell_step(c, h, z_t, w_h, b_h, act, dtype):
     """
     One LSTM timestep from pre-projected input ``z_t`` (gate order
@@ -186,12 +208,160 @@ def lstm_cell_step(c, h, z_t, w_h, b_h, act, dtype):
     OptimizedLSTMCell's float32 (param_dtype) carry. Shared by both the
     per-layer and the stacked schedules so the cell math lives ONCE.
     """
-    gates = (z_t + h.astype(dtype) @ w_h + b_h).astype(jnp.float32)
-    i, f, g, o = jnp.split(gates, 4, axis=-1)
-    i, f, o = nn.sigmoid(i), nn.sigmoid(f), nn.sigmoid(o)
-    c = f * c + i * act(g)
-    h = o * act(c)
-    return c, h
+    return lstm_cell_update(c, lstm_gates(h, z_t, w_h, b_h, dtype), act)
+
+
+@functools.lru_cache(maxsize=None)
+def _step_writer(n_stacked):
+    """
+    ``write(buffer, value, t)``: ``value`` as step ``t``'s row of a stacked
+    buffer that has ``n_stacked`` leading axes before its time axis. It is
+    its own rule under ``vmap``: one axis more in front, the same
+    ``dynamic_update_slice`` on the time axis. JAX's own batching rule
+    turns the update into a scatter, which on the chip reads the old row
+    and selects against it before it writes the new one.
+    """
+
+    @jax.custom_batching.custom_vmap
+    def write(buffer, value, t):
+        return jax.lax.dynamic_update_slice_in_dim(
+            buffer, jnp.expand_dims(value, n_stacked), t, n_stacked
+        )
+
+    @write.def_vmap
+    def write_stacked(axis_size, in_batched, buffer, value, t):
+        if in_batched[2]:
+            raise NotImplementedError("a time scan's step index is one for the stack")
+        buffer, value = (
+            x if batched else jnp.broadcast_to(x, (axis_size, *x.shape))
+            for x, batched in zip((buffer, value), in_batched)
+        )
+        return _step_writer(n_stacked + 1)(buffer, value, t), True
+
+    return write
+
+
+def _write_step(buffer, value, t):
+    return _step_writer(0)(buffer, value, t)
+
+
+def _read_step(buffer, t):
+    return jax.lax.dynamic_index_in_dim(buffer, t, 0, keepdims=False)
+
+
+def _lstm_forward(act, dtype, unroll, keep_residuals, z, w_h, b_h):
+    """
+    The recurrence over time-major ``z`` (time, batch, 4h): a counted loop
+    that writes step t's row of each stacked buffer and nothing else of
+    it. The buffers start uninitialised (``jax.lax.empty``: on a TPU an
+    ``AllocateBuffer``, no fill), and every row is written before anything
+    reads it. Without ``keep_residuals`` the hidden states are all that is
+    stacked; with it also what the backward loop reads: the gates before
+    their activations, one (time, batch, 4h) buffer, and the cell states.
+    """
+    n_steps, batch, h_dim = z.shape[0], z.shape[1], z.shape[2] // 4
+    state = jnp.zeros((batch, h_dim), jnp.float32)
+    stacked = [jax.lax.empty((n_steps, batch, h_dim), jnp.float32)]
+    if keep_residuals:
+        stacked += [
+            jax.lax.empty((n_steps, batch, 4 * h_dim), jnp.float32),
+            jax.lax.empty((n_steps, batch, h_dim), jnp.float32),
+        ]
+
+    def body(t, carry):
+        c, h, stacked = carry
+        gates = lstm_gates(h, _read_step(z, t), w_h, b_h, dtype)
+        c, h = lstm_cell_update(c, gates, act)
+        rows = [h, gates, c] if keep_residuals else [h]
+        return c, h, [_write_step(b, r, t) for b, r in zip(stacked, rows)]
+
+    _, _, stacked = jax.lax.fori_loop(
+        0, n_steps, body, (state, state, stacked), unroll=unroll
+    )
+    return stacked
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def lstm_time_scan(act, dtype, unroll, z, w_h, b_h):
+    """
+    The hidden states (time, batch, h), float32, of one LSTM layer over its
+    pre-projected time-major input ``z`` (time, batch, 4h): step for step
+    :func:`lstm_cell_step` from a zero state.
+
+    Why not ``jax.lax.scan`` under autodiff: ``scan`` starts every stacked
+    output as a broadcast zero (jax 0.9.0, ``loops.py`` ``_empty_array``),
+    autodiff makes 11-12 (time, batch, h) residuals a layer such outputs,
+    and on the chip the fleet's step program wrote all of them whole every
+    step before the scans overwrote them row by row: 6.5 GB a step in the
+    50-tag plant (PERF.md section 6, PR 30). Here forward and backward are
+    each one counted loop over buffers that are never filled, and what is
+    kept from the forward pass is chosen: the gates, the cell states and
+    the output itself. A new recurrent layer uses this shape of scan, not
+    ``lax.scan`` under autodiff.
+    """
+    (hs,) = _lstm_forward(act, dtype, unroll, False, z, w_h, b_h)
+    return hs
+
+
+def _lstm_time_scan_fwd(act, dtype, unroll, z, w_h, b_h):
+    hs, gates, cs = _lstm_forward(act, dtype, unroll, True, z, w_h, b_h)
+    return hs, (hs, gates, cs, w_h)
+
+
+def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
+    """
+    The transposed recurrence, last step first. A step's elementwise half
+    is transposed by autodiff from the kept gates and the previous cell
+    state (its activations are computed again, no product is); the two
+    transposes of ``h @ w_h`` are written as autodiff writes them, so the
+    lowered program's list of products is the autodiff scan's: ``d_gates``
+    against ``w_h`` into the previous hidden state, against the previous
+    hidden state into ``d_w`` (accumulated in the carry, as the bias's sum
+    is), and ``d_gates`` itself stacked as the cotangent of ``z`` for the
+    hoisted projection's backward.
+    """
+    hs, gates, cs, w_h = residuals
+    n_steps, batch, h_dim = hs.shape
+    zeros = jnp.zeros((batch, h_dim), jnp.float32)
+
+    def previous(buffer, t):
+        # step 0 started from the zero state
+        row = _read_step(buffer, jnp.maximum(t - 1, 0))
+        return jnp.where(t > 0, row, 0.0)
+
+    def body(k, carry):
+        d_c, d_h, d_z, d_w, d_b = carry
+        t = n_steps - 1 - k
+        _, update_vjp = jax.vjp(
+            lambda c, g: lstm_cell_update(c, g, act),
+            previous(cs, t),
+            _read_step(gates, t),
+        )
+        d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t)))
+        d_gates = d_gates.astype(dtype)
+        d_w = d_w + jax.lax.dot_general(
+            d_gates, previous(hs, t).astype(dtype), (((0,), (0,)), ((), ()))
+        ).T
+        d_b = d_b + jax.lax.reduce_sum(d_gates, axes=(0,))
+        d_h = jax.lax.dot_general(
+            d_gates, w_h, (((1,), (1,)), ((), ()))
+        ).astype(jnp.float32)
+        return d_c, d_h, _write_step(d_z, d_gates, t), d_w, d_b
+
+    carry = (
+        zeros,
+        zeros,
+        jax.lax.empty((n_steps, batch, 4 * h_dim), dtype),
+        jnp.zeros_like(w_h),
+        jnp.zeros((4 * h_dim,), dtype),
+    )
+    _, _, d_z, d_w, d_b = jax.lax.fori_loop(
+        0, n_steps, body, carry, unroll=unroll
+    )
+    return d_z, d_w, d_b
+
+
+lstm_time_scan.defvjp(_lstm_time_scan_fwd, _lstm_time_scan_bwd)
 
 
 def gru_cell_step(h, z_t, w_rz, w_n, b_n, act, dtype, h_dim):
@@ -216,16 +386,20 @@ class FusedLSTMLayer(nn.Module):
     (f, 4h) product (MXU-sized), and the scan carries only the recurrent
     h@W_h matmul. Same math as ``nn.RNN(OptimizedLSTMCell)`` — gate order
     [i, f, g, o], sigmoid gates, ``activation_fn`` on g and the cell
-    output — with a TPU-friendlier schedule.
+    output — with a TPU-friendlier schedule. The time scan is
+    :func:`lstm_time_scan`, forward and backward written out as counted
+    loops over buffers that are allocated and never filled; the layer has
+    no other scan.
     """
 
     features: int
     activation_fn: Any = jnp.tanh
     dtype: Any = jnp.float32
-    # time-scan unroll factor: XLA fuses gate math across consecutive
-    # steps, shrinking per-step carry copies (the dominant non-matmul
-    # cost in the CPU fallback's trace) and loop overhead; a pure
-    # schedule knob — the math is step-for-step identical
+    # time-scan unroll factor (of the forward and of the backward loop):
+    # XLA fuses gate math across consecutive steps, shrinking per-step
+    # carry copies (the dominant non-matmul cost in the CPU fallback's
+    # trace) and loop overhead; a pure schedule knob — the math is
+    # step-for-step identical
     unroll: int = 1
     # time_major=True: x is (time, batch, f) and the output sequence comes
     # back (time, batch, h) — the scan consumes/produces that layout
@@ -258,26 +432,17 @@ class FusedLSTMLayer(nn.Module):
         b_h = self.param(
             "recurrent_bias", nn.initializers.zeros_init(), (4 * h_dim,), jnp.float32
         ).astype(self.dtype)
-        act = self.activation_fn
-
-        def step(carry, z_t):
-            c, h = lstm_cell_step(*carry, z_t, w_h, b_h, act, self.dtype)
-            return (c, h), h
-
-        batch = x.shape[1] if self.time_major else x.shape[0]
-        carry0 = (
-            jnp.zeros((batch, h_dim), dtype=jnp.float32),
-            jnp.zeros((batch, h_dim), dtype=jnp.float32),
-        )
         # a stable name for "the time scan of this layer" on a device trace
         # (.../FusedLSTMLayer_k/scan/..., under transpose(jvp(...)) for the
         # backward pass), with the layout swaps that feed and drain it
         with jax.named_scope("scan"):
-            _, hs = jax.lax.scan(
-                step,
-                carry0,
+            hs = lstm_time_scan(
+                self.activation_fn,
+                self.dtype,
+                max(1, int(self.unroll)),
                 z if self.time_major else z.swapaxes(0, 1),
-                unroll=max(1, int(self.unroll)),
+                w_h,
+                b_h,
             )
             hs = hs if self.time_major else hs.swapaxes(0, 1)
         return hs.astype(self.dtype)

@@ -756,8 +756,8 @@ class FleetTrainer:
             step_ids = jnp.arange(n_batches, dtype=jnp.int32)
             # fleet.step holds what the step loop runs beside the named
             # parts of a step: the loop's own plumbing and the buffers XLA
-            # makes for it (on the chip, the zero-fill of the model scans'
-            # stacked outputs carries the step body's path and no more)
+            # makes for it (a fill of a scan's stacked outputs would carry
+            # this path: specs.lstm_time_scan allocates and never fills)
             with jax.named_scope("fleet.step"):
                 (new_params, new_opt), (loss_sums, w_sums) = jax.lax.scan(
                     step,

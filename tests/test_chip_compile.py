@@ -13,6 +13,7 @@ steered HERE (``compiled_kernels``), not through a program option.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -194,6 +195,40 @@ def test_fleet_epoch_program_compiles(chip):
         N_TIMESTEPS, BATCH, True, quarantine=True
     ).lower(*fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip), healthy).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_lstm_cell_epoch_program_fills_no_stacked_buffer(chip):
+    """``lstm50.fit``'s epoch program at the cell's own widths (4 machines,
+    quarantine on): the time scans' stacked buffers, 64 steps x 4 machines
+    x 512 rows, are allocated and never filled. Under ``jax.lax.scan`` and
+    autodiff the step body wrote 78 of them whole, 6.5 GB a step, before
+    the scans overwrote them row by row, and XLA's own rewrite of such a
+    fill did not fire (PERF.md section 6, PR 30)."""
+    n_machines = 4
+    spec = lstm_model(
+        n_features=N_TAGS, lookback_window=LOOKBACK,
+        encoding_dim=(256, 128, 64), encoding_func=("tanh",) * 3,
+        decoding_dim=(64, 128, 256), decoding_func=("tanh",) * 3,
+        fused=True,
+    )
+    trainer = FleetTrainer(spec, lookahead=0)
+    healthy = jax.ShapeDtypeStruct((n_machines,), jnp.bool_, sharding=chip)
+    compiled = trainer._epoch_fn(
+        N_TIMESTEPS, BATCH, True, quarantine=True
+    ).lower(
+        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=n_machines),
+        healthy,
+    ).compile()
+    stacked = rf"= \w+\[(?:{LOOKBACK},{n_machines}|{n_machines},{LOOKBACK}),{BATCH},\d+\]\S* "
+    text = compiled.as_text()
+    assert not re.findall(stacked + r"broadcast\(", text)
+    allocated = [
+        line for line in re.findall(stacked + r"custom-call\(.*", text)
+        if "AllocateBuffer" in line
+    ]
+    # a layer: hidden states, gates and cell states forward, d_z backward
+    assert len(allocated) == 4 * len(spec.module.layer_dims)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8.5e9
 
 
 def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):

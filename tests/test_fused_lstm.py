@@ -5,12 +5,17 @@ weights (gate order [i, f, g, o]), and train end-to-end through the
 standard estimator machinery.
 """
 
+import pickle
+from typing import Any
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from gordo_tpu.models import LSTMAutoEncoder
-from gordo_tpu.models.specs import LSTMNet
+from gordo_tpu.models.specs import FusedLSTMLayer, LSTMNet, lstm_cell_step
 
 B, T, F, H = 3, 7, 5, 8
 
@@ -153,8 +158,6 @@ def test_stacked_estimator_trains_and_predicts():
 
 
 def test_fused_estimator_trains_and_pickles():
-    import pickle
-
     rng = np.random.default_rng(2)
     X = rng.random((80, F)).astype("float32")
     model = LSTMAutoEncoder(
@@ -197,3 +200,166 @@ def test_time_unroll_is_pure_schedule():
     out_rolled, _ = rolled.module.apply(params, x)
     out_unrolled, _ = unrolled.module.apply(params, x)
     np.testing.assert_allclose(out_unrolled, out_rolled, rtol=1e-6, atol=1e-7)
+
+
+# -- the time scan against lax.scan under autodiff ---------------------------
+
+
+class AutodiffScanLSTMLayer(nn.Module):
+    """FusedLSTMLayer as it was before its time scan became
+    ``lstm_time_scan``: ``jax.lax.scan`` over ``lstm_cell_step``, its
+    backward pass left to autodiff. Same parameter tree: the reference the
+    hand-written scan is held to."""
+
+    features: int
+    activation_fn: Any = jnp.tanh
+    dtype: Any = jnp.float32
+    unroll: int = 1
+    time_major: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        h_dim = self.features
+        lead = x.shape[:-1]
+        z = nn.Dense(
+            4 * h_dim, use_bias=False, dtype=self.dtype, name="input_proj"
+        )(x.reshape(-1, x.shape[-1]))
+        z = z.reshape(*lead, 4 * h_dim)
+        w_h = self.param(
+            "recurrent_kernel", nn.initializers.orthogonal(),
+            (h_dim, 4 * h_dim), jnp.float32,
+        ).astype(self.dtype)
+        b_h = self.param(
+            "recurrent_bias", nn.initializers.zeros_init(), (4 * h_dim,), jnp.float32
+        ).astype(self.dtype)
+
+        def step(carry, z_t):
+            c, h = lstm_cell_step(
+                *carry, z_t, w_h, b_h, self.activation_fn, self.dtype
+            )
+            return (c, h), h
+
+        batch = x.shape[1] if self.time_major else x.shape[0]
+        zeros = jnp.zeros((batch, h_dim), jnp.float32)
+        _, hs = jax.lax.scan(
+            step, (zeros, zeros), z if self.time_major else z.swapaxes(0, 1),
+            unroll=self.unroll,
+        )
+        hs = hs if self.time_major else hs.swapaxes(0, 1)
+        return hs.astype(self.dtype)
+
+
+N_MACHINES, N_STEPS = 3, 2
+
+
+def fleet_steps(layer, params, xs, probe):
+    """What the fleet's epoch program does with a layer: a ``lax.scan``
+    over steps, each the ``vmap`` over machines of a loss and its gradients
+    (here with respect to every parameter and the input)."""
+
+    def machine(p, x, w):
+        def loss(p, x):
+            hs = layer.apply(p, x)
+            return jnp.sum(hs.astype(jnp.float32) * w), hs
+
+        (_, hs), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+        return hs, grads
+
+    def step(_, x):
+        return None, jax.vmap(machine)(params, x, probe)
+
+    return jax.lax.scan(step, None, xs)[1]
+
+
+def fleet_inputs(rng, layer, n_time, width, time_major):
+    shape = (n_time, B, F) if time_major else (B, n_time, F)
+    xs = jnp.asarray(rng.normal(size=(N_STEPS, N_MACHINES, *shape)), jnp.float32)
+    out = shape[:-1] + (width,)
+    probe = jnp.asarray(rng.normal(size=(N_MACHINES, *out)), jnp.float32)
+    params = jax.vmap(lambda k: layer.init(k, xs[0, 0]))(
+        jax.random.split(jax.random.PRNGKey(7), N_MACHINES)
+    )
+    # the bias starts at zero: give every gate one
+    params["params"]["recurrent_bias"] = jnp.asarray(
+        rng.normal(size=(N_MACHINES, 4 * width)) * 0.1, jnp.float32
+    )
+    return params, xs, probe
+
+
+def assert_same_tree(got, want, dtype):
+    """float32 to 1e-6 of the reference's largest entry per leaf; bfloat16 to
+    a few of its roundings (the two backward passes sum in another order)."""
+    tol = 1e-6 if dtype == jnp.float32 else 3e-2
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+        np.testing.assert_allclose(g, w, rtol=0, atol=tol * max(np.abs(w).max(), 1e-3))
+
+
+@pytest.mark.parametrize("width", [8, 16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("time_unroll", [1, 4])
+@pytest.mark.parametrize("time_major", [False, True], ids=["batch_major", "time_major"])
+def test_time_scan_matches_autodiff_scan(time_major, time_unroll, dtype, width):
+    """Outputs and the gradients of all three parameters and of ``x``, as
+    the fleet's epoch program takes them."""
+    kwargs = dict(unroll=time_unroll, time_major=time_major, dtype=dtype)
+    layer = FusedLSTMLayer(width, **kwargs)
+    reference = AutodiffScanLSTMLayer(width, **kwargs)
+    params, xs, probe = fleet_inputs(
+        np.random.default_rng(width + time_unroll), layer, T, width, time_major
+    )
+    hs, (d_params, d_x) = fleet_steps(layer, params, xs, probe)
+    hs_ref, (d_params_ref, d_x_ref) = fleet_steps(reference, params, xs, probe)
+    assert hs.shape == (N_STEPS, N_MACHINES) + probe.shape[1:]
+    assert set(d_params["params"]) == {"input_proj", "recurrent_kernel", "recurrent_bias"}
+    assert_same_tree(hs, hs_ref, dtype)
+    assert_same_tree((d_params, d_x), (d_params_ref, d_x_ref), dtype)
+
+
+@pytest.mark.parametrize("activation", ["tanh", "relu"])
+def test_time_scan_of_one_step(activation):
+    """A LOOKBACK of 1: the loops run once, from the zero state; and an
+    activation that is not tanh (its derivative is taken from its input)."""
+    from gordo_tpu.ops.activations import resolve_activation
+
+    act = resolve_activation(activation)
+    layer = FusedLSTMLayer(H, activation_fn=act, time_major=True)
+    reference = AutodiffScanLSTMLayer(H, activation_fn=act, time_major=True)
+    params, xs, probe = fleet_inputs(np.random.default_rng(1), layer, 1, H, True)
+    got, want = (fleet_steps(m, params, xs, probe) for m in (layer, reference))
+    assert_same_tree(got, want, jnp.float32)
+
+
+def count_stacked_writes(fn, *args):
+    return jax.jit(fn).lower(*args).as_text().count("dynamic_update_slice")
+
+
+def test_forward_only_call_stacks_one_buffer_a_layer():
+    """Scoring, validation and streaming take no gradient: the scan then
+    stacks the hidden states and nothing else. Under a gradient it stacks
+    the gates (before their activations) and the cell states beside them, and
+    ``d_z`` on the way back."""
+    x = jnp.zeros((T, B, F))
+    layer = FusedLSTMLayer(H, time_major=True)
+    params = layer.init(jax.random.PRNGKey(0), x)
+    assert count_stacked_writes(layer.apply, params, x) == 1
+    assert count_stacked_writes(
+        jax.grad(lambda p, x: jnp.sum(layer.apply(p, x))), params, x
+    ) == 4
+
+
+def test_estimator_with_a_lookback_of_one_trains_and_pickles():
+    rng = np.random.default_rng(6)
+    X = rng.random((40, F)).astype("float32")
+    model = LSTMAutoEncoder(
+        kind="lstm_model", lookback_window=1,
+        encoding_dim=(8,), encoding_func=("tanh",),
+        decoding_dim=(8,), decoding_func=("tanh",),
+        fused=True, epochs=2,
+    )
+    model.fit(X, X)
+    out = model.predict(X)
+    assert out.shape == (40, F) and np.isfinite(out).all()
+    clone = pickle.loads(pickle.dumps(model))
+    np.testing.assert_allclose(clone.predict(X), out, rtol=1e-5)
