@@ -214,63 +214,74 @@ def lstm_cell_step(c, h, z_t, w_h, b_h, act, dtype):
 @functools.lru_cache(maxsize=None)
 def _step_writer(n_stacked):
     """
-    ``write(buffer, value, t)``: ``value`` as step ``t``'s row of a stacked
-    buffer that has ``n_stacked`` leading axes before its time axis. It is
+    ``write(buffer, rows, start)``: ``rows`` (batch, width) as one step's
+    rows of a row-flat stacked buffer (time*batch, width), from row
+    ``start`` on; ``n_stacked`` axes stand in front of the row axis. It is
     its own rule under ``vmap``: one axis more in front, the same
-    ``dynamic_update_slice`` on the time axis. JAX's own batching rule
-    turns the update into a scatter, which on the chip reads the old row
-    and selects against it before it writes the new one.
+    ``dynamic_update_slice`` on the row axis. JAX's own batching rule
+    turns the update into a scatter, which on the chip reads the old rows
+    and selects against them before it writes the new ones.
     """
 
     @jax.custom_batching.custom_vmap
-    def write(buffer, value, t):
-        return jax.lax.dynamic_update_slice_in_dim(
-            buffer, jnp.expand_dims(value, n_stacked), t, n_stacked
-        )
+    def write(buffer, rows, start):
+        return jax.lax.dynamic_update_slice_in_dim(buffer, rows, start, n_stacked)
 
     @write.def_vmap
-    def write_stacked(axis_size, in_batched, buffer, value, t):
+    def write_stacked(axis_size, in_batched, buffer, rows, start):
         if in_batched[2]:
             raise NotImplementedError("a time scan's step index is one for the stack")
-        buffer, value = (
+        buffer, rows = (
             x if batched else jnp.broadcast_to(x, (axis_size, *x.shape))
-            for x, batched in zip((buffer, value), in_batched)
+            for x, batched in zip((buffer, rows), in_batched)
         )
-        return _step_writer(n_stacked + 1)(buffer, value, t), True
+        return _step_writer(n_stacked + 1)(buffer, rows, start), True
 
     return write
 
 
-def _write_step(buffer, value, t):
-    return _step_writer(0)(buffer, value, t)
+def _write_step(buffer, rows, t):
+    return _step_writer(0)(buffer, rows, t * rows.shape[0])
 
 
-def _read_step(buffer, t):
-    return jax.lax.dynamic_index_in_dim(buffer, t, 0, keepdims=False)
+def _read_step(buffer, t, batch):
+    return jax.lax.dynamic_slice_in_dim(buffer, t * batch, batch, 0)
 
 
 def _lstm_forward(act, dtype, unroll, keep_residuals, z, w_h, b_h):
     """
     The recurrence over time-major ``z`` (time, batch, 4h): a counted loop
-    that writes step t's row of each stacked buffer and nothing else of
+    that writes step t's rows of each stacked buffer and nothing else of
     it. The buffers start uninitialised (``jax.lax.empty``: on a TPU an
     ``AllocateBuffer``, no fill), and every row is written before anything
     reads it. Without ``keep_residuals`` the hidden states are all that is
     stacked; with it also what the backward loop reads: the gates before
-    their activations, one (time, batch, 4h) buffer, and the cell states.
+    their activations, 4h wide, and the cell states.
+
+    Every stacked buffer, ``z`` among them, is ROW-FLAT, (time*batch,
+    width) with step t at rows ``t*batch`` on, and has no time axis: that
+    is the shape the hoisted projection multiplies (``x.reshape(-1, f)``)
+    and makes. XLA lays a buffer with the axis its loop indexes outermost,
+    so under the fleet's ``vmap`` a (time, batch, width) buffer came out
+    time-major, machines inside, and every ``hs`` and ``d_z`` was copied
+    whole to turn it machine-major for the projection's products (13
+    copies, 1.17 GB written a step in the 50-tag plant: PERF.md section 6,
+    PR 33). A loop that indexes rows leaves the machines in front, and the
+    reshapes at :func:`lstm_time_scan`'s edge compile to nothing.
     """
     n_steps, batch, h_dim = z.shape[0], z.shape[1], z.shape[2] // 4
+    z = z.reshape(n_steps * batch, 4 * h_dim)
     state = jnp.zeros((batch, h_dim), jnp.float32)
-    stacked = [jax.lax.empty((n_steps, batch, h_dim), jnp.float32)]
+    stacked = [jax.lax.empty((n_steps * batch, h_dim), jnp.float32)]
     if keep_residuals:
         stacked += [
-            jax.lax.empty((n_steps, batch, 4 * h_dim), jnp.float32),
-            jax.lax.empty((n_steps, batch, h_dim), jnp.float32),
+            jax.lax.empty((n_steps * batch, 4 * h_dim), jnp.float32),
+            jax.lax.empty((n_steps * batch, h_dim), jnp.float32),
         ]
 
     def body(t, carry):
         c, h, stacked = carry
-        gates = lstm_gates(h, _read_step(z, t), w_h, b_h, dtype)
+        gates = lstm_gates(h, _read_step(z, t, batch), w_h, b_h, dtype)
         c, h = lstm_cell_update(c, gates, act)
         rows = [h, gates, c] if keep_residuals else [h]
         return c, h, [_write_step(b, r, t) for b, r in zip(stacked, rows)]
@@ -296,16 +307,19 @@ def lstm_time_scan(act, dtype, unroll, z, w_h, b_h):
     50-tag plant (PERF.md section 6, PR 30). Here forward and backward are
     each one counted loop over buffers that are never filled, and what is
     kept from the forward pass is chosen: the gates, the cell states and
-    the output itself. A new recurrent layer uses this shape of scan, not
+    the output itself. The time axis exists at this function's edge only:
+    inside, every stacked buffer is the projection's (time*batch, width)
+    rows (:func:`_lstm_forward` says why), so nothing is turned between a
+    loop and a product. A new recurrent layer uses this shape of scan, not
     ``lax.scan`` under autodiff.
     """
     (hs,) = _lstm_forward(act, dtype, unroll, False, z, w_h, b_h)
-    return hs
+    return hs.reshape(*z.shape[:2], -1)
 
 
 def _lstm_time_scan_fwd(act, dtype, unroll, z, w_h, b_h):
     hs, gates, cs = _lstm_forward(act, dtype, unroll, True, z, w_h, b_h)
-    return hs, (hs, gates, cs, w_h)
+    return hs.reshape(*z.shape[:2], -1), (hs, gates, cs, w_h)
 
 
 def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
@@ -318,26 +332,32 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     against ``w_h`` into the previous hidden state, against the previous
     hidden state into ``d_w`` (accumulated in the carry, as the bias's sum
     is), and ``d_gates`` itself stacked as the cotangent of ``z`` for the
-    hoisted projection's backward.
+    hoisted projection's backward. The residuals, ``d_hs`` and ``d_z`` are
+    row-flat, as the forward loop's buffers are.
     """
     hs, gates, cs, w_h = residuals
-    n_steps, batch, h_dim = hs.shape
+    n_steps, batch, h_dim = d_hs.shape
+    d_hs = d_hs.reshape(n_steps * batch, h_dim)
     zeros = jnp.zeros((batch, h_dim), jnp.float32)
 
     def previous(buffer, t):
         # step 0 started from the zero state
-        row = _read_step(buffer, jnp.maximum(t - 1, 0))
-        return jnp.where(t > 0, row, 0.0)
+        rows = _read_step(buffer, jnp.maximum(t - 1, 0), batch)
+        return jnp.where(t > 0, rows, 0.0)
 
     def body(k, carry):
         d_c, d_h, d_z, d_w, d_b = carry
         t = n_steps - 1 - k
+        # the step's gates are read ONCE, as one slab, before the update's
+        # transpose takes its quarters of them: fused into that transpose,
+        # as XLA fuses a slice of rows, each of its two fusions reads the
+        # four quarters out of the 4h-wide buffer again (lstm50.fit on a
+        # v5e: 310 ms an epoch for 240; PERF.md section 6, PR 33)
+        step_gates = jax.lax.optimization_barrier(_read_step(gates, t, batch))
         _, update_vjp = jax.vjp(
-            lambda c, g: lstm_cell_update(c, g, act),
-            previous(cs, t),
-            _read_step(gates, t),
+            lambda c, g: lstm_cell_update(c, g, act), previous(cs, t), step_gates
         )
-        d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t)))
+        d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t, batch)))
         d_gates = d_gates.astype(dtype)
         d_w = d_w + jax.lax.dot_general(
             d_gates, previous(hs, t).astype(dtype), (((0,), (0,)), ((), ()))
@@ -351,14 +371,14 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     carry = (
         zeros,
         zeros,
-        jax.lax.empty((n_steps, batch, 4 * h_dim), dtype),
+        jax.lax.empty((n_steps * batch, 4 * h_dim), dtype),
         jnp.zeros_like(w_h),
         jnp.zeros((4 * h_dim,), dtype),
     )
     _, _, d_z, d_w, d_b = jax.lax.fori_loop(
         0, n_steps, body, carry, unroll=unroll
     )
-    return d_z, d_w, d_b
+    return d_z.reshape(n_steps, batch, 4 * h_dim), d_w, d_b
 
 
 lstm_time_scan.defvjp(_lstm_time_scan_fwd, _lstm_time_scan_bwd)
@@ -402,11 +422,14 @@ class FusedLSTMLayer(nn.Module):
     # step-for-step identical
     unroll: int = 1
     # time_major=True: x is (time, batch, f) and the output sequence comes
-    # back (time, batch, h) — the scan consumes/produces that layout
-    # natively, so a stacked time-major net does ZERO per-layer physical
-    # transposes (the round-4 CPU trace showed those copies out-costing
-    # the matmuls, docs/performance.md). Param shapes are identical either
-    # way; batch-major (default) keeps the original contract.
+    # back (time, batch, h). Its rows, x.reshape(-1, f), are then in the
+    # order of the scan's row-flat buffers (step t at rows t*batch on), so
+    # the projection's product reads and writes those buffers as they lie
+    # and a stacked time-major net turns nothing between a loop and a
+    # product, whole or per layer (_lstm_forward; the round-4 CPU trace
+    # showed such copies out-costing the matmuls, docs/performance.md).
+    # Param shapes are identical either way; batch-major (default) keeps
+    # the original contract and pays a swapaxes in and one out.
     time_major: bool = False
 
     @nn.compact

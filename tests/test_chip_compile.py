@@ -197,38 +197,72 @@ def test_fleet_epoch_program_compiles(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
-def test_lstm_cell_epoch_program_fills_no_stacked_buffer(chip):
+# lstm50.fit's bucket and widths (the factory's defaults)
+CELL_MACHINES, CELL_ENC, CELL_DEC = 4, (256, 128, 64), (64, 128, 256)
+
+
+@pytest.fixture(scope="module")
+def lstm_cell_epoch_program(chip):
     """``lstm50.fit``'s epoch program at the cell's own widths (4 machines,
-    quarantine on): the time scans' stacked buffers, 64 steps x 4 machines
-    x 512 rows, are allocated and never filled. Under ``jax.lax.scan`` and
-    autodiff the step body wrote 78 of them whole, 6.5 GB a step, before
-    the scans overwrote them row by row, and XLA's own rewrite of such a
-    fill did not fire (PERF.md section 6, PR 30)."""
-    n_machines = 4
+    quarantine on), compiled once for the tests that read its text."""
     spec = lstm_model(
         n_features=N_TAGS, lookback_window=LOOKBACK,
-        encoding_dim=(256, 128, 64), encoding_func=("tanh",) * 3,
-        decoding_dim=(64, 128, 256), decoding_func=("tanh",) * 3,
+        encoding_dim=CELL_ENC, encoding_func=("tanh",) * len(CELL_ENC),
+        decoding_dim=CELL_DEC, decoding_func=("tanh",) * len(CELL_DEC),
         fused=True,
     )
     trainer = FleetTrainer(spec, lookahead=0)
-    healthy = jax.ShapeDtypeStruct((n_machines,), jnp.bool_, sharding=chip)
-    compiled = trainer._epoch_fn(
+    healthy = jax.ShapeDtypeStruct((CELL_MACHINES,), jnp.bool_, sharding=chip)
+    return trainer._epoch_fn(
         N_TIMESTEPS, BATCH, True, quarantine=True
     ).lower(
-        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=n_machines),
+        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=CELL_MACHINES),
         healthy,
     ).compile()
-    stacked = rf"= \w+\[(?:{LOOKBACK},{n_machines}|{n_machines},{LOOKBACK}),{BATCH},\d+\]\S* "
-    text = compiled.as_text()
+
+
+def test_lstm_cell_epoch_program_fills_no_stacked_buffer(lstm_cell_epoch_program):
+    """The time scans' stacked buffers, 4 machines x (64 steps x 512 rows),
+    are allocated and never filled. Under ``jax.lax.scan`` and autodiff the
+    step body wrote 78 of them whole, 6.5 GB a step, before the scans
+    overwrote them row by row, and XLA's own rewrite of such a fill did
+    not fire (PERF.md section 6, PR 30)."""
+    stacked = rf"= \w+\[{CELL_MACHINES},{LOOKBACK * BATCH},\d+\]\S* "
+    text = lstm_cell_epoch_program.as_text()
     assert not re.findall(stacked + r"broadcast\(", text)
     allocated = [
         line for line in re.findall(stacked + r"custom-call\(.*", text)
         if "AllocateBuffer" in line
     ]
     # a layer: hidden states, gates and cell states forward, d_z backward
-    assert len(allocated) == 4 * len(spec.module.layer_dims)
-    assert compiled.memory_analysis().temp_size_in_bytes < 8.5e9
+    assert len(allocated) == 4 * len(CELL_ENC + CELL_DEC)
+    assert lstm_cell_epoch_program.memory_analysis().temp_size_in_bytes < 8.5e9
+
+
+_ITEM_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "pred": 1, "s8": 1, "u8": 1}
+
+
+def test_lstm_cell_epoch_program_copies_no_stacked_buffer(lstm_cell_epoch_program):
+    """The stacked buffers are row-flat, the hoisted projection's own
+    (machines, time*batch, width), so nothing is turned between a loop and
+    a product: no ``copy`` or ``transpose`` at a loop's boundary. Laid
+    (time, batch, width) they left their loops time-major and 13 copies
+    turned them machine-major, 1.17 of the 1.30 GB such operations wrote a
+    step (PERF.md section 6, PR 33). What is left, 0.116 GB, is the input
+    windows and Adam's leaves."""
+    copies = re.findall(
+        r"= (\w+)\[([\d,]+)\]\S* (?:copy|transpose)\((.*)",
+        lstm_cell_epoch_program.as_text(),
+    )
+    large = [
+        (size, rest)
+        for dtype, dims, rest in copies
+        if (size := _ITEM_BYTES[dtype] * np.prod([int(d) for d in dims.split(",")])) >= 1e6
+    ]
+    assert large, "the pattern must go on finding the program's copies"
+    assert not [rest for _, rest in large if "/scan/" in rest]
+    assert sum(size for size, _ in large) < 0.2e9
+    assert lstm_cell_epoch_program.memory_analysis().temp_size_in_bytes < 5.1e9
 
 
 def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
@@ -307,9 +341,12 @@ def test_flash_fleet_epoch_program_compiles(chip, compiled_kernels):
     assert "tpu_custom_call" in text
 
 
-def test_fleet_scorer_predict_program_compiles(chip):
+@pytest.mark.parametrize("rows", [256, 319], ids=["193_windows", "256_windows"])
+def test_fleet_scorer_predict_program_compiles(chip, rows):
     """run-server's scoring program at the shape the build exports and a
-    fleet POST dispatches: 8 machines x 256 rows."""
+    fleet POST dispatches, 8 machines x 256 rows: 193 windows, the time
+    scans' batch, so step t's rows of a stacked buffer start at ``t*193``,
+    aligned to no (8, 128) tile. And at a batch that is aligned."""
     from gordo_tpu.models import LSTMAutoEncoder
     from gordo_tpu.programs import ProgramCache
     from gordo_tpu.server.fleet_serving import FleetScorer
@@ -328,10 +365,10 @@ def test_fleet_scorer_predict_program_compiles(chip):
     )
     (group,) = scorer._groups
     batch = jax.ShapeDtypeStruct(
-        (N_MACHINES, 256, N_TAGS), jnp.float32, sharding=chip
+        (N_MACHINES, rows, N_TAGS), jnp.float32, sharding=chip
     )
     compiled = group["apply"].lower(
         on_chip(group["params"], chip), batch
     ).compile()
     (out,) = jax.tree.leaves(compiled.out_info)
-    assert out.shape == (N_MACHINES, 256 - LOOKBACK + 1, N_TAGS)
+    assert out.shape == (N_MACHINES, rows - LOOKBACK + 1, N_TAGS)

@@ -252,10 +252,9 @@ class AutodiffScanLSTMLayer(nn.Module):
 N_MACHINES, N_STEPS = 3, 2
 
 
-def fleet_steps(layer, params, xs, probe):
-    """What the fleet's epoch program does with a layer: a ``lax.scan``
-    over steps, each the ``vmap`` over machines of a loss and its gradients
-    (here with respect to every parameter and the input)."""
+def machine_step(layer):
+    """One machine's step: the layer's outputs and the gradients of a probed
+    sum of them with respect to every parameter and the input."""
 
     def machine(p, x, w):
         def loss(p, x):
@@ -265,14 +264,33 @@ def fleet_steps(layer, params, xs, probe):
         (_, hs), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
         return hs, grads
 
-    def step(_, x):
-        return None, jax.vmap(machine)(params, x, probe)
-
-    return jax.lax.scan(step, None, xs)[1]
+    return machine
 
 
-def fleet_inputs(rng, layer, n_time, width, time_major):
-    shape = (n_time, B, F) if time_major else (B, n_time, F)
+def fleet_steps(layer, params, xs, probe):
+    """What the fleet's epoch program does with a layer: a ``lax.scan``
+    over steps, each the ``vmap`` over machines of ``machine_step``."""
+    machines = jax.vmap(machine_step(layer))
+    return jax.lax.scan(lambda _, x: (None, machines(params, x, probe)), None, xs)[1]
+
+
+def unbatched_steps(layer, params, xs, probe):
+    """``fleet_steps`` with no ``vmap`` and no ``lax.scan``: one jitted call
+    a step and a machine, stacked afterwards."""
+    machine = jax.jit(machine_step(layer))
+    outs = [
+        machine(jax.tree.map(lambda leaf: leaf[m], params), x[m], probe[m])
+        for x in xs
+        for m in range(N_MACHINES)
+    ]
+    return jax.tree.map(
+        lambda *leaves: jnp.stack(leaves).reshape(N_STEPS, N_MACHINES, *leaves[0].shape),
+        *outs,
+    )
+
+
+def fleet_inputs(rng, layer, n_time, width, time_major, batch=B):
+    shape = (n_time, batch, F) if time_major else (batch, n_time, F)
     xs = jnp.asarray(rng.normal(size=(N_STEPS, N_MACHINES, *shape)), jnp.float32)
     out = shape[:-1] + (width,)
     probe = jnp.asarray(rng.normal(size=(N_MACHINES, *out)), jnp.float32)
@@ -286,35 +304,64 @@ def fleet_inputs(rng, layer, n_time, width, time_major):
     return params, xs, probe
 
 
-def assert_same_tree(got, want, dtype):
+def assert_same_tree(got, want, dtype, exact=False):
     """float32 to 1e-6 of the reference's largest entry per leaf; bfloat16 to
-    a few of its roundings (the two backward passes sum in another order)."""
-    tol = 1e-6 if dtype == jnp.float32 else 3e-2
+    a few of its roundings (the two backward passes sum in another order);
+    ``exact``: bit for bit."""
+    tol = 0.0 if exact else 1e-6 if dtype == jnp.float32 else 3e-2
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
         assert g.shape == w.shape and g.dtype == w.dtype
         g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
         np.testing.assert_allclose(g, w, rtol=0, atol=tol * max(np.abs(w).max(), 1e-3))
 
 
-@pytest.mark.parametrize("width", [8, 16])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("time_unroll", [1, 4])
-@pytest.mark.parametrize("time_major", [False, True], ids=["batch_major", "time_major"])
-def test_time_scan_matches_autodiff_scan(time_major, time_unroll, dtype, width):
+def scan_case(time_major, time_unroll, dtype, width, batch=B, unbatched=False):
+    name = "float32" if dtype == jnp.float32 else "bfloat16"
+    layout = "time_major" if time_major else "batch_major"
+    return pytest.param(
+        time_major, time_unroll, dtype, width, batch, unbatched,
+        id=f"{layout}-{time_unroll}-{name}-{width}"
+        + (f"-batch{batch}-unbatched_reference" if unbatched else ""),
+    )
+
+
+@pytest.mark.parametrize(
+    "time_major,time_unroll,dtype,width,batch,unbatched",
+    [
+        scan_case(time_major, time_unroll, dtype, width)
+        for time_major in (False, True)
+        for time_unroll in (1, 4)
+        for dtype in (jnp.float32, jnp.bfloat16)
+        for width in (8, 16)
+    ]
+    # the stacked buffers are row-flat: step t's rows start at t*batch, which
+    # at 5 rows a batch is aligned to nothing; and the machines' axis that
+    # ``vmap`` puts in front of the rows must change no number of any machine
+    + [
+        scan_case(time_major, time_unroll, jnp.float32, 8, batch=5, unbatched=True)
+        for time_major in (False, True)
+        for time_unroll in (1, 4)
+    ],
+)
+def test_time_scan_matches_autodiff_scan(
+    time_major, time_unroll, dtype, width, batch, unbatched
+):
     """Outputs and the gradients of all three parameters and of ``x``, as
-    the fleet's epoch program takes them."""
+    the fleet's epoch program takes them, against the autodiff scan under
+    the same ``vmap``; the ``unbatched`` cases against the autodiff scan
+    of one machine at a time, float32 and bit for bit."""
     kwargs = dict(unroll=time_unroll, time_major=time_major, dtype=dtype)
     layer = FusedLSTMLayer(width, **kwargs)
     reference = AutodiffScanLSTMLayer(width, **kwargs)
     params, xs, probe = fleet_inputs(
-        np.random.default_rng(width + time_unroll), layer, T, width, time_major
+        np.random.default_rng(width + time_unroll), layer, T, width, time_major, batch
     )
-    hs, (d_params, d_x) = fleet_steps(layer, params, xs, probe)
-    hs_ref, (d_params_ref, d_x_ref) = fleet_steps(reference, params, xs, probe)
+    got = fleet_steps(layer, params, xs, probe)
+    want = (unbatched_steps if unbatched else fleet_steps)(reference, params, xs, probe)
+    hs, (d_params, _) = got
     assert hs.shape == (N_STEPS, N_MACHINES) + probe.shape[1:]
     assert set(d_params["params"]) == {"input_proj", "recurrent_kernel", "recurrent_bias"}
-    assert_same_tree(hs, hs_ref, dtype)
-    assert_same_tree((d_params, d_x), (d_params_ref, d_x_ref), dtype)
+    assert_same_tree(got, want, dtype, exact=unbatched)
 
 
 @pytest.mark.parametrize("activation", ["tanh", "relu"])
