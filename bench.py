@@ -85,13 +85,13 @@ def bench_jax(n_timesteps: int, epochs: int) -> dict:
         decoding_dim=DEC,
         decoding_func=("tanh",) * len(DEC),
         dtype="bfloat16",
-        # hoisted input projections: one wide (B*T) matmul feeds the scan
-        # instead of a per-step projection; parity pinned by
+        # one hand-written time scan a layer, the gates' kernels side by
+        # side (specs.FusedLSTMLayer); parity pinned by
         # tests/test_fused_lstm.py
         fused=True,
         # schedule-only time-scan unroll for on-chip sweeps
         time_unroll=int(os.environ.get("BENCH_TIME_UNROLL", "1")),
-        # "layer" (hoisted MXU schedule) or "stacked" (one streaming
+        # "layer" (one scan a layer) or "stacked" (one streaming
         # scan); math is identical either way (tests/test_fused_lstm.py)
         schedule=os.environ.get("BENCH_SCHEDULE", "layer"),
     )

@@ -78,8 +78,9 @@ def lstm_model(
 ) -> ModelSpec:
     """
     Stacked LSTM encoder/decoder with a Dense head on the last timestep.
-    ``fused=True`` hoists input projections out of the time scan
-    (specs.FusedLSTMLayer) — same math, TPU-friendlier schedule.
+    ``fused=True`` runs each layer as one hand-written time scan with the
+    four gates' kernels side by side (specs.FusedLSTMLayer): same math,
+    TPU-friendlier schedule.
     ``time_unroll`` unrolls the fused layers' time scan (schedule-only;
     identical math) — XLA then fuses gate math across consecutive steps,
     cutting per-step carry-copy overhead.

@@ -248,29 +248,33 @@ def _read_step(buffer, t, batch):
     return jax.lax.dynamic_slice_in_dim(buffer, t * batch, batch, 0)
 
 
-def _lstm_forward(act, dtype, unroll, keep_residuals, z, w_h, b_h):
+def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
     """
-    The recurrence over time-major ``z`` (time, batch, 4h): a counted loop
-    that writes step t's rows of each stacked buffer and nothing else of
-    it. The buffers start uninitialised (``jax.lax.empty``: on a TPU an
+    The recurrence over time-major ``x`` (time, batch, f): a counted loop
+    that multiplies step t's rows of ``x`` by the input kernel ``w_x``
+    itself, beside the recurrent product of the same rows and columns, and
+    writes step t's rows of each stacked buffer and nothing else of it.
+    The buffers start uninitialised (``jax.lax.empty``: on a TPU an
     ``AllocateBuffer``, no fill), and every row is written before anything
     reads it. Without ``keep_residuals`` the hidden states are all that is
     stacked; with it also what the backward loop reads: the gates before
-    their activations, 4h wide, and the cell states.
+    their activations, 4h wide, and the cell states. The projected input
+    ``x @ w_x`` is NOT stacked: it would be the widest buffer the forward
+    pass moves, written whole and read back a step at a time with nothing
+    else done to it (docs/performance.md, "The forward loop multiplies a
+    step's input itself").
 
-    Every stacked buffer, ``z`` among them, is ROW-FLAT, (time*batch,
+    Every stacked buffer, ``x`` among them, is ROW-FLAT, (time*batch,
     width) with step t at rows ``t*batch`` on, and has no time axis: that
-    is the shape the hoisted projection multiplies (``x.reshape(-1, f)``)
-    and makes. XLA lays a buffer with the axis its loop indexes outermost,
-    so under the fleet's ``vmap`` a (time, batch, width) buffer came out
-    time-major, machines inside, and every ``hs`` and ``d_z`` was copied
-    whole to turn it machine-major for the projection's products (13
-    copies, 1.17 GB written a step in the 50-tag plant: PERF.md section 6,
-    PR 33). A loop that indexes rows leaves the machines in front, and the
-    reshapes at :func:`lstm_time_scan`'s edge compile to nothing.
+    is the shape the products after the backward loop multiply. XLA lays a
+    buffer with the axis its loop indexes outermost, so under the fleet's
+    ``vmap`` a (time, batch, width) buffer comes out time-major, machines
+    inside, and is copied whole to turn it machine-major for a product
+    over all rows. A loop that indexes rows leaves the machines in front,
+    and the reshapes at :func:`lstm_time_scan`'s edge compile to nothing.
     """
-    n_steps, batch, h_dim = z.shape[0], z.shape[1], z.shape[2] // 4
-    z = z.reshape(n_steps * batch, 4 * h_dim)
+    n_steps, batch, h_dim = x.shape[0], x.shape[1], w_h.shape[0]
+    x = x.reshape(n_steps * batch, -1)
     state = jnp.zeros((batch, h_dim), jnp.float32)
     stacked = [jax.lax.empty((n_steps * batch, h_dim), jnp.float32)]
     if keep_residuals:
@@ -281,7 +285,9 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, z, w_h, b_h):
 
     def body(t, carry):
         c, h, stacked = carry
-        gates = lstm_gates(h, _read_step(z, t, batch), w_h, b_h, dtype)
+        # the two products stay apart, each rounding its own operands, and
+        # are summed in float32: [x_t, h] @ [w_x; w_h] would change the sums
+        gates = lstm_gates(h, _read_step(x, t, batch) @ w_x, w_h, b_h, dtype)
         c, h = lstm_cell_update(c, gates, act)
         rows = [h, gates, c] if keep_residuals else [h]
         return c, h, [_write_step(b, r, t) for b, r in zip(stacked, rows)]
@@ -293,11 +299,12 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, z, w_h, b_h):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def lstm_time_scan(act, dtype, unroll, z, w_h, b_h):
+def lstm_time_scan(act, dtype, unroll, x, w_x, w_h, b_h):
     """
     The hidden states (time, batch, h), float32, of one LSTM layer over its
-    pre-projected time-major input ``z`` (time, batch, 4h): step for step
-    :func:`lstm_cell_step` from a zero state.
+    time-major input ``x`` (time, batch, f) and input kernel ``w_x`` (f,
+    4h), both in ``dtype``: step for step :func:`lstm_cell_step` of ``x_t @
+    w_x`` from a zero state.
 
     Why not ``jax.lax.scan`` under autodiff: ``scan`` starts every stacked
     output as a broadcast zero (jax 0.9.0, ``loops.py`` ``_empty_array``),
@@ -308,18 +315,18 @@ def lstm_time_scan(act, dtype, unroll, z, w_h, b_h):
     each one counted loop over buffers that are never filled, and what is
     kept from the forward pass is chosen: the gates, the cell states and
     the output itself. The time axis exists at this function's edge only:
-    inside, every stacked buffer is the projection's (time*batch, width)
-    rows (:func:`_lstm_forward` says why), so nothing is turned between a
-    loop and a product. A new recurrent layer uses this shape of scan, not
+    inside, every stacked buffer is (time*batch, width) rows
+    (:func:`_lstm_forward` says why), so nothing is turned between a loop
+    and a product. A new recurrent layer uses this shape of scan, not
     ``lax.scan`` under autodiff.
     """
-    (hs,) = _lstm_forward(act, dtype, unroll, False, z, w_h, b_h)
-    return hs.reshape(*z.shape[:2], -1)
+    (hs,) = _lstm_forward(act, dtype, unroll, False, x, w_x, w_h, b_h)
+    return hs.reshape(*x.shape[:2], -1)
 
 
-def _lstm_time_scan_fwd(act, dtype, unroll, z, w_h, b_h):
-    hs, gates, cs = _lstm_forward(act, dtype, unroll, True, z, w_h, b_h)
-    return hs.reshape(*z.shape[:2], -1), (hs, gates, cs, w_h)
+def _lstm_time_scan_fwd(act, dtype, unroll, x, w_x, w_h, b_h):
+    hs, gates, cs = _lstm_forward(act, dtype, unroll, True, x, w_x, w_h, b_h)
+    return hs.reshape(*x.shape[:2], -1), (hs, gates, cs, x, w_x, w_h)
 
 
 def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
@@ -331,11 +338,13 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     lowered program's list of products is the autodiff scan's: ``d_gates``
     against ``w_h`` into the previous hidden state, against the previous
     hidden state into ``d_w`` (accumulated in the carry, as the bias's sum
-    is), and ``d_gates`` itself stacked as the cotangent of ``z`` for the
-    hoisted projection's backward. The residuals, ``d_hs`` and ``d_z`` are
-    row-flat, as the forward loop's buffers are.
+    is), and ``d_gates`` itself stacked as ``d_z``, the cotangent of ``x @
+    w_x``. After the loop the two transposes of that product, as autodiff
+    writes them for the ``nn.Dense`` it was: ``x^T d_z`` and ``d_z w_x^T``,
+    each ONE product over all rows. The residuals, ``d_hs`` and ``d_z``
+    are row-flat, as the forward loop's buffers are.
     """
-    hs, gates, cs, w_h = residuals
+    hs, gates, cs, x, w_x, w_h = residuals
     n_steps, batch, h_dim = d_hs.shape
     d_hs = d_hs.reshape(n_steps * batch, h_dim)
     zeros = jnp.zeros((batch, h_dim), jnp.float32)
@@ -378,7 +387,11 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     _, _, d_z, d_w, d_b = jax.lax.fori_loop(
         0, n_steps, body, carry, unroll=unroll
     )
-    return d_z.reshape(n_steps, batch, 4 * h_dim), d_w, d_b
+    d_x = jax.lax.dot_general(d_z, w_x, (((1,), (1,)), ((), ())))
+    d_w_x = jax.lax.dot_general(
+        d_z, x.reshape(n_steps * batch, -1), (((0,), (0,)), ((), ()))
+    ).T
+    return d_x.reshape(x.shape), d_w_x, d_w, d_b
 
 
 lstm_time_scan.defvjp(_lstm_time_scan_fwd, _lstm_time_scan_bwd)
@@ -399,17 +412,45 @@ def gru_cell_step(h, z_t, w_rz, w_n, b_n, act, dtype, h_dim):
     return (1.0 - zg) * n + zg * h
 
 
+class _InputKernel(nn.Module):
+    """
+    The ``kernel`` of a bias-free ``nn.Dense`` without its product: under
+    the name ``input_proj`` the parameter tree, the initializer and the
+    key are those of the ``nn.Dense`` that :class:`FusedLSTMLayer` held
+    while its projection was hoisted, so stored models load as they were.
+    """
+
+    features: int
+
+    @nn.compact
+    def __call__(self, in_features):
+        return self.param(
+            "kernel",
+            nn.initializers.lecun_normal(),
+            (in_features, self.features),
+            jnp.float32,
+        )
+
+
 class FusedLSTMLayer(nn.Module):
     """
-    LSTM layer with the input projection hoisted OUT of the time scan: the
-    x@W_[ifgo] matmul for the whole sequence runs as one (batch*time, f) x
-    (f, 4h) product (MXU-sized), and the scan carries only the recurrent
-    h@W_h matmul. Same math as ``nn.RNN(OptimizedLSTMCell)`` — gate order
-    [i, f, g, o], sigmoid gates, ``activation_fn`` on g and the cell
-    output — with a TPU-friendlier schedule. The time scan is
-    :func:`lstm_time_scan`, forward and backward written out as counted
-    loops over buffers that are allocated and never filled; the layer has
-    no other scan.
+    LSTM layer as one time scan, :func:`lstm_time_scan`: forward and
+    backward written out as counted loops over buffers that are allocated
+    and never filled; the layer has no other scan. Same math as
+    ``nn.RNN(OptimizedLSTMCell)`` (gate order [i, f, g, o], sigmoid gates,
+    ``activation_fn`` on g and the cell output) with the four gates'
+    kernels side by side: ``input_proj/kernel`` (f, 4h), bias-free (the
+    recurrent bias covers it), and ``recurrent_kernel`` (h, 4h).
+
+    The forward loop multiplies a step's rows of ``x`` by the input kernel
+    itself, beside ``h @ w_h``. Hoisted out of the scan as one (time*batch,
+    f) x (f, 4h) product, which is what XLA:CPU's gemm wants, the product
+    is bound on a TPU by the write of its own output, which the loop then
+    reads back a step at a time (docs/performance.md, "The forward loop
+    multiplies a step's input itself", has the chip's numbers). The
+    hoisting remains in :class:`FusedGRULayer` only. The backward pass
+    makes ``x^T d_z`` and ``d_z w_x^T`` as one product each over all rows,
+    after its loop.
     """
 
     features: int
@@ -424,28 +465,19 @@ class FusedLSTMLayer(nn.Module):
     # time_major=True: x is (time, batch, f) and the output sequence comes
     # back (time, batch, h). Its rows, x.reshape(-1, f), are then in the
     # order of the scan's row-flat buffers (step t at rows t*batch on), so
-    # the projection's product reads and writes those buffers as they lie
-    # and a stacked time-major net turns nothing between a loop and a
-    # product, whole or per layer (_lstm_forward; the round-4 CPU trace
-    # showed such copies out-costing the matmuls, docs/performance.md).
-    # Param shapes are identical either way; batch-major (default) keeps
-    # the original contract and pays a swapaxes in and one out.
+    # the loops and the backward's products read and write those buffers
+    # as they lie and a stacked time-major net turns nothing between a
+    # loop and a product, whole or per layer (_lstm_forward; the round-4
+    # CPU trace showed such copies out-costing the matmuls,
+    # docs/performance.md). Param shapes are identical either way;
+    # batch-major (default) keeps the original contract and pays a
+    # swapaxes in and one out.
     time_major: bool = False
 
     @nn.compact
     def __call__(self, x):  # x: (batch, time, f) or time-major (time, batch, f)
         h_dim = self.features
-        # one big matmul over the full sequence (no bias: the recurrent
-        # projection's bias covers it, as in OptimizedLSTMCell). The
-        # explicit 2D reshape matters: a 3D dot_general's backward makes
-        # XLA:CPU materialize 67MB transposes of the sequence to feed its
-        # gemm, while the 2D form's dW = x^T @ dz lowers to a gemm with
-        # transpose flags (no copies) — measured in the round-5 HLO dump.
-        lead = x.shape[:-1]
-        z = nn.Dense(
-            4 * h_dim, use_bias=False, dtype=self.dtype, name="input_proj"
-        )(x.reshape(-1, x.shape[-1]))
-        z = z.reshape(*lead, 4 * h_dim)
+        w_x = _InputKernel(4 * h_dim, name="input_proj")(x.shape[-1]).astype(self.dtype)
         w_h = self.param(
             "recurrent_kernel",
             nn.initializers.orthogonal(),
@@ -455,6 +487,7 @@ class FusedLSTMLayer(nn.Module):
         b_h = self.param(
             "recurrent_bias", nn.initializers.zeros_init(), (4 * h_dim,), jnp.float32
         ).astype(self.dtype)
+        x = x.astype(self.dtype)
         # a stable name for "the time scan of this layer" on a device trace
         # (.../FusedLSTMLayer_k/scan/..., under transpose(jvp(...)) for the
         # backward pass), with the layout swaps that feed and drain it
@@ -463,7 +496,8 @@ class FusedLSTMLayer(nn.Module):
                 self.activation_fn,
                 self.dtype,
                 max(1, int(self.unroll)),
-                z if self.time_major else z.swapaxes(0, 1),
+                x if self.time_major else x.swapaxes(0, 1),
+                w_x,
                 w_h,
                 b_h,
             )
@@ -494,8 +528,11 @@ class FusedGRULayer(nn.Module):
         h_dim = self.features
         # one big matmul over the full sequence; carries the input-side
         # biases for r/z/n (the recurrent r/z projections are bias-free,
-        # as in GRUCell's summed-dense convention). 2D reshape around the
-        # projection for the same gemm-layout reason as FusedLSTMLayer.
+        # as in GRUCell's summed-dense convention). The explicit 2D reshape
+        # matters: a 3D dot_general's backward makes XLA:CPU materialize
+        # 67MB transposes of the sequence to feed its gemm, while the 2D
+        # form's dW = x^T @ dz lowers to a gemm with transpose flags (no
+        # copies), measured in the round-5 HLO dump.
         lead = x.shape[:-1]
         z = nn.Dense(
             3 * h_dim, use_bias=True, dtype=self.dtype, name="input_proj"
@@ -542,9 +579,9 @@ class LSTMNet(nn.Module):
     sequence to the next; the Dense head reads the final layer's last
     timestep — identical math to Keras' return_sequences=False on the last
     recurrent layer. ``fused=True`` swaps each layer for the cell's fused
-    variant (FusedLSTMLayer / FusedGRULayer — input projections hoisted
-    out of the scan; different param tree, so choose it at model
-    definition time).
+    variant (FusedLSTMLayer / FusedGRULayer: the gates' kernels side by
+    side and one scan a layer; different param tree, so choose it at
+    model definition time).
     """
 
     layer_dims: Tuple[int, ...]
@@ -554,8 +591,9 @@ class LSTMNet(nn.Module):
     fused: bool = False
     cell: str = "lstm"  # "lstm" | "gru"
     time_unroll: int = 1  # fused layers' scan unroll (schedule-only knob)
-    # "layer": one time scan per layer, input projections hoisted to big
-    #   (batch*time) matmuls — the MXU-friendly schedule (TPU default).
+    # "layer": one time scan per layer (TPU default). The LSTM's loop
+    #   multiplies a step's input itself (FusedLSTMLayer says why); the
+    #   GRU's input projection is hoisted to one (batch*time) matmul.
     # "stacked": ALL layers stream through ONE time scan (layer l's step
     #   consumes layer l-1's hidden state of the same timestep), so the
     #   inter-layer (time, batch, 4h) z/hs sequence buffers never
