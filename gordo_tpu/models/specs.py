@@ -265,13 +265,13 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
     step's input itself").
 
     Every stacked buffer, ``x`` among them, is ROW-FLAT, (time*batch,
-    width) with step t at rows ``t*batch`` on, and has no time axis: that
-    is the shape the products after the backward loop multiply. XLA lays a
-    buffer with the axis its loop indexes outermost, so under the fleet's
-    ``vmap`` a (time, batch, width) buffer comes out time-major, machines
-    inside, and is copied whole to turn it machine-major for a product
-    over all rows. A loop that indexes rows leaves the machines in front,
-    and the reshapes at :func:`lstm_time_scan`'s edge compile to nothing.
+    width) with step t at rows ``t*batch`` on, and has no time axis. XLA
+    lays a buffer with the axis its loop indexes outermost, so under the
+    fleet's ``vmap`` a (time, batch, width) buffer comes out time-major,
+    machines inside, and is copied whole to turn it machine-major for
+    whatever takes all its rows at once (PERF.md section 6, PR 33). A
+    loop that indexes rows leaves the machines in front, and the reshapes
+    at :func:`lstm_time_scan`'s edge compile to nothing.
     """
     n_steps, batch, h_dim = x.shape[0], x.shape[1], w_h.shape[0]
     x = x.reshape(n_steps * batch, -1)
@@ -316,9 +316,9 @@ def lstm_time_scan(act, dtype, unroll, x, w_x, w_h, b_h):
     kept from the forward pass is chosen: the gates, the cell states and
     the output itself. The time axis exists at this function's edge only:
     inside, every stacked buffer is (time*batch, width) rows
-    (:func:`_lstm_forward` says why), so nothing is turned between a loop
-    and a product. A new recurrent layer uses this shape of scan, not
-    ``lax.scan`` under autodiff.
+    (:func:`_lstm_forward` says why), so nothing is turned between one
+    layer's loop and the next's. A new recurrent layer uses this shape of
+    scan, not ``lax.scan`` under autodiff.
     """
     (hs,) = _lstm_forward(act, dtype, unroll, False, x, w_x, w_h, b_h)
     return hs.reshape(*x.shape[:2], -1)
@@ -333,20 +333,25 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     """
     The transposed recurrence, last step first. A step's elementwise half
     is transposed by autodiff from the kept gates and the previous cell
-    state (its activations are computed again, no product is); the two
-    transposes of ``h @ w_h`` are written as autodiff writes them, so the
-    lowered program's list of products is the autodiff scan's: ``d_gates``
-    against ``w_h`` into the previous hidden state, against the previous
-    hidden state into ``d_w`` (accumulated in the carry, as the bias's sum
-    is), and ``d_gates`` itself stacked as ``d_z``, the cotangent of ``x @
-    w_x``. After the loop the two transposes of that product, as autodiff
-    writes them for the ``nn.Dense`` it was: ``x^T d_z`` and ``d_z w_x^T``,
-    each ONE product over all rows. The residuals, ``d_hs`` and ``d_z``
-    are row-flat, as the forward loop's buffers are.
+    state (its activations are computed again, no product is). Its
+    ``d_gates`` (batch, 4h) is the cotangent of both gate products, and the
+    loop makes all four transposes of them while it is on the chip, each
+    with the dimension numbers autodiff writes: against ``w_h`` into the
+    previous hidden state, against the previous hidden state into ``d_w``,
+    against ``w_x`` into step t's rows of the stacked ``d_x``, against step
+    t's rows of ``x`` into ``d_w_x``; the weight gradients and the bias's
+    sum are accumulated in the carry. ``d_gates`` is NOT stacked: as
+    ``d_z``, the cotangent of ``x @ w_x``, it was 4h wide where ``d_x`` is
+    f wide, written a step at a time and read whole twice by two products
+    after the loop that waited for its bytes (docs/performance.md, "The
+    backward loop multiplies a step's gate cotangent itself"). The
+    residuals, ``d_hs`` and ``d_x`` are row-flat, as the forward loop's
+    buffers are.
     """
     hs, gates, cs, x, w_x, w_h = residuals
     n_steps, batch, h_dim = d_hs.shape
     d_hs = d_hs.reshape(n_steps * batch, h_dim)
+    x = x.reshape(n_steps * batch, -1)
     zeros = jnp.zeros((batch, h_dim), jnp.float32)
 
     def previous(buffer, t):
@@ -355,7 +360,7 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
         return jnp.where(t > 0, rows, 0.0)
 
     def body(k, carry):
-        d_c, d_h, d_z, d_w, d_b = carry
+        d_c, d_h, d_x, d_w_x, d_w, d_b = carry
         t = n_steps - 1 - k
         # the step's gates are read ONCE, as one slab, before the update's
         # transpose takes its quarters of them: fused into that transpose,
@@ -368,30 +373,31 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
         )
         d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t, batch)))
         d_gates = d_gates.astype(dtype)
+        d_w_x = d_w_x + jax.lax.dot_general(
+            d_gates, _read_step(x, t, batch), (((0,), (0,)), ((), ()))
+        ).T
         d_w = d_w + jax.lax.dot_general(
             d_gates, previous(hs, t).astype(dtype), (((0,), (0,)), ((), ()))
         ).T
         d_b = d_b + jax.lax.reduce_sum(d_gates, axes=(0,))
+        d_x_t = jax.lax.dot_general(d_gates, w_x, (((1,), (1,)), ((), ())))
         d_h = jax.lax.dot_general(
             d_gates, w_h, (((1,), (1,)), ((), ()))
         ).astype(jnp.float32)
-        return d_c, d_h, _write_step(d_z, d_gates, t), d_w, d_b
+        return d_c, d_h, _write_step(d_x, d_x_t, t), d_w_x, d_w, d_b
 
     carry = (
         zeros,
         zeros,
-        jax.lax.empty((n_steps * batch, 4 * h_dim), dtype),
+        jax.lax.empty(x.shape, dtype),
+        jnp.zeros_like(w_x),
         jnp.zeros_like(w_h),
         jnp.zeros((4 * h_dim,), dtype),
     )
-    _, _, d_z, d_w, d_b = jax.lax.fori_loop(
+    _, _, d_x, d_w_x, d_w, d_b = jax.lax.fori_loop(
         0, n_steps, body, carry, unroll=unroll
     )
-    d_x = jax.lax.dot_general(d_z, w_x, (((1,), (1,)), ((), ())))
-    d_w_x = jax.lax.dot_general(
-        d_z, x.reshape(n_steps * batch, -1), (((0,), (0,)), ((), ()))
-    ).T
-    return d_x.reshape(x.shape), d_w_x, d_w, d_b
+    return d_x.reshape(n_steps, batch, -1), d_w_x, d_w, d_b
 
 
 lstm_time_scan.defvjp(_lstm_time_scan_fwd, _lstm_time_scan_bwd)
@@ -448,9 +454,12 @@ class FusedLSTMLayer(nn.Module):
     is bound on a TPU by the write of its own output, which the loop then
     reads back a step at a time (docs/performance.md, "The forward loop
     multiplies a step's input itself", has the chip's numbers). The
-    hoisting remains in :class:`FusedGRULayer` only. The backward pass
-    makes ``x^T d_z`` and ``d_z w_x^T`` as one product each over all rows,
-    after its loop.
+    hoisting remains in :class:`FusedGRULayer` only. The backward loop
+    likewise multiplies a step's gate cotangent by the input kernel and by
+    the step's rows of ``x`` itself, beside the recurrent kernel's two
+    transposes: what it stacks is ``d_x``, f wide, not the 4h-wide
+    cotangent of ``x @ w_x`` for two products over all rows to read back
+    ("The backward loop multiplies a step's gate cotangent itself").
     """
 
     features: int
@@ -465,9 +474,9 @@ class FusedLSTMLayer(nn.Module):
     # time_major=True: x is (time, batch, f) and the output sequence comes
     # back (time, batch, h). Its rows, x.reshape(-1, f), are then in the
     # order of the scan's row-flat buffers (step t at rows t*batch on), so
-    # the loops and the backward's products read and write those buffers
-    # as they lie and a stacked time-major net turns nothing between a
-    # loop and a product, whole or per layer (_lstm_forward; the round-4
+    # the loops read and write those buffers as they lie and a stacked
+    # time-major net turns nothing between one layer's loop and the
+    # next's, whole or per layer (_lstm_forward; the round-4
     # CPU trace showed such copies out-costing the matmuls,
     # docs/performance.md). Param shapes are identical either way;
     # batch-major (default) keeps the original contract and pays a

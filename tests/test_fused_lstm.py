@@ -358,10 +358,11 @@ def scan_case(
         for time_major in (False, True)
         for time_unroll in (1, 4)
     ]
-    # the input kernel's and ``x``'s cotangents are one product each over all
-    # rows, after the backward loop: a first layer's input is 50 wide, a
-    # later one's a multiple of 8; and at 8 rows a step XLA:CPU's gemm gives
-    # a row block other last bits than the whole product's rows
+    # the input kernel's and ``x``'s cotangents are a product each of a step's
+    # rows, inside the backward loop (the reference makes each as ONE product
+    # over all rows): a first layer's input is 50 wide, a later one's a
+    # multiple of 8; and at 8 rows a step XLA:CPU's gemm gives a row block
+    # other last bits than the whole product's rows
     + [
         scan_case(time_major, 1, dtype, 8, batch=batch, n_features=n_features)
         for dtype in (jnp.float32, jnp.bfloat16)
@@ -420,8 +421,10 @@ def test_forward_only_call_stacks_one_buffer_a_layer():
     """Scoring, validation and streaming take no gradient: the scan then
     stacks the hidden states and nothing else: no ``z``, the step's input
     product is the loop's own. Under a gradient it stacks the gates (before
-    their activations) and the cell states beside them, and ``d_z`` on the
-    way back, which ``x^T d_z`` and ``d_z w_x^T`` read after the loop."""
+    their activations) and the cell states beside them, and on the way back
+    ``d_x``, f wide, if the input takes a cotangent: a first layer's does
+    not (``x`` is data), and JAX drops that write and its product from the
+    backward loop before XLA sees them."""
     x = jnp.zeros((T, B, F))
     layer = FusedLSTMLayer(H, time_major=True)
     params = layer.init(jax.random.PRNGKey(0), x)
@@ -430,10 +433,85 @@ def test_forward_only_call_stacks_one_buffer_a_layer():
     # nothing (time*batch, 4h) exists without a gradient; with the projection
     # hoisted, ``z`` was one
     assert f"tensor<{T * B}x{4 * H}xf32>" not in forward
-    with_gradient = lowered_text(
-        jax.grad(lambda p, x: jnp.sum(layer.apply(p, x))), params, x
+
+    def loss(p, x):
+        return jnp.sum(layer.apply(p, x))
+
+    first_layer = lowered_text(jax.grad(loss), params, x)
+    assert first_layer.count("dynamic_update_slice") == 3
+    assert first_layer.count("dot_general") == 5
+    later_layer = lowered_text(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert later_layer.count("dynamic_update_slice") == 4
+    assert later_layer.count("dot_general") == 6
+
+
+def sub_equations(eqns):
+    """Every equation of ``eqns`` and of the jaxprs they hold."""
+    for eqn in eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from sub_equations(sub.eqns)
+
+
+@pytest.mark.parametrize(
+    "width,n_features,batch", [(8, 5, 3), (8, 32, 3), (16, 50, 8)],
+    ids=["4h_32-f_5", "4h_32-f_32", "4h_64-f_50"],
+)
+def test_backward_loop_stacks_no_gate_cotangent(width, n_features, batch):
+    """The backward loop multiplies a step's ``d_gates`` by the input kernel
+    and by the step's rows of ``x`` itself: the backward pass allocates and
+    writes ONE stacked buffer, ``d_x``, f wide, its four products are the
+    loop's and take a step's rows, and no product follows the loop. ``d_z``
+    was a second buffer, (time*batch, 4h), which two products over all rows
+    read after the loop. The second case has f = 4h: the widths alone do not
+    tell ``d_x`` from ``d_z``, the count of buffers does."""
+    x = jnp.zeros((T, batch, n_features))
+    layer = FusedLSTMLayer(width, time_major=True)
+    params = layer.init(jax.random.PRNGKey(0), x)
+    eqns = jax.make_jaxpr(
+        jax.grad(lambda p, x: jnp.sum(layer.apply(p, x)), argnums=(0, 1))
+    )(params, x).jaxpr.eqns
+    forward_loop, backward_loop = (
+        i for i, e in enumerate(eqns) if e.primitive.name == "scan"
     )
-    assert with_gradient.count("dynamic_update_slice") == 4
+    rows = T * batch
+    allocated = [
+        e.outvars[0].aval.shape
+        for e in eqns[forward_loop + 1 :] if e.primitive.name == "empty"
+    ]
+    assert allocated == [(rows, n_features)]
+    body = list(sub_equations(eqns[backward_loop].params["jaxpr"].eqns))
+    written = [
+        e.outvars[0].aval.shape for e in body
+        if e.primitive.name == "dynamic_update_slice"
+    ]
+    assert written == [(rows, n_features)]
+    products = [e for e in body if e.primitive.name == "dot_general"]
+    assert len(products) == 4
+    assert all(rows not in v.aval.shape for e in products for v in e.invars)
+    assert not [
+        e for e in sub_equations(eqns[backward_loop + 1 :])
+        if e.primitive.name == "dot_general"
+    ]
+
+
+def test_input_cotangent_changes_no_parameter_gradient():
+    """A first layer's input is data and takes no cotangent, a later
+    layer's does: JAX drops ``d_x``'s product and write from the first
+    one's backward loop, and what is left gives the same bits."""
+    layer = FusedLSTMLayer(H, time_major=True)
+    params, xs, probe = fleet_inputs(np.random.default_rng(2), layer, T, H, True)
+
+    def loss(p, x):
+        return jnp.sum(layer.apply(p, x) * probe[0])
+
+    p = jax.tree.map(lambda leaf: leaf[0], params)
+    first_layer = jax.jit(jax.grad(loss))
+    later_layer = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    without = first_layer(p, xs[0, 0])
+    with_d_x, d_x = later_layer(p, xs[0, 0])
+    assert d_x.shape == xs[0, 0].shape and np.abs(d_x).max() > 0
+    assert_same_tree(without, with_d_x, jnp.float32, exact=True)
 
 
 def test_estimator_with_a_lookback_of_one_trains_and_pickles():
