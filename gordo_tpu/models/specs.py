@@ -287,10 +287,18 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
         c, h, stacked = carry
         # the two products stay apart, each rounding its own operands, and
         # are summed in float32: [x_t, h] @ [w_x; w_h] would change the sums
-        gates = lstm_gates(h, _read_step(x, t, batch) @ w_x, w_h, b_h, dtype)
-        c, h = lstm_cell_update(c, gates, act)
+        with jax.named_scope("lstm.fwd.gates"):
+            gates = lstm_gates(h, _read_step(x, t, batch) @ w_x, w_h, b_h, dtype)
+        with jax.named_scope("lstm.fwd.cell"):
+            c, h = lstm_cell_update(c, gates, act)
         rows = [h, gates, c] if keep_residuals else [h]
-        return c, h, [_write_step(b, r, t) for b, r in zip(stacked, rows)]
+        # a stacked write is named for the stage that made its rows
+        scopes = ["lstm.fwd.cell", "lstm.fwd.gates", "lstm.fwd.cell"]
+        written = []
+        for buffer, row, scope in zip(stacked, rows, scopes):
+            with jax.named_scope(scope):
+                written.append(_write_step(buffer, row, t))
+        return c, h, written
 
     _, _, stacked = jax.lax.fori_loop(
         0, n_steps, body, (state, state, stacked), unroll=unroll
@@ -361,30 +369,35 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
 
     def body(k, carry):
         d_c, d_h, d_x, d_w_x, d_w, d_b = carry
-        t = n_steps - 1 - k
-        # the step's gates are read ONCE, as one slab, before the update's
-        # transpose takes its quarters of them: fused into that transpose,
-        # as XLA fuses a slice of rows, each of its two fusions reads the
-        # four quarters out of the 4h-wide buffer again (lstm50.fit on a
-        # v5e: 310 ms an epoch for 240; PERF.md section 6, PR 33)
-        step_gates = jax.lax.optimization_barrier(_read_step(gates, t, batch))
-        _, update_vjp = jax.vjp(
-            lambda c, g: lstm_cell_update(c, g, act), previous(cs, t), step_gates
-        )
-        d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t, batch)))
-        d_gates = d_gates.astype(dtype)
-        d_w_x = d_w_x + jax.lax.dot_general(
-            d_gates, _read_step(x, t, batch), (((0,), (0,)), ((), ()))
-        ).T
-        d_w = d_w + jax.lax.dot_general(
-            d_gates, previous(hs, t).astype(dtype), (((0,), (0,)), ((), ()))
-        ).T
-        d_b = d_b + jax.lax.reduce_sum(d_gates, axes=(0,))
-        d_x_t = jax.lax.dot_general(d_gates, w_x, (((1,), (1,)), ((), ())))
-        d_h = jax.lax.dot_general(
-            d_gates, w_h, (((1,), (1,)), ((), ()))
-        ).astype(jnp.float32)
-        return d_c, d_h, _write_step(d_x, d_x_t, t), d_w_x, d_w, d_b
+        with jax.named_scope("lstm.bwd.read"):
+            t = n_steps - 1 - k
+            # the step's gates are read ONCE, as one slab, before the
+            # update's transpose takes its quarters of them: fused into that
+            # transpose, as XLA fuses a slice of rows, each of its two
+            # fusions reads the four quarters out of the 4h-wide buffer
+            # again (lstm50.fit on a v5e: 310 ms an epoch for 240; PERF.md
+            # section 6, PR 33)
+            step_gates = jax.lax.optimization_barrier(_read_step(gates, t, batch))
+        with jax.named_scope("lstm.bwd.cell"):
+            _, update_vjp = jax.vjp(
+                lambda c, g: lstm_cell_update(c, g, act), previous(cs, t), step_gates
+            )
+            d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t, batch)))
+            d_gates = d_gates.astype(dtype)
+        with jax.named_scope("lstm.bwd.products"):
+            d_w_x = d_w_x + jax.lax.dot_general(
+                d_gates, _read_step(x, t, batch), (((0,), (0,)), ((), ()))
+            ).T
+            d_w = d_w + jax.lax.dot_general(
+                d_gates, previous(hs, t).astype(dtype), (((0,), (0,)), ((), ()))
+            ).T
+            d_b = d_b + jax.lax.reduce_sum(d_gates, axes=(0,))
+            d_x_t = jax.lax.dot_general(d_gates, w_x, (((1,), (1,)), ((), ())))
+            d_h = jax.lax.dot_general(
+                d_gates, w_h, (((1,), (1,)), ((), ()))
+            ).astype(jnp.float32)
+            d_x = _write_step(d_x, d_x_t, t)
+        return d_c, d_h, d_x, d_w_x, d_w, d_b
 
     carry = (
         zeros,

@@ -5,8 +5,8 @@ Server-Timing has three coarse phases (``queue``/``model_load``/
 ``predict``); the request hot path actually crosses seven seams — and
 the float64 pandas/sklearn transform seam the dtype walk documented
 (docs/serving.md "Streaming scoring") was invisible in every metric.
-This module brackets the serving, streaming, and training hot paths
-into ONE closed phase vocabulary:
+This module brackets the serving, streaming and routing hot paths into
+ONE closed phase vocabulary:
 
 ==============  ============================================================
 phase           what it covers
@@ -22,10 +22,10 @@ phase           what it covers
 ``serialize``   response frame -> JSON bytes
 ==============  ============================================================
 
-Each request/update/dispatch carries a :class:`PhaseLedger`; phases are
-recorded into ``gordo_phase_seconds{plane,phase}`` histograms, stamped
-as attributes on the enclosing span (``server.request`` /
-``stream.update`` / ``train.dispatch``), and windowed by the rollup into
+Each request/update carries a :class:`PhaseLedger`; phases are recorded
+into ``gordo_phase_seconds{plane,phase}`` histograms, stamped as
+attributes on the enclosing span (``server.request`` /
+``stream.update``), and windowed by the rollup into
 the ``host_fraction``/``device_fraction`` control signals — roadmap
 direction #2's target metric (drive ``host_fraction`` toward zero).
 
@@ -68,7 +68,7 @@ HOST_PHASES = frozenset(
 DEVICE_PHASES = frozenset({"transfer", "device"})
 
 #: the planes a ledger can account for (the ``plane`` label's vocabulary)
-PLANES: typing.Tuple[str, ...] = ("server", "stream", "train", "router")
+PLANES: typing.Tuple[str, ...] = ("server", "stream", "router")
 
 #: per-thread stack of active ledgers: cross-layer code (the fleet
 #: scorer, the estimator hot path) attributes via
@@ -304,15 +304,6 @@ def record_current(phase: str, seconds: float) -> bool:
         return False
     stack[-1].add(phase, seconds)
     return True
-
-
-def record(plane: str, phase: str, seconds: float) -> None:
-    """Directly observe one phase duration (the trainer path: long-lived
-    fits have no per-request ledger; each dispatch accounts itself).
-    One env lookup when disabled."""
-    if not ledger_enabled():
-        return
-    _phase_histogram().observe(seconds, plane=plane, phase=phase)
 
 
 # -- registry-snapshot readers (benches, `profile report`, summarize) ------

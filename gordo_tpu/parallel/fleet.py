@@ -43,7 +43,6 @@ from gordo_tpu.models.specs import (
     per_sample_loss,
 )
 from gordo_tpu.observability import (
-    attribution,
     emit_event,
     get_registry,
     tracing,
@@ -1249,13 +1248,9 @@ class FleetTrainer:
                     )
                 if masked:
                     extras.append(fmask)
-                t_disp = time.perf_counter()
                 result = epoch_fn(
                     params, opt_state, epoch_keys, X_arg, y_arg, w_arg,
                     *extras
-                )
-                attribution.record(
-                    "train", "device", time.perf_counter() - t_disp
                 )
             if quarantine:
                 params, opt_state, epoch_loss, healthy_dev = result

@@ -44,7 +44,7 @@ def test_phase_vocabulary_is_closed_and_partitioned():
     documented four."""
     assert set(PHASES) == HOST_PHASES | DEVICE_PHASES
     assert not (HOST_PHASES & DEVICE_PHASES)
-    assert PLANES == ("server", "stream", "train", "router")
+    assert PLANES == ("server", "stream", "router")
 
 
 def test_phases_documented():
@@ -102,10 +102,6 @@ def test_disabled_path_call_counts_pinned(monkeypatch):
     assert ledger.phases == {}
     # the reusable no-op context manager: no per-bracket allocation
     assert ledger.phase("parse") is ledger.phase("serialize")
-    # record() is one env lookup, no histogram touch
-    snapshot_before = phase_totals()
-    attribution.record("train", "device", 5.0)
-    assert phase_totals() == snapshot_before
 
 
 def test_sampler_hook_is_one_global_read_when_inactive(monkeypatch):
@@ -230,7 +226,7 @@ def test_split_host_device_and_block_shape():
     totals = {
         ("server", "parse"): {"count": 2, "sum": 1.0},
         ("server", "device"): {"count": 2, "sum": 3.0},
-        ("train", "transfer"): {"count": 1, "sum": 1.0},
+        ("stream", "transfer"): {"count": 1, "sum": 1.0},
     }
     split = split_host_device(totals)
     assert split["host_s"] == 1.0

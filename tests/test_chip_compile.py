@@ -328,7 +328,7 @@ def test_lstm_cell_epoch_program_stacks_no_gate_cotangent(lstm_cell_epoch_progra
     assert {int(layer) for _, layer, _ in written} == set(range(1, len(widths)))
     for width, layer, path in written:
         assert int(width) == inputs[int(layer)] != 4 * widths[int(layer)], path
-    assert {"empty", "while/body/closed_call/dot_general"} <= {
+    assert {"empty", "while/body/closed_call/lstm.bwd.products/dot_general"} <= {
         path for _, _, path in written
     }
     assert not re.findall(r"op_name=\"[^\"]*/scan/dot_general\"", text)

@@ -294,6 +294,99 @@ def test_lstm_scan_is_a_path_forward_and_backward(epoch_paths):
     assert not any("/scan/" in p for p in epoch_paths["feedforward"])
 
 
+#: the parts of a time step ``lstm_time_scan``'s loops name, each read by
+#: one metric of ``BENCHMARK.json`` (``chipbench/step_scopes.py``)
+STEP_SCOPES = {
+    "lstm.fwd.gates": "lstm_fwd_gates_ms.fit",
+    "lstm.fwd.cell": "lstm_fwd_cell_ms.fit",
+    "lstm.bwd.read": "lstm_bwd_read_ms.fit",
+    "lstm.bwd.cell": "lstm_bwd_cell_ms.fit",
+    "lstm.bwd.products": "lstm_bwd_products_ms.fit",
+}
+
+
+def test_every_op_of_a_time_step_carries_one_step_scope():
+    """In the COMPILED program, where the loop body's ops carry their full
+    path (in the lowered text they sit in a function of their own): each
+    op of a time step holds exactly one step scope, forward ones outside
+    ``transpose(`` and backward ones under it, in both layers; and no step
+    scope can be taken for a fragment ``chipbench/scopes.json`` selects."""
+    text = lowered_epoch(recurrent_spec(), shuffle=False).compile().as_text()
+    step = [
+        p for p in set(re.findall(r'op_name="([^"]+)"', text))
+        if "/scan/while/body/closed_call/" in p
+    ]
+    assert step
+    for path in step:
+        held = [s for s in STEP_SCOPES if f"{s}/" in path]
+        assert len(held) == 1, path
+        assert held[0].startswith("lstm.bwd.") == ("transpose(" in path), path
+    for layer in ("FusedLSTMLayer_0", "FusedLSTMLayer_1"):
+        for scope in STEP_SCOPES:
+            assert any(f"{layer}/scan/" in p and f"{scope}/" in p for p in step), (
+                layer, scope,
+            )
+    for scope in STEP_SCOPES:
+        assert not any(part in scope for part in ("fleet.", "/scan/", "transpose("))
+
+
+_FWD = (
+    "jit(machine_epoch)/vmap(fleet.step)/while/body/closed_call/fleet.loss_grad/"
+    "jvp(LSTMNet)/FusedLSTMLayer_{}/scan/"
+)
+_BWD = _FWD.replace("jvp(LSTMNet)", "transpose(fleet.loss_grad)/jvp(LSTMNet)")
+_STEP = "while/body/closed_call/"
+#: self seconds by path of a traced stretch of 2 calls x 3 epochs
+TRACED_PATHS = {
+    _FWD.format(0) + _STEP + "lstm.fwd.gates/dot_general": 0.030,
+    _FWD.format(1) + _STEP + "lstm.fwd.gates/dot_general": 0.012,
+    _FWD.format(1) + _STEP + "lstm.fwd.cell/dynamic_update_slice": 0.009,
+    _BWD.format(0) + _STEP + "lstm.bwd.read/gather": 0.006,
+    _BWD.format(0) + _STEP + "lstm.bwd.cell/transpose(jvp())/mul": 0.0105,
+    _BWD.format(1) + _STEP + "lstm.bwd.cell/transpose(jvp())/mul": 0.0045,
+    _BWD.format(1) + _STEP + "lstm.bwd.products/dot_general": 0.024,
+    _FWD.format(0) + "while": 0.003,
+    "jit(machine_epoch)/vmap(fleet.step)/while/body/closed_call/fleet.gather/gather": 0.5,
+}
+#: the parent's program: the same loops with no step scope
+UNNAMED_PATHS = {
+    _FWD.format(0) + _STEP + "dot_general": 0.042,
+    _BWD.format(0) + _STEP + "gather": 0.006,
+}
+
+
+@pytest.mark.parametrize(
+    "metric,by_path,expected,layers",
+    [
+        ("lstm_fwd_gates_ms.fit", TRACED_PATHS, 7.0, (0, 1)),
+        ("lstm_fwd_cell_ms.fit", TRACED_PATHS, 1.5, (1,)),
+        ("lstm_bwd_read_ms.fit", TRACED_PATHS, 1.0, (0,)),
+        ("lstm_bwd_cell_ms.fit", TRACED_PATHS, 2.5, (0, 1)),
+        ("lstm_bwd_products_ms.fit", TRACED_PATHS, 4.0, (1,)),
+        ("lstm_bwd_read_ms.fit", UNNAMED_PATHS, None, ()),
+    ],
+    ids=[*STEP_SCOPES, "absent"],
+)
+def test_step_scope_metric_reads_ms_an_epoch(capsys, metric, by_path, expected, layers):
+    """A reader sums the traced paths that hold its scope over the traced
+    epochs and tells each layer's share on stderr; where the program names
+    no such scope (the parent's) it reads nothing and raises nothing."""
+    from chipbench import loading
+
+    ctx = {
+        "scope_reduce": {"by_path": by_path},
+        "traced": {"calls": [{}, {}], "epochs_per_call": 3},
+    }
+    value = loading.metric_reader("layer_metrics", metric)(ctx)
+    err = capsys.readouterr().err
+    if expected is None:
+        assert value is None and not err
+        return
+    assert value == pytest.approx(expected)
+    told = re.findall(r"FusedLSTMLayer_(\d+)", err)
+    assert tuple(sorted(int(k) for k in told)) == layers
+
+
 @pytest.mark.parametrize(
     "factory,kwargs,module",
     [(gru_model, {}, "FusedGRULayer_0"), (lstm_model, {"schedule": "stacked"}, "LSTMNet._stacked_scan")],
