@@ -256,13 +256,15 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
     writes step t's rows of each stacked buffer and nothing else of it.
     The buffers start uninitialised (``jax.lax.empty``: on a TPU an
     ``AllocateBuffer``, no fill), and every row is written before anything
-    reads it. Without ``keep_residuals`` the hidden states are all that is
-    stacked; with it also what the backward loop reads: the gates before
-    their activations, 4h wide, and the cell states. The projected input
-    ``x @ w_x`` is NOT stacked: it would be the widest buffer the forward
-    pass moves, written whole and read back a step at a time with nothing
-    else done to it (docs/performance.md, "The forward loop multiplies a
-    step's input itself").
+    reads it. Returns the stacked hidden states and, with
+    ``keep_residuals``, the cell states, which the backward loop reads
+    besides. The gates are NOT stacked: the backward loop makes them again
+    from the stacked ``x`` and hidden states, which it reads anyway
+    (docs/performance.md, "The backward loop makes a step's gates again").
+    Nor is the projected input ``x @ w_x``: it would be the widest buffer
+    the forward pass moves, written whole and read back a step at a time
+    with nothing else done to it ("The forward loop multiplies a step's
+    input itself").
 
     Every stacked buffer, ``x`` among them, is ROW-FLAT, (time*batch,
     width) with step t at rows ``t*batch`` on, and has no time axis. XLA
@@ -276,12 +278,10 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
     n_steps, batch, h_dim = x.shape[0], x.shape[1], w_h.shape[0]
     x = x.reshape(n_steps * batch, -1)
     state = jnp.zeros((batch, h_dim), jnp.float32)
-    stacked = [jax.lax.empty((n_steps * batch, h_dim), jnp.float32)]
-    if keep_residuals:
-        stacked += [
-            jax.lax.empty((n_steps * batch, 4 * h_dim), jnp.float32),
-            jax.lax.empty((n_steps * batch, h_dim), jnp.float32),
-        ]
+    stacked = [
+        jax.lax.empty((n_steps * batch, h_dim), jnp.float32)
+        for _ in range(2 if keep_residuals else 1)
+    ]
 
     def body(t, carry):
         c, h, stacked = carry
@@ -291,13 +291,8 @@ def _lstm_forward(act, dtype, unroll, keep_residuals, x, w_x, w_h, b_h):
             gates = lstm_gates(h, _read_step(x, t, batch) @ w_x, w_h, b_h, dtype)
         with jax.named_scope("lstm.fwd.cell"):
             c, h = lstm_cell_update(c, gates, act)
-        rows = [h, gates, c] if keep_residuals else [h]
-        # a stacked write is named for the stage that made its rows
-        scopes = ["lstm.fwd.cell", "lstm.fwd.gates", "lstm.fwd.cell"]
-        written = []
-        for buffer, row, scope in zip(stacked, rows, scopes):
-            with jax.named_scope(scope):
-                written.append(_write_step(buffer, row, t))
+        with jax.named_scope("lstm.fwd.cell"):
+            written = [_write_step(b, row, t) for b, row in zip(stacked, [h, c])]
         return c, h, written
 
     _, _, stacked = jax.lax.fori_loop(
@@ -321,27 +316,32 @@ def lstm_time_scan(act, dtype, unroll, x, w_x, w_h, b_h):
     step before the scans overwrote them row by row: 6.5 GB a step in the
     50-tag plant (PERF.md section 6, PR 30). Here forward and backward are
     each one counted loop over buffers that are never filled, and what is
-    kept from the forward pass is chosen: the gates, the cell states and
-    the output itself. The time axis exists at this function's edge only:
-    inside, every stacked buffer is (time*batch, width) rows
-    (:func:`_lstm_forward` says why), so nothing is turned between one
-    layer's loop and the next's. A new recurrent layer uses this shape of
-    scan, not ``lax.scan`` under autodiff.
+    kept from the forward pass is chosen: the cell states and the output
+    itself. The time axis exists at this function's edge only: inside,
+    every stacked buffer is (time*batch, width) rows (:func:`_lstm_forward`
+    says why), so nothing is turned between one layer's loop and the
+    next's. A new recurrent layer uses this shape of scan, not
+    ``lax.scan`` under autodiff.
     """
     (hs,) = _lstm_forward(act, dtype, unroll, False, x, w_x, w_h, b_h)
     return hs.reshape(*x.shape[:2], -1)
 
 
 def _lstm_time_scan_fwd(act, dtype, unroll, x, w_x, w_h, b_h):
-    hs, gates, cs = _lstm_forward(act, dtype, unroll, True, x, w_x, w_h, b_h)
-    return hs.reshape(*x.shape[:2], -1), (hs, gates, cs, x, w_x, w_h)
+    hs, cs = _lstm_forward(act, dtype, unroll, True, x, w_x, w_h, b_h)
+    return hs.reshape(*x.shape[:2], -1), (hs, cs, x, w_x, w_h, b_h)
 
 
 def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     """
-    The transposed recurrence, last step first. A step's elementwise half
-    is transposed by autodiff from the kept gates and the previous cell
-    state (its activations are computed again, no product is). Its
+    The transposed recurrence, last step first. A step's gates are made
+    again from its rows of ``x`` and the previous hidden state, the
+    forward's own expression of the same operands. Kept, they were 4h wide,
+    float32, written once and read once: at the widths of ``lstm50.fit``
+    the forward's two products cost less than those bytes
+    (docs/performance.md, "The backward loop makes a step's gates again").
+    A step's elementwise half is transposed by autodiff from the gates and
+    the previous cell state (its activations are computed again). Its
     ``d_gates`` (batch, 4h) is the cotangent of both gate products, and the
     loop makes all four transposes of them while it is on the chip, each
     with the dimension numbers autodiff writes: against ``w_h`` into the
@@ -356,7 +356,7 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     residuals, ``d_hs`` and ``d_x`` are row-flat, as the forward loop's
     buffers are.
     """
-    hs, gates, cs, x, w_x, w_h = residuals
+    hs, cs, x, w_x, w_h, b_h = residuals
     n_steps, batch, h_dim = d_hs.shape
     d_hs = d_hs.reshape(n_steps * batch, h_dim)
     x = x.reshape(n_steps * batch, -1)
@@ -369,15 +369,18 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
 
     def body(k, carry):
         d_c, d_h, d_x, d_w_x, d_w, d_b = carry
+        # what gives the step its gates: the forward's two products again
         with jax.named_scope("lstm.bwd.read"):
             t = n_steps - 1 - k
-            # the step's gates are read ONCE, as one slab, before the
-            # update's transpose takes its quarters of them: fused into that
-            # transpose, as XLA fuses a slice of rows, each of its two
-            # fusions reads the four quarters out of the 4h-wide buffer
-            # again (lstm50.fit on a v5e: 310 ms an epoch for 240; PERF.md
-            # section 6, PR 33)
-            step_gates = jax.lax.optimization_barrier(_read_step(gates, t, batch))
+            step_gates = lstm_gates(
+                previous(hs, t), _read_step(x, t, batch) @ w_x, w_h, b_h, dtype
+            )
+            # the gates are made ONCE, behind a barrier, before the update's
+            # transpose takes its quarters of them: without it their layout
+            # follows the product's, a 128-wide layer's lies rows-on-lanes,
+            # and four of the step's slabs are copied to meet them
+            # (lstm50.fit on a v5e: 573.5 ms an epoch for 559.2)
+            step_gates = jax.lax.optimization_barrier(step_gates)
         with jax.named_scope("lstm.bwd.cell"):
             _, update_vjp = jax.vjp(
                 lambda c, g: lstm_cell_update(c, g, act), previous(cs, t), step_gates
@@ -472,7 +475,13 @@ class FusedLSTMLayer(nn.Module):
     the step's rows of ``x`` itself, beside the recurrent kernel's two
     transposes: what it stacks is ``d_x``, f wide, not the 4h-wide
     cotangent of ``x @ w_x`` for two products over all rows to read back
-    ("The backward loop multiplies a step's gate cotangent itself").
+    ("The backward loop multiplies a step's gate cotangent itself"). And
+    it makes a step's gates again from ``x_t`` and the previous ``h``,
+    both of which it reads anyway, so the forward loop under a gradient
+    stacks ``h`` and ``c`` and not the 4h-wide float32 gates, whose write
+    and read cost more HBM time than the two products take on the MXU at
+    the widths of ``lstm50.fit`` ("The backward loop makes a step's gates
+    again").
     """
 
     features: int

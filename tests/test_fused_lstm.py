@@ -420,8 +420,9 @@ def lowered_text(fn, *args):
 def test_forward_only_call_stacks_one_buffer_a_layer():
     """Scoring, validation and streaming take no gradient: the scan then
     stacks the hidden states and nothing else: no ``z``, the step's input
-    product is the loop's own. Under a gradient it stacks the gates (before
-    their activations) and the cell states beside them, and on the way back
+    product is the loop's own. Under a gradient it stacks the cell states
+    beside them and not the gates: the backward loop makes them again with
+    the forward's two products. On the way back it stacks
     ``d_x``, f wide, if the input takes a cotangent: a first layer's does
     not (``x`` is data), and JAX drops that write and its product from the
     backward loop before XLA sees them."""
@@ -432,17 +433,20 @@ def test_forward_only_call_stacks_one_buffer_a_layer():
     assert forward.count("dynamic_update_slice") == 1
     # nothing (time*batch, 4h) exists without a gradient; with the projection
     # hoisted, ``z`` was one
-    assert f"tensor<{T * B}x{4 * H}xf32>" not in forward
+    gates_buffer = f"tensor<{T * B}x{4 * H}xf32>"
+    assert gates_buffer not in forward
 
     def loss(p, x):
         return jnp.sum(layer.apply(p, x))
 
     first_layer = lowered_text(jax.grad(loss), params, x)
-    assert first_layer.count("dynamic_update_slice") == 3
-    assert first_layer.count("dot_general") == 5
+    assert first_layer.count("dynamic_update_slice") == 2
+    assert first_layer.count("dot_general") == 7
     later_layer = lowered_text(jax.grad(loss, argnums=(0, 1)), params, x)
-    assert later_layer.count("dynamic_update_slice") == 4
-    assert later_layer.count("dot_general") == 6
+    assert later_layer.count("dynamic_update_slice") == 3
+    assert later_layer.count("dot_general") == 8
+    # nor with one: the gates were the widest stacked buffer
+    assert gates_buffer not in first_layer and gates_buffer not in later_layer
 
 
 def sub_equations(eqns):
@@ -453,18 +457,30 @@ def sub_equations(eqns):
             yield from sub_equations(sub.eqns)
 
 
+#: the 50-tag plant's six layers at the LSTM factory's default widths,
+#: (width, input features), and four layers far wider than any of them
+LAYER_SHAPES = [(256, 50), (128, 256), (64, 128), (64, 64), (128, 64), (256, 128)] + [
+    (8, 520), (256, 8), (256, 1000), (1024, 1000)
+]
+
+
 @pytest.mark.parametrize(
-    "width,n_features,batch", [(8, 5, 3), (8, 32, 3), (16, 50, 8)],
-    ids=["4h_32-f_5", "4h_32-f_32", "4h_64-f_50"],
+    "width,n_features,batch",
+    [(8, 5, 3), (8, 32, 3), (16, 50, 8)] + [(h, f, B) for h, f in LAYER_SHAPES],
+    ids=lambda v: str(v),
 )
 def test_backward_loop_stacks_no_gate_cotangent(width, n_features, batch):
     """The backward loop multiplies a step's ``d_gates`` by the input kernel
     and by the step's rows of ``x`` itself: the backward pass allocates and
-    writes ONE stacked buffer, ``d_x``, f wide, its four products are the
-    loop's and take a step's rows, and no product follows the loop. ``d_z``
-    was a second buffer, (time*batch, 4h), which two products over all rows
-    read after the loop. The second case has f = 4h: the widths alone do not
-    tell ``d_x`` from ``d_z``, the count of buffers does."""
+    writes ONE stacked buffer, ``d_x``, f wide, its products are the loop's
+    and take a step's rows, and no product follows the loop. ``d_z`` was a
+    second buffer, (time*batch, 4h), which two products over all rows read
+    after the loop. The second case has f = 4h: the widths alone do not
+    tell ``d_x`` from ``d_z``, the count of buffers does. The loop also
+    makes a step's gates again, the forward's two products beside the four
+    transposes, so the forward loop stacks ``h`` and ``c`` and no
+    (time*batch, 4h) gates: at the plant's six layer shapes and at layers
+    far wider than gordo's factories make by default."""
     x = jnp.zeros((T, batch, n_features))
     layer = FusedLSTMLayer(width, time_major=True)
     params = layer.init(jax.random.PRNGKey(0), x)
@@ -476,10 +492,10 @@ def test_backward_loop_stacks_no_gate_cotangent(width, n_features, batch):
     )
     rows = T * batch
     allocated = [
-        e.outvars[0].aval.shape
-        for e in eqns[forward_loop + 1 :] if e.primitive.name == "empty"
+        (i > forward_loop, e.outvars[0].aval.shape)
+        for i, e in enumerate(eqns) if e.primitive.name == "empty"
     ]
-    assert allocated == [(rows, n_features)]
+    assert allocated == [(False, (rows, width))] * 2 + [(True, (rows, n_features))]
     body = list(sub_equations(eqns[backward_loop].params["jaxpr"].eqns))
     written = [
         e.outvars[0].aval.shape for e in body
@@ -487,7 +503,7 @@ def test_backward_loop_stacks_no_gate_cotangent(width, n_features, batch):
     ]
     assert written == [(rows, n_features)]
     products = [e for e in body if e.primitive.name == "dot_general"]
-    assert len(products) == 4
+    assert len(products) == 6
     assert all(rows not in v.aval.shape for e in products for v in e.invars)
     assert not [
         e for e in sub_equations(eqns[backward_loop + 1 :])
