@@ -200,6 +200,36 @@ def lstm_cell_update(c, gates, act):
     return c, h
 
 
+def lstm_cell_update_transpose(c, gates, d_c, d_h, act):
+    """
+    The cotangents of ``c`` and ``gates`` in :func:`lstm_cell_update` from
+    those of the new (c, h): autodiff's own, op for op, taken in two parts,
+    the gates' activations and what makes (c, h) of them. The activations,
+    the residuals of their derivatives, ``c`` and ``d_h`` pass through ONE
+    ``optimization_barrier``, so each is made once and read once.
+    Transposed whole, the update compiles for a TPU to fusions that each
+    make the activations again and each read ``c`` and ``d_h`` again, rows
+    the backward loop reads from HBM (docs/performance.md, "The backward
+    step's transposed cell update is made once").
+    """
+
+    def activations(gates):
+        i, f, g, o = jnp.split(gates, 4, axis=-1)
+        return nn.sigmoid(i), nn.sigmoid(f), act(g), nn.sigmoid(o)
+
+    def update(c, activations):
+        i, f, g, o = activations
+        c = f * c + i * g
+        return c, o * act(c)
+
+    acts, acts_vjp = jax.vjp(activations, gates)
+    acts, acts_vjp, c, d_h = jax.lax.optimization_barrier((acts, acts_vjp, c, d_h))
+    _, update_vjp = jax.vjp(update, c, acts)
+    d_c, d_acts = update_vjp((d_c, d_h))
+    (d_gates,) = acts_vjp(d_acts)
+    return d_c, d_gates
+
+
 def lstm_cell_step(c, h, z_t, w_h, b_h, act, dtype):
     """
     One LSTM timestep from pre-projected input ``z_t`` (gate order
@@ -340,8 +370,10 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
     float32, written once and read once: at the widths of ``lstm50.fit``
     the forward's two products cost less than those bytes
     (docs/performance.md, "The backward loop makes a step's gates again").
-    A step's elementwise half is transposed by autodiff from the gates and
-    the previous cell state (its activations are computed again). Its
+    A step's elementwise half is transposed by
+    :func:`lstm_cell_update_transpose` from the gates, the previous cell
+    state and ``d_h`` with the step's rows of ``d_hs``: autodiff's own ops,
+    each activation made once and each of those two rows read once. Its
     ``d_gates`` (batch, 4h) is the cotangent of both gate products, and the
     loop makes all four transposes of them while it is on the chip, each
     with the dimension numbers autodiff writes: against ``w_h`` into the
@@ -375,17 +407,10 @@ def _lstm_time_scan_bwd(act, dtype, unroll, residuals, d_hs):
             step_gates = lstm_gates(
                 previous(hs, t), _read_step(x, t, batch) @ w_x, w_h, b_h, dtype
             )
-            # the gates are made ONCE, behind a barrier, before the update's
-            # transpose takes its quarters of them: without it their layout
-            # follows the product's, a 128-wide layer's lies rows-on-lanes,
-            # and four of the step's slabs are copied to meet them
-            # (lstm50.fit on a v5e: 573.5 ms an epoch for 559.2)
-            step_gates = jax.lax.optimization_barrier(step_gates)
         with jax.named_scope("lstm.bwd.cell"):
-            _, update_vjp = jax.vjp(
-                lambda c, g: lstm_cell_update(c, g, act), previous(cs, t), step_gates
+            d_c, d_gates = lstm_cell_update_transpose(
+                previous(cs, t), step_gates, d_c, d_h + _read_step(d_hs, t, batch), act
             )
-            d_c, d_gates = update_vjp((d_c, d_h + _read_step(d_hs, t, batch)))
             d_gates = d_gates.astype(dtype)
         with jax.named_scope("lstm.bwd.products"):
             d_w_x = d_w_x + jax.lax.dot_general(

@@ -346,6 +346,57 @@ def test_lstm_cell_epoch_program_stacks_no_gate_cotangent(lstm_cell_epoch_progra
     assert not re.findall(r"op_name=\"[^\"]*/scan/dot_general\"", text)
 
 
+def backward_step_ops(text):
+    """Per layer of the cell, what the fusions its backward loop's body
+    calls under ``lstm.bwd.*`` compute: the count of each transcendental op
+    and of the float32 (machines, batch, h) row reads of a stacked (machines,
+    time*batch, h) buffer, which in the backward loop are the previous cell
+    states and ``d_hs``."""
+    computations = {
+        m[1]: body for body in re.split(r"\n(?=%[\w.\-]+ \()", text)
+        if (m := re.match(r"%([\w.\-]+) \(", body))
+    }
+    per_layer = []
+    for layer, width in enumerate(CELL_ENC + CELL_DEC):
+        step = (
+            rf"transpose\([^\"]*/FusedLSTMLayer_{layer}/scan/while/body/"
+            r"closed_call/lstm\.bwd\."
+        )
+        fused = "\n".join(
+            computations[callee]
+            for body in computations.values()
+            for line in body.split("\n")
+            if " fusion(" in line and re.search(step, line)
+            for callee in re.findall(r"calls=%([\w.\-]+)", line)
+        )
+        params = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]+\])\S* parameter", fused))
+        sliced = re.findall(
+            rf"f32\[{CELL_MACHINES},{BATCH},{width}\]\S* dynamic-slice\((%[\w.\-]+)", fused
+        )
+        stacked = f"f32[{CELL_MACHINES},{LOOKBACK * BATCH},{width}]"
+        per_layer.append({
+            op: len(re.findall(rf"\S+ {op}\(", fused))
+            for op in ("exponential", "divide", "tanh")
+        } | {"row_reads": sum(params.get(p) == stacked for p in sliced)})
+    return per_layer
+
+
+def test_lstm_cell_backward_step_makes_each_activation_once(lstm_cell_epoch_program):
+    """A backward step's transposed cell update computes each of the five
+    activations once and reads the previous cell states' rows and ``d_hs``'s
+    rows from HBM once each, in every layer. Transposed whole, the update
+    compiled to two fusions a step, each computing sigmoid on i, f and o and
+    ``act`` on g again (6 ``exponential``, 6 ``divide``, 3 ``tanh``) and
+    each reading both rows, 8 MB a 256-wide step where 4 MB are needed
+    (docs/performance.md, "The backward step's transposed cell update is
+    made once")."""
+    per_layer = backward_step_ops(lstm_cell_epoch_program.as_text())
+    for layer, ops in enumerate(per_layer):
+        assert ops["exponential"] == ops["divide"] == 3, (layer, ops)
+        assert ops["tanh"] <= 2, (layer, ops)
+        assert ops["row_reads"] == 2, (layer, ops)
+
+
 def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
     """The epoch program a TPU's feedforward fleet gets (``row_fetch``
     ``"permute_epoch"``), 48 machines of ff50.fit1000's 1000: two groups of

@@ -16,7 +16,13 @@ import numpy as np
 import pytest
 
 from gordo_tpu.models import LSTMAutoEncoder
-from gordo_tpu.models.specs import FusedLSTMLayer, LSTMNet, lstm_cell_step
+from gordo_tpu.models.specs import (
+    FusedLSTMLayer,
+    LSTMNet,
+    lstm_cell_step,
+    lstm_cell_update,
+    lstm_cell_update_transpose,
+)
 
 B, T, F, H = 3, 7, 5, 8
 
@@ -411,6 +417,41 @@ def test_time_scan_of_one_step(activation):
     params, xs, probe = fleet_inputs(np.random.default_rng(1), layer, 1, H, True)
     got, want = (fleet_steps(m, params, xs, probe) for m in (layer, reference))
     assert_same_tree(got, want, jnp.float32)
+
+
+@pytest.mark.parametrize("first_step", [False, True], ids=["later_step", "zero_state"])
+@pytest.mark.parametrize("activation", ["tanh", "relu"])
+def test_cell_update_transpose_is_autodiffs_bit_for_bit(activation, first_step):
+    """The backward step's transpose of the cell update, taken in two parts
+    with the activations behind a barrier, gives ``jax.vjp``'s cotangents of
+    ``lstm_cell_update`` bit for bit in float32, under the fleet's ``vmap``:
+    the previous cell state's and the gates'. At ``t = 0`` the previous cell
+    state is the zero state; ``relu``'s derivative is taken from its input."""
+    from gordo_tpu.ops.activations import resolve_activation
+
+    act = resolve_activation(activation)
+    rng = np.random.default_rng(3)
+
+    def normal(width, scale=1.0):
+        return jnp.asarray(rng.normal(size=(N_MACHINES, B, width)) * scale, jnp.float32)
+
+    c = normal(H, 2.0) * (0.0 if first_step else 1.0)
+    gates, d_c, d_h = normal(4 * H, 3.0), normal(H), normal(H)
+
+    @jax.jit
+    @jax.vmap
+    def autodiff(c, gates, d_c, d_h):
+        _, update_vjp = jax.vjp(lambda c, g: lstm_cell_update(c, g, act), c, gates)
+        return update_vjp((d_c, d_h))
+
+    @jax.jit
+    @jax.vmap
+    def transpose(c, gates, d_c, d_h):
+        return lstm_cell_update_transpose(c, gates, d_c, d_h, act)
+
+    want, got = autodiff(c, gates, d_c, d_h), transpose(c, gates, d_c, d_h)
+    assert np.abs(want[1]).max() > 0
+    assert_same_tree(got, want, jnp.float32, exact=True)
 
 
 def lowered_text(fn, *args):
