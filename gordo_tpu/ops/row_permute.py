@@ -1,23 +1,43 @@
 """
-Row permutation from on-chip memory, as a Pallas TPU kernel.
+A fleet's epoch of minibatches, fetched by ONE Pallas TPU kernel.
 
-``out[g, j] = table[g, idx[g, j]]`` for a stack of small tables: one grid
-step per table brings the whole ``(n, f)`` table into VMEM with one
-contiguous copy (the pipeline fetches the next table while this one is
-permuted), copies that table's ``(n_out,)`` indices into SMEM, and moves
-the rows one by one with a dynamic single-row load and a single-row store.
-An XLA gather fetches every row from HBM on its own; here HBM sees two
-contiguous streams and the random access stays in vector memory.
+:func:`epoch_batches` gives the trainer, once an epoch, every machine's rows
+``X[m][order[m]]`` and ``y[m][order[m]]`` cut into the step loop's batches.
+The kernel reads the stacked tables as they lie on the chip and writes the
+step slabs as the step loop reads them, so that XLA adds no copy on either
+side:
 
-The kernel moves rows, so the table's rows have to lie on the sublane axis
-(row-major, ``f`` on lanes). A table occupies ``n x round_up(f, 128) x 4``
-bytes of VMEM whatever ``f`` is, so the caller packs what it can into the
-128 lanes of one row (``parallel/fleet.py`` puts a machine's input and
-target columns side by side) and asks :func:`serves` first.
+* A stacked table ``f32[M, n, f]`` lies rows-on-lanes in (8, 128) tiles,
+  with the tags outermost (XLA:TPU's layout ``{1,0,2}``, physically
+  ``(f, M, n)``: a tile holds 8 machines' rows of one tag) or, where that
+  pads less (:func:`_tags_outermost`), the machines outermost (``{1,2,0}``,
+  ``(M, f, n)``: a tile holds 8 tags' rows of one machine). The kernel takes
+  each table as that transpose, which XLA hands over by bitcast, and its
+  grid walks the fleet a group of 8 machines (one sublane tile) at a time:
+  DMAs bring a group's tables into vector memory, whole tiles of the
+  tables' own (the padding of a fleet's last tiles with them), and the
+  next group's start as soon as this one's last machine has read its own.
+* For each machine of the group it stacks the machine's input and target
+  rows (strided loads where a machine is one sublane of every tile) into a
+  128 x 128 square per 128 timesteps, input tags first and target tags from
+  ``yoff``, and transposes the square: the packed table ``[n, 128]``, one
+  ``[x | y]`` row per timestep.
+* It moves the packed rows into the epoch's order by the machine's indices
+  in scalar memory, 128 at a time, transposes each block of 128 back, and
+  writes its input rows and its target rows into the slabs
+  ``(n_batches, M, f, batch)``, the order in which the step loop's products
+  take a batch. Every slab element is written once.
 
-On the CPU backend (tests, rehearsals) the kernel runs in interpret mode;
-on ``tpu`` it compiles through Mosaic; any other backend is an error
-(the rule of ``ops/flash_attention.py``).
+Data movement only: the slabs hold the tables' own bits (the input's
+rounded to bfloat16 where the caller says its products take them so,
+:func:`epoch_batches`). A batch that is not a multiple of 128 rows makes
+the kernel write a machine's rows as one slab that XLA then cuts into
+batches.
+
+On the CPU backend (tests, rehearsals) the kernel runs in interpret mode,
+on tables padded to whole tiles first; on ``tpu`` it compiles through
+Mosaic; any other backend is an error (the rule of
+``ops/flash_attention.py``).
 """
 
 import functools
@@ -30,203 +50,392 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _LANES, _interpret_for_backend, _round_up
 
-#: rows moved per iteration of the kernel's loop: one (8, 128) output tile,
-#: so that every store's sublane offset within its tile is static
-_ROWS_PER_ITER = 8
+#: machines a grid step brings in: one (8, 128) tile's sublanes, the least
+#: a DMA may cut out of the rows-on-lanes tables
+_GROUP = 8
+#: column tiles of a machine packed in one iteration of the kernel's loop,
+#: blocks of 128 moved rows laid back in one (beside the next blocks'
+#: moves), and rows moved in one: independent work written out for the
+#: scheduler to overlap, and no more, since every process that builds the
+#: program traces and lowers what is written out again
+_PACK_BY = 8
+_LAY_BY = 2
+_MOVE_BY = 32
 #: what one kernel call may ask of the chip's vector memory (a v5e core has
-#: 128 MiB): the table and the permuted rows, each double-buffered by the
-#: pipeline, plus room for the compiler's own
+#: 128 MiB): the group's tables, the packed table, the squares and the
+#: double-buffered slabs, plus room for the compiler's own
 _VMEM_BUDGET_BYTES = 96 * 1024 * 1024
 _VMEM_HEADROOM_BYTES = 4 * 1024 * 1024
+#: the group's indices in scalar memory (a v5e core has 1 MiB)
+_SMEM_BUDGET_BYTES = 768 * 1024
 
 
-def vmem_bytes(n: int, n_out: int, f: int) -> int:
-    """VMEM the kernel's buffers take for one ``(n, f)`` float32 table and
-    ``n_out`` permuted rows: rows pad to 128 lanes, both blocks are
-    double-buffered."""
-    row = _round_up(f, _LANES) * 4
-    return 2 * (_round_up(n, 8) + _round_up(n_out, 8)) * row
+def _tags_outermost(n_machines: int, f: int) -> bool:
+    """Whether XLA:TPU lays a stacked ``f32[M, n, f]`` table with the tags
+    outermost (``{1,0,2}``) rather than the machines (``{1,2,0}``): the
+    layout that pads the table's (8, 128) tiles less, the machines'
+    where both pad alike."""
+    return f * _round_up(n_machines, _GROUP) < _round_up(f, _GROUP) * n_machines
 
 
-def _movable(dtype, n_out: int) -> bool:
-    """What the kernel is written for: float32 rows, and a count of output
-    rows that fills whole 8-row tiles."""
-    return jnp.dtype(dtype) == jnp.float32 and n_out % _ROWS_PER_ITER == 0
+def vmem_bytes(n: int, n_out: int, fx: int, fy: int, n_machines: int = 1) -> int:
+    """VMEM the kernel's buffers take for ``(n, fx)`` input and ``(n, fy)``
+    target float32 tables of ``n_machines`` machines and ``n_out`` fetched
+    rows: a group's tables (a machine's tags pad to 8 sublanes where the
+    machines lie outermost), the packed table (rows pad to 128 lanes), the
+    128 x 128 squares (one to pack, two sets of moved blocks), and an input
+    and a target slab block (tags pad to 8 sublanes) double-buffered by the
+    pipeline."""
+    n_pad, out_pad = _round_up(n, _LANES), _round_up(n_out, _LANES)
+
+    def tags(f):
+        return f if _tags_outermost(n_machines, f) else _round_up(f, 8)
+
+    group = (tags(fx) + tags(fy)) * _GROUP * n_pad
+    packed = n_pad * _LANES
+    squares = (1 + 2 * _LAY_BY) * _LANES * _LANES
+    slabs = 2 * (_round_up(fx, 8) + _round_up(fy, 8)) * out_pad
+    return 4 * (group + packed + squares + slabs)
+
+
+def smem_bytes(n_out: int) -> int:
+    """Scalar memory a group's int32 indices take."""
+    return 4 * _GROUP * _round_up(n_out, _LANES)
 
 
 def serves(X, y, n_out: int) -> bool:
     """
     Whether :func:`epoch_batches` can fetch ``n_out`` rows an epoch from
-    tables like ``X`` and ``y`` (``(..., n, f)``; only shapes and dtypes are
-    read): float32 rows in whole 8-row tiles, an input row and its target
-    row side by side within the 128 lanes of one packed row, and a machine's
-    packed table within the kernel's share of vector memory.
+    tables like ``X`` and ``y`` (``(M, n, f)``, or one machine's ``(n, f)``;
+    only shapes and dtypes are read): float32 rows, an input row and its
+    target row side by side within the 128 lanes of one packed row, and the
+    kernel's buffers within its share of vector and scalar memory.
     """
     n, fx, fy = X.shape[-2], X.shape[-1], y.shape[-1]
+    n_machines = X.shape[0] if X.ndim == 3 else 1
     return (
-        _movable(X.dtype, n_out)
-        and _movable(y.dtype, n_out)
+        jnp.dtype(X.dtype) == jnp.float32
+        and jnp.dtype(y.dtype) == jnp.float32
         and fx + fy <= _LANES
-        and vmem_bytes(n, n_out, fx + fy) + _VMEM_HEADROOM_BYTES <= _VMEM_BUDGET_BYTES
+        and vmem_bytes(n, n_out, fx, fy, n_machines) + _VMEM_HEADROOM_BYTES
+        <= _VMEM_BUDGET_BYTES
+        and smem_bytes(n_out) <= _SMEM_BUDGET_BYTES
     )
 
 
-def _permute_kernel(idx_hbm, table_ref, out_ref, idx_smem, sem, *, n_out):
-    g = pl.program_id(0)
-    fetch = pltpu.make_async_copy(idx_hbm.at[g], idx_smem, sem)
-    fetch.start()
-    fetch.wait()
-
-    def move_tile(t, carry):
-        base = pl.multiple_of(t * _ROWS_PER_ITER, _ROWS_PER_ITER)
-        for u in range(_ROWS_PER_ITER):
-            row = idx_smem[0, base + u]
-            out_ref[0, pl.ds(base + u, 1), :] = table_ref[0, pl.ds(row, 1), :]
+def _in_runs(n, run, by):
+    """``run(start, count)`` over ``range(n)`` in runs of ``by``, one run an
+    iteration of a loop of the kernel (Mosaic unrolls a loop whole or not
+    at all), the rest after it: ``start`` is a multiple of ``by``, ``count``
+    static, so that a run is written out and the scheduler can overlap its
+    independent parts."""
+    def each(c, carry):
+        run(pl.multiple_of(c * by, by), by)
         return carry
 
-    jax.lax.fori_loop(0, n_out // _ROWS_PER_ITER, move_tile, 0)
+    if n // by:
+        jax.lax.fori_loop(0, n // by, each, 0)
+    if n % by:
+        run(n - n % by, n % by)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _permute_stack(table, idx, interpret):
-    n_tables, n, f = table.shape
-    n_out = idx.shape[1]
-    limit = vmem_bytes(n, n_out, f) + _VMEM_HEADROOM_BYTES
-    return pl.pallas_call(
-        functools.partial(_permute_kernel, n_out=n_out),
-        out_shape=jax.ShapeDtypeStruct((n_tables, n_out, f), table.dtype),
-        grid_spec=pl.GridSpec(
-            grid=(n_tables,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec((1, n, f), lambda g: (g, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n_out, f), lambda g: (g, 0, 0)),
-            scratch_shapes=[
-                pltpu.SMEM((1, n_out), jnp.int32),
-                pltpu.SemaphoreType.DMA(()),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=limit,
-        ),
-        interpret=interpret,
-        name="row_permute",
-    )(idx[:, None, :], table)
+def _fetch_kernel(
+    x_hbm, y_hbm, idx_hbm, x_out, y_out,
+    x_group, y_group, square, packed, moved, idx_smem, sems,
+    *, n_machines, n_groups, n_cols, n_out, fx, fy, yoff, width,
+):
+    g, j = pl.program_id(0), pl.program_id(1)
+    m = g * _GROUP + j
+    tables = (
+        (x_hbm, x_group, sems.at[0], fx, _tags_outermost(n_machines, fx)),
+        (y_hbm, y_group, sems.at[1], fy, _tags_outermost(n_machines, fy)),
+    )
+
+    def group_copies(group, act):
+        machine0 = pl.multiple_of(group * _GROUP, _GROUP)
+        for src, dst, sem, f, tags_out in tables:
+            if tags_out:
+                # (f, M, n): one (8, 128) tile of every tag a DMA, the
+                # group's 8 machines by 128 timesteps
+                def each(c, carry, src=src, dst=dst, sem=sem, f=f):
+                    cols = pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)
+                    act(pltpu.make_async_copy(
+                        src.at[:, pl.ds(machine0, _GROUP), cols],
+                        dst.at[pl.ds(c * f, f)], sem,
+                    ))
+                    return carry
+
+                jax.lax.fori_loop(0, n_cols, each, 0)
+            else:
+                # (M, f, n): a machine's tiles a DMA (the last machine again
+                # in the places of machines past the fleet's end)
+                whole = (pl.ds(0, dst.shape[1]), pl.ds(0, dst.shape[2]))
+                for u in range(_GROUP):
+                    machine = jnp.minimum(machine0 + u, n_machines - 1)
+                    act(pltpu.make_async_copy(src.at[(machine,) + whole], dst.at[u], sem))
+
+    idx_copy = pltpu.make_async_copy(
+        idx_hbm.at[pl.ds(g * idx_smem.shape[0], idx_smem.shape[0])],
+        idx_smem, sems.at[2],
+    )
+
+    @pl.when((g == 0) & (j == 0))
+    def _():
+        group_copies(0, lambda copy: copy.start())
+
+    @pl.when(j == 0)
+    def _():
+        idx_copy.start()
+        group_copies(g, lambda copy: copy.wait())
+
+    def at(k):
+        if isinstance(k, int):
+            return k * _LANES
+        return pl.multiple_of(k * _LANES, _LANES)
+
+    def tags_at(table, c):
+        # machine j's rows of the table's tags, timesteps c * 128 on
+        _, group, _, f, tags_out = table
+        if tags_out:
+            # one sublane of each tag's tile: the tiles as rows of 128
+            # lanes, a column's tags after each other
+            rows = group.reshape(n_cols * f * _GROUP, _LANES)
+            return rows[pl.ds(c * f * _GROUP + j, f, stride=_GROUP), :]
+        return group[j, pl.ds(0, f), pl.ds(at(c), _LANES)]
+
+    def pack(c0, count):
+        # machine j's 128 timesteps from c * 128, input tags first, then
+        # target tags
+        for c in (c0 + u for u in range(count)):
+            square[pl.ds(0, fx), :] = tags_at(tables[0], c)
+            square[pl.ds(yoff, fy), :] = tags_at(tables[1], c)
+            packed[pl.ds(at(c), _LANES), :] = square[...].T
+
+    # machine j's indices, flat: an index's address is one add
+    first = j * (idx_smem.shape[0] // _GROUP)
+
+    def move(k, rows, slot):
+        # block k's rows into the epoch's order
+        def run(r0, count):
+            at_idx = first + at(k) + r0
+            rows_out = moved.at[slot, pl.ds(r0, count)]
+
+            def one(u, carry):
+                row = idx_smem[at_idx + u]
+                rows_out[pl.ds(u, 1), :] = packed[pl.ds(row, 1), :]
+                return carry
+
+            # written out whole, traced once
+            jax.lax.fori_loop(0, count, one, 0, unroll=True)
+
+        _in_runs(rows, run, _MOVE_BY)
+
+    def lay(k, rows, slot):
+        # block k back to tags-by-timesteps, into its slab
+        block = moved[slot].T
+        if width % _LANES == 0:
+            slab, col = at(k) // width, at(k) % width
+            if not isinstance(k, int):
+                col = pl.multiple_of(col, _LANES)
+        else:
+            slab, col = 0, at(k)
+        x_out[slab, :, pl.ds(col, rows)] = block[:fx, :rows].astype(x_out.dtype)
+        y_out[slab, :, pl.ds(col, rows)] = block[yoff:yoff + fy, :rows]
+
+    @pl.when(m < n_machines)
+    def _():
+        _in_runs(n_cols, pack, _PACK_BY)
+
+        # the group's tables are read: the next group's come in meanwhile
+        @pl.when((j == _GROUP - 1) & (g + 1 < n_groups))
+        def _():
+            group_copies(g + 1, lambda copy: copy.start())
+
+        @pl.when(j == 0)
+        def _():
+            idx_copy.wait()
+
+        # _LAY_BY blocks' moves beside the previous ones' laying, so that
+        # the transposes overlap each other and the moves; the first
+        # blocks' moves, and the blocks the loop leaves (a part-full last
+        # one among them), go one by one
+        full, tail = divmod(n_out, _LANES)
+        steps = full // _LAY_BY
+
+        def lays(i, half):
+            for u in range(_LAY_BY):
+                lay(i * _LAY_BY + u, _LANES, half * _LAY_BY + u)
+
+        def step(i, carry):
+            lays(i - 1, (i - 1) % 2)
+            for u in range(_LAY_BY):
+                move(i * _LAY_BY + u, _LANES, (i % 2) * _LAY_BY + u)
+            return carry
+
+        def first_moves(u, carry):
+            move(u, _LANES, u)
+            return carry
+
+        def leftover(k, carry):
+            move(k, _LANES, 0)
+            lay(k, _LANES, 0)
+            return carry
+
+        if steps:
+            jax.lax.fori_loop(0, _LAY_BY, first_moves, 0)
+            if steps > 1:
+                jax.lax.fori_loop(1, steps, step, 0)
+            lays(steps - 1, (steps - 1) % 2)
+        if full > steps * _LAY_BY:
+            jax.lax.fori_loop(steps * _LAY_BY, full, leftover, 0)
+        if tail:
+            move(full, tail, 0)
+            lay(full, tail, 0)
 
 
-def permute_rows(
-    table: jnp.ndarray, idx: jnp.ndarray, interpret: Optional[bool] = None
-) -> jnp.ndarray:
+def _as_laid(a, tags_out: bool, interpret: bool):
     """
-    ``table[idx]`` for one ``(n, f)`` table and ``(n_out,)`` int32 indices,
-    or row by row for a stack ``(g, n, f)`` / ``(g, n_out)``: bit for bit
-    what the gather gives, for indices inside the table (a permutation, or
-    one with repeats; nothing checks the range).
-
-    ``interpret=None`` selects from the backend: compiled Mosaic kernel on
-    ``tpu``, interpreter on ``cpu``, ValueError on anything else.
+    ``a`` (M, n, f) in the order of its layout on the chip, ``(f, M, n)``
+    with the tags outermost, else ``(M, f, n)``: a bitcast there. The
+    kernel reads whole (8, 128) tiles. Compiled, a tile past the table's
+    last row, and past its last machine (tags outermost) or tag (machines
+    outermost), lies in the padding of the table's own tiles, which are
+    (8, 128) where their sublanes number 8 or more. The interpreter's
+    slices, and smaller tiles, stop at the array's end: there the table is
+    padded to whole tiles first (a copy).
     """
-    if not _movable(table.dtype, idx.shape[-1]):
-        raise ValueError(
-            f"permute_rows moves float32 rows in tiles of {_ROWS_PER_ITER}: "
-            f"got {table.dtype} and {idx.shape[-1]} indices"
-        )
-    if interpret is None:
-        interpret = _interpret_for_backend(jax.default_backend())
-    idx = idx.astype(jnp.int32)
-    if table.ndim == 2:
-        return _permute_stack(table[None], idx[None], interpret)[0]
-    return _permute_stack(table, idx, interpret)
+    m, n, f = a.shape
+    sublanes = m if tags_out else f
+    if interpret or sublanes < _GROUP:
+        rows = (0, _round_up(n, _LANES) - n)
+        if tags_out:
+            a = jnp.pad(a, ((0, _round_up(m, _GROUP) - m), rows, (0, 0)))
+        else:
+            a = jnp.pad(a, ((0, 0), rows, (0, _round_up(f, _GROUP) - f)))
+    return a.transpose(2, 0, 1) if tags_out else a.transpose(0, 2, 1)
 
 
-# --------------------------------------------------------------------------
-# a fleet's epoch of minibatches
-# --------------------------------------------------------------------------
-
-#: bytes of packed row-major tables the fleet loop takes at a time: the loop
-#: walks the fleet in groups of machines small enough that XLA keeps a
-#: group's packed tables, the kernel's operands among them, in vector
-#: memory from the packing to the laying back, and HBM sees only the data
-#: read once and the permuted rows written once. Measured on ff50.fit1000's
-#: epoch program (PERF.md, PR 25; 8.4 MB a machine): groups of 1, 2, 4, 8,
-#: 16, 40 and 104 machines gave epochs of 349, 343, 305, 341, 368, 406 and
-#: 419 ms; from 8 machines on the compiler leaves the tables in HBM.
-_GROUP_BYTES = 32 * 1024 * 1024
-
-
-def _group_size(n_machines: int, n: int, n_out: int) -> int:
-    per_machine = max(n, n_out) * _LANES * 4
-    return max(1, min(_GROUP_BYTES // per_machine, n_machines))
-
-
-def _fleet_batches(X, y, idx, n_batches, interpret):
+def _fleet_batches(X, y, idx, n_batches, x_dtype, interpret):
     """
     ``X[m][idx[m]]`` and ``y[m][idx[m]]`` for every machine ``m``, cut into
     ``n_batches`` batches: ``(M, n_batches, batch, fx)`` and ``(..., fy)``.
-
-    A stacked fleet's data lies on the chip with the rows on lanes and the
-    tags outermost (the compact layout XLA:TPU gives ``f32[M, n, 50]``), so
-    a group of machines at a time is packed row-major, ``[x | y | 0]`` in
-    the 128 lanes of one row, permuted by the kernel, and laid back as
-    ``(n_batches, f, M, batch)`` slabs, the form in which the step loop's
-    products take a batch. The transposes at the two ends say that to XLA;
-    they move no data of their own.
+    The kernel writes ``(n_batches, M, f, batch)`` slabs, the form in which
+    the step loop's products take a batch; the transposes at the two ends
+    say that to XLA and move no data of their own.
     """
     n_machines, n, fx = X.shape
     fy = y.shape[2]
     n_out = idx.shape[1]
     batch = n_out // n_batches
-    group = _group_size(n_machines, n, n_out)
-    n_groups = -(-n_machines // group)
-    filler = jnp.zeros((group, n, _LANES - fx - fy), X.dtype)
+    # a slab is a batch where a batch is whole 128-lane tiles, else a
+    # machine's whole epoch, which XLA then cuts into batches
+    width = batch if batch % _LANES == 0 else n_out
+    n_slabs = n_out // width
+    m_pad, n_pad = _round_up(n_machines, _GROUP), _round_up(n, _LANES)
+    out_pad = _round_up(n_out, _LANES)
+    idx = idx.astype(jnp.int32)
+    if (m_pad, out_pad) != (n_machines, n_out):
+        idx = jnp.pad(idx, ((0, m_pad - n_machines), (0, out_pad - n_out)))
+    n_groups = m_pad // _GROUP
+    n_cols = n_pad // _LANES
+    tags_out = tuple(_tags_outermost(n_machines, f) for f in (fx, fy))
 
-    def slab(rows):
-        f = rows.shape[2]
-        return rows.reshape(group, n_batches, batch, f).transpose(1, 3, 0, 2)
+    def group_scratch(f, tags_outermost):
+        if tags_outermost:
+            shape = (n_cols * f, _GROUP, _LANES)
+        else:
+            shape = (_GROUP, _round_up(f, _GROUP), n_pad)
+        return pltpu.VMEM(shape, jnp.float32)
 
-    def one_group(g, slabs):
-        px, py = slabs
-        # the last group steps back to end at the fleet's end, and writes
-        # some machines a second time with the same rows
-        start = jnp.minimum(g * group, n_machines - group)
-        take = lambda a: jax.lax.dynamic_slice_in_dim(a, start, group, 0)
-        packed = jnp.concatenate([take(X), take(y), filler], axis=2)
-        moved = _permute_stack(packed, take(idx), interpret)
-        at = (0, 0, start, 0)
-        px = jax.lax.dynamic_update_slice(px, slab(moved[:, :, :fx]), at)
-        py = jax.lax.dynamic_update_slice(py, slab(moved[:, :, fx:fx + fy]), at)
-        return px, py
+    # target rows from a tile's edge where they fit, else straight after
+    # the input's
+    yoff = min(_round_up(fx, 8), _LANES - fy)
 
-    # the loop writes every slab, so what they hold before it is nothing's
-    # value; filled from the data and not with a constant, because XLA takes
-    # a constant fill out from under the scope's name and a device trace
-    # then shows its time as nobody's
-    unwritten = lambda f: jnp.full((n_batches, f, n_machines, batch), X[0, 0, 0])
-    px, py = jax.lax.fori_loop(
-        0, n_groups, one_group, (unwritten(fx), unwritten(fy))
+    def slab_spec(f):
+        return pl.BlockSpec(
+            (n_slabs, None, f, width),
+            lambda g, j: (0, jnp.minimum(g * _GROUP + j, n_machines - 1), 0, 0),
+        )
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    x_slabs, y_slabs = pl.pallas_call(
+        functools.partial(
+            _fetch_kernel, n_machines=n_machines, n_groups=n_groups,
+            n_cols=n_cols, n_out=n_out, fx=fx, fy=fy, yoff=yoff, width=width,
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_slabs, n_machines, fx, width), x_dtype),
+            jax.ShapeDtypeStruct((n_slabs, n_machines, fy, width), y.dtype),
+        ),
+        grid_spec=pl.GridSpec(
+            grid=(n_groups, _GROUP),
+            in_specs=[anywhere, anywhere, anywhere],
+            out_specs=[slab_spec(fx), slab_spec(fy)],
+            scratch_shapes=[
+                group_scratch(fx, tags_out[0]),
+                group_scratch(fy, tags_out[1]),
+                pltpu.VMEM((_LANES, _LANES), jnp.float32),
+                pltpu.VMEM((n_pad, _LANES), jnp.float32),
+                pltpu.VMEM((2 * _LAY_BY, _LANES, _LANES), jnp.float32),
+                pltpu.SMEM((_GROUP * out_pad,), jnp.int32),
+                pltpu.SemaphoreType.DMA((3,)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=(
+                vmem_bytes(n, n_out, fx, fy, n_machines) + _VMEM_HEADROOM_BYTES
+            ),
+        ),
+        interpret=interpret,
+        name="epoch_fetch",
+    )(
+        _as_laid(X, tags_out[0], interpret),
+        _as_laid(y, tags_out[1], interpret),
+        idx.reshape(-1),
     )
-    return px.transpose(2, 0, 3, 1), py.transpose(2, 0, 3, 1)
+
+    def batches(slabs, f):
+        if n_slabs == n_batches:
+            return slabs.transpose(1, 0, 3, 2)
+        rows = slabs[0].reshape(n_machines, f, n_batches, batch)
+        return rows.transpose(0, 2, 3, 1)
+
+    return batches(x_slabs, fx), batches(y_slabs, fy)
 
 
-def epoch_batches(n_batches: int, interpret: Optional[bool] = None):
+def epoch_batches(
+    n_batches: int,
+    input_in_products: bool = False,
+    interpret: Optional[bool] = None,
+):
     """
     ``fetch(Xi, yi, order) -> (xb_all, yb_all)`` for ONE machine: its rows
     ``Xi[order]``, ``yi[order]`` as ``(n_batches, batch, f)`` stacks, the
     ``xs`` of the trainer's step loop. Under ``jax.vmap`` over a fleet the
-    whole fleet goes through :func:`permute_rows` a group of machines at a
-    time (the rule below), which is how the trainer calls it.
+    whole fleet goes through ONE kernel call (the rule below), which is how
+    the trainer calls it.
+
+    ``input_in_products``: the caller reads the input rows only as operands
+    of matrix products at the default precision. Compiled on a TPU, where
+    such a product takes them in bfloat16, the kernel then stores the input
+    slab rounded to bfloat16, as XLA stores such an operand (half the
+    bytes, the same bits to the products); interpreted on the CPU, whose
+    products take float32, the slab stays float32.
+
+    ``interpret=None`` selects from the backend: compiled Mosaic kernel on
+    ``tpu``, interpreter on ``cpu``, ValueError on anything else.
     """
     if interpret is None:
         interpret = _interpret_for_backend(jax.default_backend())
+    x_dtype = jnp.bfloat16 if input_in_products and not interpret else jnp.float32
 
     @jax.custom_batching.custom_vmap
     def fetch(Xi, yi, order):
-        xb, yb = _fleet_batches(
-            Xi[None], yi[None], order[None], n_batches, interpret
-        )
-        return xb[0], yb[0]
+        # one machine alone, outside any vmap: the rows the kernel gives
+        batch = order.shape[0] // n_batches
+        xb = Xi[order].reshape(n_batches, batch, -1).astype(x_dtype)
+        return xb, yi[order].reshape(n_batches, batch, -1)
 
     @fetch.def_vmap
     def fetch_fleet(axis_size, in_batched, X, y, order):
@@ -234,6 +443,9 @@ def epoch_batches(n_batches: int, interpret: Optional[bool] = None):
             a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
             for a, batched in zip((X, y, order), in_batched)
         )
-        return _fleet_batches(X, y, order, n_batches, interpret), (True, True)
+        return (
+            _fleet_batches(X, y, order, n_batches, x_dtype, interpret),
+            (True, True),
+        )
 
     return fetch

@@ -143,6 +143,41 @@ def _weight_facts(w, lookback: Optional[int] = None, lookahead: int = 0):
     return rows, valid.sum(axis=1, dtype=jnp.int32)
 
 
+def _read_by_products_alone(fn, x, *rest) -> bool:
+    """
+    Whether ``fn(x, *rest)`` reads the array ``x`` only as an operand of
+    matrix products at the default precision (``x`` and ``rest`` may be
+    abstract). On a TPU such a product computes in bfloat16
+    (``jax.lax.Precision.DEFAULT``), so ``x`` rounded to bfloat16 gives ``fn``
+    the same bits: what XLA's own bfloat16 propagation stores for such an
+    operand where it makes it.
+    """
+    if jax.config.jax_default_matmul_precision is not None:
+        return False
+    closed = jax.make_jaxpr(fn)(x, *rest)
+    return _products_alone_read(closed.jaxpr, closed.jaxpr.invars[0])
+
+
+def _products_alone_read(jaxpr, var) -> bool:
+    if any(out is var for out in jaxpr.outvars):
+        return False
+    default = jax.lax.Precision.DEFAULT
+    for eqn in jaxpr.eqns:
+        for k, arg in enumerate(eqn.invars):
+            if arg is not var:
+                continue
+            if eqn.primitive is jax.lax.dot_general_p:
+                if eqn.params["precision"] not in (None, (default, default)):
+                    return False
+                continue
+            # a call (jit, a custom rule's body): what its body does with it
+            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
+            inner = getattr(inner, "jaxpr", inner)
+            if inner is None or not _products_alone_read(inner, inner.invars[k]):
+                return False
+    return True
+
+
 @jax.jit
 def _split_masks(w, train_cut, n_train):
     """``validation_split``'s two ``(M, n)`` float32 masks from the ``(M,)``
@@ -450,9 +485,9 @@ class FleetTrainer:
         machine's rows permuted into batch order once an epoch from on-chip
         memory (``ops/row_permute.py``) — where each row is read once an
         epoch (no windows), every machine has rows of its own on ONE chip,
-        the chip is a TPU, the rows are float32 and a machine's table fits
-        the kernel's vector memory; ``"gather"``, the per-step row gathers,
-        everywhere else.
+        the chip is a TPU, the rows are float32 and the kernel's buffers fit
+        its vector and scalar memory (``row_permute.serves``); ``"gather"``,
+        the per-step row gathers, everywhere else.
         """
         if (
             self.spec.windowed
@@ -591,11 +626,12 @@ class FleetTrainer:
         ``"gather"``: inside every step, the three row gathers ``Xi[sel]``,
         ``yi[sel]``, ``wb_all[sel]`` (for a windowed spec, the gather of
         the step's windows). ``"permute_epoch"``: once an epoch, before the
-        step loop, every machine's rows permuted into batch order from
-        on-chip memory (``ops/row_permute.py``: the packing of a group of
-        machines, the kernel, the laying back as per-step slabs); a step
-        then takes its batch as the loop's own slice, and its weights from
-        the sort in ``fleet.order`` that made the order.
+        step loop, every machine's rows permuted into batch order by ONE
+        kernel call (``ops/row_permute.py``: it reads the tables as they
+        lie and writes the per-step slabs, the input slab in bfloat16
+        where the step reads it only through default-precision products);
+        a step then takes its batch as the loop's own slice, and its
+        weights from the sort in ``fleet.order`` that made the order.
         """
         n_samples = self._n_samples(n)
         spec = self.spec
@@ -622,8 +658,6 @@ class FleetTrainer:
                     "the permuting row fetch is for stacked, non-windowed data"
                 )
             from gordo_tpu.ops import row_permute
-
-            fetch_epoch = row_permute.epoch_batches(n_batches)
 
         def sample_weights(wi):
             """Per-sample effective weight for every grid sample: a window
@@ -705,9 +739,6 @@ class FleetTrainer:
                     # overflow slots fetch sample 0 and weigh nothing
                     w_all = jnp.pad(w_sorted, (0, max(0, n_pad - n_samples)))
                     w_all = w_all[:n_pad].reshape(n_batches, batch_size)
-            if permute:
-                with jax.named_scope("fleet.gather"):
-                    xb_all, yb_all = fetch_epoch(Xi, yi, order[:n_pad])
 
             def loss_fn(p, xb, yb, wb, dropout_key):
                 out, penalty = module.apply(
@@ -723,10 +754,30 @@ class FleetTrainer:
 
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+            if permute:
+                # a step reads its input rows only through the model (the
+                # loss reads the targets), and autodiff's products of them
+                # keep the forward's precision: a forward that reads them
+                # only through default-precision products lets the kernel
+                # store them rounded
+                rounded = _read_by_products_alone(
+                    lambda xb, p, k: module.apply(
+                        p, xb, deterministic=False, rngs={"dropout": k}
+                    ),
+                    jax.ShapeDtypeStruct((batch_size,) + Xi.shape[1:], Xi.dtype),
+                    params, key,
+                )
+                with jax.named_scope("fleet.gather"):
+                    fetch_epoch = row_permute.epoch_batches(
+                        n_batches, input_in_products=rounded
+                    )
+                    xb_all, yb_all = fetch_epoch(Xi, yi, order[:n_pad])
+
             def step(carry, batch):
                 p, o = carry
                 if permute:
                     xb, yb, wb, idx = batch
+                    xb = xb.astype(Xi.dtype)  # a no-op where stored float32
                 else:
                     sel, pm, idx = batch
                     with jax.named_scope("fleet.gather"):

@@ -115,16 +115,85 @@ def test_flash_attention_fwd_bwd_compiles(chip, seq):
     assert text.count("tpu_custom_call") >= 3
 
 
-def test_row_permute_kernel_compiles(chip):
-    """The feedforward fleet's fetch at ff50.fit1000's widths: one group of
-    40 machines, 16,384 packed rows of 128 lanes each, 33.5 MB of VMEM."""
-    table = jax.ShapeDtypeStruct((40, N_TIMESTEPS, 128), jnp.float32, sharding=chip)
-    idx = jax.ShapeDtypeStruct((40, N_TIMESTEPS), jnp.int32, sharding=chip)
-    text = (
-        jax.jit(lambda t, i: row_permute.permute_rows(t, i, interpret=False))
-        .lower(table, idx).compile().as_text()
+def kernel_call(entry):
+    """(name, operands) of the one Pallas kernel in an entry computation."""
+    ((name, operands),) = re.findall(
+        r"%(\S+) = \(\S+, \S+\) custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\"",
+        entry,
     )
-    assert "tpu_custom_call" in text
+    return name, operands.split(", ")
+
+
+def assert_tables_by_bitcast(entry, operands, tables):
+    """Each table ``(param, machines, rows, tags)`` reaches the kernel as a
+    bitcast of the entry's own parameter, in the order of its layout."""
+    for operand, (param, n_machines, n, f) in zip(operands, tables):
+        if row_permute._tags_outermost(n_machines, f):
+            order = (f, n_machines, n)
+        else:
+            order = (n_machines, f, n)
+        dims = ",".join(map(str, order))
+        assert re.search(
+            rf"{re.escape(operand)} = f32\[{dims}\]\S* bitcast\(%{param}\.", entry
+        ), (operand, param)
+
+
+# (machines, rows, input tags, target tags): ff50.fit1000's widths in two
+# groups of the grid; a last group part empty; rows that fill no 128-row
+# tile; tags that lie machines outermost; and the two layouts in one call
+KERNEL_SHAPES = {
+    "ff50.fit1000's widths": (16, N_TIMESTEPS, N_TAGS, N_TAGS),
+    "1001 machines": (1001, N_TIMESTEPS, N_TAGS, N_TAGS),
+    "16,100 rows": (1000, 16100, N_TAGS, N_TAGS),
+    "64 tags": (1001, N_TIMESTEPS, 64, 64),
+    "50 and 64 tags": (1001, N_TIMESTEPS, N_TAGS, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
+def test_row_permute_kernel_compiles(chip, case):
+    """The feedforward fleet's fetch, batches of 512, the input slab in
+    bfloat16: each table reaches the kernel by bitcast, in the order of its
+    layout, however the fleet fills its (8, 128) tiles (the kernel reads
+    their padding in place)."""
+    n_machines, n, fx, fy = KERNEL_SHAPES[case]
+    n_batches = N_TIMESTEPS // BATCH
+    X = jax.ShapeDtypeStruct((n_machines, n, fx), jnp.float32, sharding=chip)
+    y = jax.ShapeDtypeStruct((n_machines, n, fy), jnp.float32, sharding=chip)
+    idx = jax.ShapeDtypeStruct((n_machines, N_TIMESTEPS), jnp.int32, sharding=chip)
+    text = (
+        jax.jit(
+            lambda x, y, i: row_permute._fleet_batches(
+                x, y, i, n_batches, jnp.bfloat16, False
+            )
+        )
+        .lower(X, y, idx).compile().as_text()
+    )
+    entry = text[text.index("\nENTRY "):]
+    _, operands = kernel_call(entry)
+    assert_tables_by_bitcast(
+        entry, operands, [("x", n_machines, n, fx), ("y", n_machines, n, fy)]
+    )
+
+
+# (machines, tags) of stacked f32[M, 16384, f] tables, each side of the
+# rule that says which of two layouts pads less
+LAID_TABLES = [(1000, 50), (1001, 50), (56, 50), (57, 50), (1000, 64), (1001, 6), (3, 6)]
+
+
+@pytest.mark.parametrize("shape", LAID_TABLES)
+def test_tables_lie_as_the_fetch_expects(chip, shape):
+    """``row_permute._tags_outermost`` is the compiler's own choice of
+    layout for a stacked table: tags outermost ``{1,0,2}``, else machines
+    outermost ``{1,2,0}``."""
+    n_machines, f = shape
+    table = jax.ShapeDtypeStruct((n_machines, N_TIMESTEPS, f), jnp.float32, sharding=chip)
+    text = jax.jit(lambda x: x + 1).lower(table).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    (layout,) = re.findall(rf"f32\[{n_machines},{N_TIMESTEPS},{f}\]\{{([0-9,]+):\S* parameter", entry)
+    expected = "1,0,2" if row_permute._tags_outermost(n_machines, f) else "1,2,0"
+    assert layout == expected
 
 
 # -- the fused recurrent train steps -----------------------------------------
@@ -397,27 +466,88 @@ def test_lstm_cell_backward_step_makes_each_activation_once(lstm_cell_epoch_prog
         assert ops["row_reads"] == 2, (layer, ops)
 
 
-def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
-    """The epoch program a TPU's feedforward fleet gets (``row_fetch``
-    ``"permute_epoch"``), 48 machines of ff50.fit1000's 1000: two groups of
-    the fleet loop, the second stepping back over the first. Beside the
-    permuted rows the program holds one group's packed tables (4 x 8.4 MB,
-    in and out, and the copies that turn them), whatever the fleet's width."""
+def feedforward_epoch_program(chip, n_machines, n, batch, row_fetch, n_tags=N_TAGS):
+    """The epoch program a feedforward fleet gets on one chip, compiled."""
     from gordo_tpu.models.factories.feedforward import feedforward_hourglass
 
-    n_machines = 48
-    trainer = FleetTrainer(feedforward_hourglass(n_features=N_TAGS))
+    trainer = FleetTrainer(feedforward_hourglass(n_features=n_tags))
     healthy = jax.ShapeDtypeStruct((n_machines,), jnp.bool_, sharding=chip)
-    compiled = trainer._epoch_fn(
-        N_TIMESTEPS, BATCH, True, quarantine=True, row_fetch="permute_epoch"
+    return trainer._epoch_fn(
+        n, batch, True, quarantine=True, row_fetch=row_fetch
     ).lower(
-        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=n_machines),
-        healthy,
+        *fleet_args(trainer, n_tags, n, chip, n_machines=n_machines), healthy
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    permuted = 2 * n_machines * N_TIMESTEPS * N_TAGS * 4
-    one_group = 4 * N_TIMESTEPS * 128 * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < permuted + 4 * one_group
+
+
+def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
+    """The epoch program a TPU's feedforward fleet gets (``row_fetch``
+    ``"permute_epoch"``), 48 machines of ff50.fit1000's 1000: six groups of
+    the kernel's grid. The tables reach the kernel by bitcast, as they lie
+    (rows on lanes, tags outermost); the kernel writes the step slabs, the
+    input slab in bfloat16, and they reach the step loop by bitcast: no
+    copy on either side, no fill and no update of the slabs."""
+    n_machines = 48
+    compiled = feedforward_epoch_program(
+        chip, n_machines, N_TIMESTEPS, BATCH, "permute_epoch"
+    )
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    name, operands = kernel_call(entry)
+    # the tables: a bitcast of the entry's own parameters, no copy
+    assert_tables_by_bitcast(
+        entry, operands,
+        [(table, n_machines, N_TIMESTEPS, N_TAGS) for table in ("Xi", "yi")],
+    )
+    # the slabs: (n_batches, M, f, batch) by bitcast into the step loop,
+    # neither filled nor updated anywhere
+    slab = rf"\[{N_TIMESTEPS // BATCH},{n_machines},(?:{N_TAGS},{BATCH}|{BATCH},{N_TAGS})\]"
+    for op in ("broadcast", "dynamic-update-slice"):
+        assert not re.search(rf"(?:f32|bf16){slab}\S* {op}\(", text), op
+    outputs = re.findall(rf"%(\S+) = \S+ get-tuple-element\(%{re.escape(name)}\)", entry)
+    assert len(outputs) == 2
+    for output in outputs:
+        users = re.findall(rf"= (\S+) (\S+)\([^)]*%{re.escape(output)}\b", entry)
+        assert [op for _, op in users] == ["bitcast"], users
+    assert re.search(rf"bf16\[{N_TIMESTEPS // BATCH},{n_machines},{N_TAGS},{BATCH}\]", entry)
+    # the slabs and the step's own buffers: 269,369,856 bytes (272,838,144
+    # with the XLA loop around the kernel and its fill)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e8
+
+
+def test_gather_path_takes_the_inputs_as_bfloat16(chip):
+    """The same fleet on the per-step gathers (``row_fetch`` ``"gather"``):
+    the program reads ``Xi`` once, as a copy rounded to bfloat16 before the
+    step loop, so its products take the bits that the permuting path's
+    bfloat16 input slab holds."""
+    compiled = feedforward_epoch_program(chip, 48, N_TIMESTEPS, BATCH, "gather")
+    text = compiled.as_text()
+    users = re.findall(r"= (\S+) (\S+)\([^)]*%Xi\.\d+\b", text)
+    assert [(shape.split("[")[0], op) for shape, op in users] == [("bf16", "copy")], users
+
+
+# (machines, rows, batch): fleets that fill their tiles part, and batches
+# of no whole 128-row tile, beside the peak that the fetch this kernel replaced (an XLA
+# loop over groups of machines around a row-moving kernel) stated for the
+# same program (compile, described v5e): a fetch that copied the tables or
+# the slabs whole would state more
+LOOP_FETCH_PEAKS = {
+    "1001 machines": (1001, N_TIMESTEPS, BATCH, 13_373_507_072),
+    "16,100 rows": (1000, 16100, BATCH, 13_210_015_232),
+    "batches of 32": (600, N_TIMESTEPS, 32, 11_885_677_056),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOOP_FETCH_PEAKS))
+def test_permuting_fetch_needs_no_more_hbm_than_before(chip, compiled_kernels, case):
+    n_machines, n, batch, loop_fetch_peak = LOOP_FETCH_PEAKS[case]
+    compiled = feedforward_epoch_program(chip, n_machines, n, batch, "permute_epoch")
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    _, operands = kernel_call(entry)
+    assert_tables_by_bitcast(
+        entry, operands, [(t, n_machines, n, N_TAGS) for t in ("Xi", "yi")]
+    )
+    assert compiled.memory_analysis().peak_memory_in_bytes < loop_fetch_peak
 
 
 def test_fleet_epoch_program_compiles_for_four_chips(topology):

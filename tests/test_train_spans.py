@@ -250,17 +250,18 @@ def test_epoch_program_names_the_trainers_scopes(epoch_paths, kind):
 
 
 def test_permuting_fetch_lies_under_the_gather_scope_before_the_steps(epoch_paths):
-    """Once an epoch: the kernel and the packing around it carry
+    """Once an epoch: the kernel's one call, named ``epoch_fetch``, carries
     ``fleet.gather`` and not ``fleet.step``, no step gathers any more, and
     nothing of it could be taken for a model scan (``scopes.json`` asks for
     ``/scan/`` first)."""
     paths = epoch_paths["feedforward-permuting"]
     fetch = [p for p in paths if "fleet.gather" in p]
-    # the fleet loop, and in its body the kernel's own jitted call
-    assert any(p.endswith("fleet.gather)/while") for p in fetch)
-    assert any("_permute_stack" in p for p in paths)
+    assert any(p.endswith("fleet.gather)/epoch_fetch/pallas_call") for p in fetch)
     assert not any("fleet.step" in p or "/scan/" in p for p in fetch)
-    assert not any(p.endswith("/gather") for p in paths)
+    # the interpreter's own gathers lie inside the kernel's call
+    assert not any(
+        p.endswith("/gather") and "/epoch_fetch/" not in p for p in paths
+    )
     assert "fleet.gather/gather" in epoch_paths["feedforward"]
 
 
