@@ -393,6 +393,11 @@ class FleetTrainer:
         #: refits add "refit" so refit:nan targets refit builds only)
         self.fault_sites = tuple(fault_sites)
         self._optimizer = optimizer if optimizer is not None else spec.make_optimizer()
+        #: the optimizer is the spec's own (what the fused step loop reads
+        #: its arguments from), not one passed in
+        self._spec_optimizer = optimizer is None
+        #: _inputs_in_products' answers, by batch shape and precision
+        self._inputs_rounded: dict = {}
         # ALL compiled program handles (epoch, val, predict, opt_init)
         # live in the one ProgramCache (docs/performance.md "AOT
         # executable cache") — LRU + HBM-aware bounded, hit/miss/evict
@@ -504,6 +509,68 @@ class FleetTrainer:
             return "permute_epoch"
         return "gather"
 
+    def _choose_step_loop(
+        self, data: StackedData, batch_size: int, sample_cap: Optional[int],
+        row_fetch: str,
+    ) -> str:
+        """
+        How this fit's epochs run their optimizer steps, from what the
+        trainer can see (docs/observability.md has the table): ``"fused"``
+        — every machine's steps in ONE kernel call an epoch, each machine
+        block's parameters and Adam moments held in vector memory
+        (``ops/fleet_dense.py``) — where the rows come by the permuting
+        fetch on a TPU, the spec is a float32 ``FeedForwardNet`` with
+        activations and a loss the kernel knows, its own optimizer is Adam
+        with constant arguments, no matrix precision is set, a batch is
+        whole 128-row tiles and a block of machines fits vector memory
+        (``fleet_dense.plan`` and ``serves``); ``"scan"``, the per-step
+        ``lax.scan``, everywhere else.
+        """
+        if (
+            row_fetch != "permute_epoch"
+            or not self._spec_optimizer
+            or jax.default_backend() != "tpu"
+        ):
+            return "scan"
+        from gordo_tpu.ops import fleet_dense
+
+        stack = fleet_dense.plan(self.spec, data.X.shape[-1])
+        if stack is None:
+            return "scan"
+        n_batches = self._n_batches(data.n_timesteps, batch_size, sample_cap)
+        # the input slab as the permuting fetch stores it
+        rounded = self._inputs_in_products(
+            jax.ShapeDtypeStruct((batch_size,) + data.X.shape[2:], data.X.dtype)
+        )
+        x_itemsize = 2 if rounded else data.X.dtype.itemsize
+        if not fleet_dense.serves(
+            stack, data.n_machines, batch_size, n_batches, x_itemsize
+        ):
+            return "scan"
+        return "fused"
+
+    def _inputs_in_products(self, xb: jax.ShapeDtypeStruct) -> bool:
+        """
+        Whether the model's forward pass reads a batch ``xb`` of input rows
+        only as operands of default-precision matrix products
+        (:func:`_read_by_products_alone`), so that the permuting fetch may
+        store them rounded to bfloat16 on a TPU. Traced once a batch shape
+        and matrix precision.
+        """
+        seen = (xb.shape, xb.dtype, jax.config.jax_default_matmul_precision)
+        if seen not in self._inputs_rounded:
+            module = self.spec.module
+            # shapes alone: the forward pass is traced, nothing is drawn
+            abstract_rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
+            params = jax.eval_shape(module.init, abstract_rng, xb)
+            self._inputs_rounded[seen] = _read_by_products_alone(
+                lambda x, p, k: module.apply(
+                    p, x, deterministic=False, rngs={"dropout": k}
+                ),
+                xb, params, abstract_rng,
+            )
+        return self._inputs_rounded[seen]
+
     def _n_batches(
         self, n: int, batch_size: int, sample_cap: Optional[int]
     ) -> int:
@@ -526,6 +593,7 @@ class FleetTrainer:
         inject: bool = False,
         masked: bool = False,
         row_fetch: str = "gather",
+        step_loop: str = "scan",
     ):
         """
         Build (and cache) the jitted fleet-epoch function for a given
@@ -570,19 +638,20 @@ class FleetTrainer:
 
         ``row_fetch`` is how a step's rows reach it (``_choose_row_fetch``,
         which ``fit`` asks once per fit): the default traces the per-step
-        gathers.
+        gathers. ``step_loop`` is how an epoch runs its steps
+        (``_choose_step_loop``): the default traces the ``lax.scan``.
         """
         n_batches = self._n_batches(n, batch_size, sample_cap)
         cache_key = (
             n, batch_size, shuffle, gated, n_batches, quarantine, inject,
-            masked, row_fetch,
+            masked, row_fetch, step_loop,
         )
 
         def build():
             fleet_epoch = self._build_epoch_callable(
                 n, batch_size, shuffle, gated, n_batches,
                 quarantine=quarantine, inject=inject, masked=masked,
-                row_fetch=row_fetch,
+                row_fetch=row_fetch, step_loop=step_loop,
             )
             n_args = 6 + int(gated) + int(quarantine) + int(inject) + int(masked)
             jit_kwargs: dict = {}
@@ -611,6 +680,7 @@ class FleetTrainer:
         inject: bool = False,
         masked: bool = False,
         row_fetch: str = "gather",
+        step_loop: str = "scan",
     ):
         """
         The vmapped fleet-epoch callable for a geometry, un-jitted
@@ -632,6 +702,13 @@ class FleetTrainer:
         where the step reads it only through default-precision products);
         a step then takes its batch as the loop's own slice, and its
         weights from the sort in ``fleet.order`` that made the order.
+
+        ``step_loop`` ``"fused"`` (the permuting fetch only) runs the
+        epoch's steps for the whole fleet in ONE kernel call
+        (``ops/fleet_dense.py``) under ``fleet.loss_grad``, Adam's update
+        with them: the scope ``fleet.optimizer`` is then empty. The
+        kernel's reference, and the path of an un-vmapped call, is the
+        scan itself.
         """
         n_samples = self._n_samples(n)
         spec = self.spec
@@ -658,6 +735,11 @@ class FleetTrainer:
                     "the permuting row fetch is for stacked, non-windowed data"
                 )
             from gordo_tpu.ops import row_permute
+        fused = step_loop == "fused"
+        if fused:
+            if not permute:
+                raise ValueError("the fused step loop reads the permuting fetch's slabs")
+            from gordo_tpu.ops import fleet_dense
 
         def sample_weights(wi):
             """Per-sample effective weight for every grid sample: a window
@@ -740,7 +822,7 @@ class FleetTrainer:
                     w_all = jnp.pad(w_sorted, (0, max(0, n_pad - n_samples)))
                     w_all = w_all[:n_pad].reshape(n_batches, batch_size)
 
-            def loss_fn(p, xb, yb, wb, dropout_key):
+            def loss_fn(p, xb, yb, wb, dropout_key, fm):
                 out, penalty = module.apply(
                     p, xb, deterministic=False, rngs={"dropout": dropout_key}
                 )
@@ -760,12 +842,8 @@ class FleetTrainer:
                 # keep the forward's precision: a forward that reads them
                 # only through default-precision products lets the kernel
                 # store them rounded
-                rounded = _read_by_products_alone(
-                    lambda xb, p, k: module.apply(
-                        p, xb, deterministic=False, rngs={"dropout": k}
-                    ),
-                    jax.ShapeDtypeStruct((batch_size,) + Xi.shape[1:], Xi.dtype),
-                    params, key,
+                rounded = self._inputs_in_products(
+                    jax.ShapeDtypeStruct((batch_size,) + Xi.shape[1:], Xi.dtype)
                 )
                 with jax.named_scope("fleet.gather"):
                     fetch_epoch = row_permute.epoch_batches(
@@ -773,7 +851,7 @@ class FleetTrainer:
                     )
                     xb_all, yb_all = fetch_epoch(Xi, yi, order[:n_pad])
 
-            def step(carry, batch):
+            def step(key, fm, carry, batch):
                 p, o = carry
                 if permute:
                     xb, yb, wb, idx = batch
@@ -787,7 +865,7 @@ class FleetTrainer:
                 # the model's own Flax scopes nest under this one, the
                 # backward pass under transpose(jvp(...))
                 with jax.named_scope("fleet.loss_grad"):
-                    (_, loss_sum), grads = grad_fn(p, xb, yb, wb, dkey)
+                    (_, loss_sum), grads = grad_fn(p, xb, yb, wb, dkey, fm)
                 with jax.named_scope("fleet.optimizer"):
                     updates, new_o = optimizer.update(grads, o, p)
                     new_p = jax.tree.map(lambda a, u: a + u, p, updates)
@@ -804,18 +882,31 @@ class FleetTrainer:
                 return (p, o), (loss_sum, jnp.sum(wb))
 
             step_ids = jnp.arange(n_batches, dtype=jnp.int32)
+
+            def scan_steps(params, opt_state, key, batches, fm):
+                # every per-machine value an argument, none closed over:
+                # the fused step loop's rule sees the fleet's
+                (p, o), (loss_sums, w_sums) = jax.lax.scan(
+                    functools.partial(step, key, fm),
+                    (params, opt_state),
+                    batches + (step_ids,),
+                    unroll=min(self.scan_unroll, n_batches),
+                )
+                return p, o, loss_sums, w_sums
+
             # fleet.step holds what the step loop runs beside the named
             # parts of a step: the loop's own plumbing and the buffers XLA
             # makes for it (a fill of a scan's stacked outputs would carry
             # this path: specs.lstm_time_scan allocates and never fills)
             with jax.named_scope("fleet.step"):
-                (new_params, new_opt), (loss_sums, w_sums) = jax.lax.scan(
-                    step,
-                    (params, opt_state),
-                    (xb_all, yb_all, w_all, step_ids)
-                    if permute
-                    else (sel_all, pm_all, step_ids),
-                    unroll=min(self.scan_unroll, n_batches),
+                batches = (xb_all, yb_all, w_all) if permute else (sel_all, pm_all)
+                if fused:
+                    stack = fleet_dense.plan(spec, Xi.shape[-1])
+                    if stack is None:
+                        raise ValueError("the fused step loop does not serve this spec")
+                    scan_steps = fleet_dense.epoch_steps(stack, scan_steps)
+                new_params, new_opt, loss_sums, w_sums = scan_steps(
+                    params, opt_state, key, batches, fm
                 )
             with jax.named_scope("fleet.guard"):
                 epoch_loss = jnp.sum(loss_sums) / jnp.maximum(jnp.sum(w_sums), 1.0)
@@ -1236,6 +1327,9 @@ class FleetTrainer:
             sample_cap = max(1, int(valid.max()))
             track_best = early_stopping and restore_best_weights
             row_fetch = self._choose_row_fetch(data, batch_size, sample_cap)
+            step_loop = self._choose_step_loop(
+                data, batch_size, sample_cap, row_fetch
+            )
 
             epoch_fn = self._epoch_fn(
                 data.n_timesteps,
@@ -1247,6 +1341,7 @@ class FleetTrainer:
                 inject=inj is not None,
                 masked=masked,
                 row_fetch=row_fetch,
+                step_loop=step_loop,
             )
             val_fn = (
                 self._val_fn(
@@ -1489,6 +1584,7 @@ class FleetTrainer:
             dispatch_times=dispatch_times,
             n_quarantined=n_quarantined,
             row_fetch=row_fetch,
+            step_loop=step_loop,
         )
         return params, losses_out
 
@@ -1623,6 +1719,7 @@ class FleetTrainer:
         dispatch_times: list,
         n_quarantined: int,
         row_fetch: str,
+        step_loop: str,
     ) -> None:
         """
         Derive and publish one fit's telemetry: ``self.fit_telemetry_``
@@ -1698,6 +1795,11 @@ class FleetTrainer:
             # how the steps' rows reached them (_choose_row_fetch), and the
             # epochs run on that path
             "row_fetch": {"path": row_fetch, "epochs": epochs_run},
+            # how their optimizer steps ran (_choose_step_loop), and the
+            # share of this call's epochs whose steps ran in the kernel (a
+            # float: what a benchmark's call log keeps)
+            "step_loop": {"path": step_loop, "epochs": epochs_run},
+            "fused_step_share": float(step_loop == "fused") if epochs_run else 0.0,
             # one dispatch an epoch
             "n_dispatches": epochs_run,
             "n_host_syncs": n_host_syncs,

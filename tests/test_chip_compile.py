@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from gordo_tpu.models.factories.gru import gru_model
 from gordo_tpu.models.factories.lstm import lstm_model
 from gordo_tpu.models.factories.transformer import transformer_model
+from gordo_tpu.ops import fleet_dense
 from gordo_tpu.ops import flash_attention as flash_module
 from gordo_tpu.ops import row_permute
 from gordo_tpu.ops.flash_attention import flash_attention
@@ -68,7 +69,7 @@ def compiled_kernels(monkeypatch):
     """Under a described-topology compile the backend still reads "cpu",
     which selects the Pallas interpreter; compile the Mosaic kernels, as
     the chip would."""
-    for module in (flash_module, row_permute):
+    for module in (flash_module, row_permute, fleet_dense):
         monkeypatch.setattr(
             module, "_interpret_for_backend", lambda backend: False
         )
@@ -466,14 +467,16 @@ def test_lstm_cell_backward_step_makes_each_activation_once(lstm_cell_epoch_prog
         assert ops["row_reads"] == 2, (layer, ops)
 
 
-def feedforward_epoch_program(chip, n_machines, n, batch, row_fetch, n_tags=N_TAGS):
+def feedforward_epoch_program(
+    chip, n_machines, n, batch, row_fetch, n_tags=N_TAGS, step_loop="scan"
+):
     """The epoch program a feedforward fleet gets on one chip, compiled."""
     from gordo_tpu.models.factories.feedforward import feedforward_hourglass
 
     trainer = FleetTrainer(feedforward_hourglass(n_features=n_tags))
     healthy = jax.ShapeDtypeStruct((n_machines,), jnp.bool_, sharding=chip)
     return trainer._epoch_fn(
-        n, batch, True, quarantine=True, row_fetch=row_fetch
+        n, batch, True, quarantine=True, row_fetch=row_fetch, step_loop=step_loop
     ).lower(
         *fleet_args(trainer, n_tags, n, chip, n_machines=n_machines), healthy
     ).compile()
@@ -512,6 +515,70 @@ def test_permuting_feedforward_epoch_program_compiles(chip, compiled_kernels):
     # the slabs and the step's own buffers: 269,369,856 bytes (272,838,144
     # with the XLA loop around the kernel and its fill)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.7e8
+
+
+#: ff50.fit1000's epoch program's stated peak before its steps ran in one
+#: kernel (compile, described v5e): the step kernel may not need more
+FF50_SCAN_PEAK = 13_310_869_504
+
+
+def test_ff50_epoch_runs_its_steps_in_one_kernel(chip, compiled_kernels):
+    """ff50.fit1000's epoch program as a TPU gets it (1000 machines,
+    ``step_loop`` ``"fused"``): the step slabs go from the fetch kernel
+    into the step kernel with nothing between them (no copy, no
+    transpose), the step kernel runs under ``fleet.loss_grad``, no XLA
+    loop over the steps is left, and the program needs no more HBM than
+    the scan's did."""
+    n_machines = 1000
+    compiled = feedforward_epoch_program(
+        chip, n_machines, N_TIMESTEPS, BATCH, "permute_epoch", step_loop="fused"
+    )
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    steps = re.search(
+        r"%(fleet_steps\S*) = .* custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\".*op_name=\"([^\"]*)\"",
+        entry,
+    )
+    assert steps, "no step kernel"
+    assert "fleet.loss_grad" in steps.group(3)
+    slabs = re.findall(
+        r"%(\S+) = \S+ get-tuple-element\(%epoch_fetch\S*\), index=", entry
+    )
+    assert len(slabs) == 2
+    for slab in slabs:
+        users = re.findall(rf"%(\S+) = .*[(, ]%{re.escape(slab)}[,)]", entry)
+        assert users == [steps.group(1)], (slab, users)
+    assert " while(" not in entry
+    assert compiled.memory_analysis().peak_memory_in_bytes <= FF50_SCAN_PEAK
+
+
+#: sha256 of lstm50.fit's epoch program lowered for a described v5e (4
+#: machines x 16,384 rows x 50 tags, lookback 64, batch 512, the guard on),
+#: as it was before the fused step loop: a feedforward kernel must not move
+#: a line of it
+LSTM50_EPOCH_SHA256 = "72c9010b1ad9ce1056ce2b80f414b6af6de102a48f200ffad5a6a94bb95e59aa"
+
+
+def test_lstm50_epoch_program_is_untouched_by_the_step_kernel(chip):
+    import hashlib
+    import json
+    import pathlib
+
+    from gordo_tpu import serializer
+    from gordo_tpu.builder.fleet_build import _find_jax_estimator
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / "chipbench/configs/lstm-ae-50tag.json").read_text())
+    estimator = _find_jax_estimator(serializer.from_definition(config["model"]))
+    estimator.kwargs.update(n_features=N_TAGS, n_features_out=N_TAGS)
+    trainer = FleetTrainer(estimator._build_spec(), lookahead=0)
+    healthy = jax.ShapeDtypeStruct((CELL_MACHINES,), jnp.bool_, sharding=chip)
+    text = trainer._epoch_fn(N_TIMESTEPS, BATCH, False, quarantine=True).lower(
+        *fleet_args(trainer, N_TAGS, N_TIMESTEPS, chip, n_machines=CELL_MACHINES),
+        healthy,
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == LSTM50_EPOCH_SHA256
 
 
 def test_gather_path_takes_the_inputs_as_bfloat16(chip):

@@ -265,6 +265,25 @@ def test_permuting_fetch_lies_under_the_gather_scope_before_the_steps(epoch_path
     assert "fleet.gather/gather" in epoch_paths["feedforward"]
 
 
+def test_fused_step_kernel_lies_under_the_loss_grad_scope():
+    """On the fused step loop the kernel's one call, named ``fleet_steps``,
+    carries ``fleet.loss_grad`` inside ``fleet.step`` (``scopes.json``
+    counts it under ``fleet.loss_grad``, Adam's update with it), nothing is
+    left under ``fleet.optimizer``, and nothing of it could be taken for a
+    model scan."""
+    paths = op_paths(lowered_epoch(
+        feedforward_hourglass(n_features=F), shuffle=True,
+        row_fetch="permute_epoch", step_loop="fused",
+    ))
+    steps = [p for p in paths if "/fleet_steps/" in p]
+    assert any(
+        p.endswith("fleet.step)/fleet.loss_grad/fleet_steps/pallas_call") for p in steps
+    )
+    assert all("fleet.loss_grad" in p for p in steps)
+    assert not any("/scan/" in p for p in steps)
+    assert not any("fleet.optimizer" in p for p in paths)
+
+
 def test_windowed_epoch_program_is_untouched_by_the_row_fetch(monkeypatch):
     """A windowed spec never takes the permuting fetch: asked as on a TPU
     the chooser keeps the gather, the program it then gets is the text the
