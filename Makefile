@@ -13,7 +13,7 @@ DOCKERFILE_deploy  = Dockerfile-Deploy
 
 # NB: image-%/push-% pattern targets must NOT be .PHONY — GNU make skips
 # implicit-rule search for .PHONY targets
-.PHONY: all test test-sanitize lint bench bench-summary bench-cold-start bench-hetero bench-sharded bench-streaming bench-precision bench-slo bench-gameday bench-attribution build-multiworker images push
+.PHONY: all test test-sanitize lint bench-summary bench-cold-start bench-hetero bench-sharded bench-streaming bench-slo bench-gameday bench-attribution build-multiworker images push
 
 all: lint test
 
@@ -34,9 +34,6 @@ test-sanitize:
 	GORDO_LOCK_SANITIZE=1 GORDO_LOCK_SANITIZE_REPORT=lock_graph_report.json \
 		python -m pytest tests/ -q -m 'not slow'
 	python -m gordo_tpu.cli lockgraph lock_graph_report.json
-
-bench:
-	python bench.py
 
 # fold every ad-hoc results_*.json into one benchmarks/trajectory.json
 # (bench name, revision, headline metric, knob settings) — the autotuner
@@ -72,17 +69,6 @@ bench-streaming:
 	python benchmarks/stream_load.py --streams 1,4,16 --duration 10 \
 		--update-rows 5 --window-rows 256 --mixed-rps 2 \
 		--output benchmarks/results_stream_cpu_r12.json
-
-# per-machine mixed precision + transfer pipelining + donation arms
-# (docs/performance.md "Mixed precision, buffer donation, and transfer
-# pipelining"): bf16-vs-float32 build/dispatch arms with per-machine
-# MAE deltas, prefetch-depth overlap ratios, and the donate on/off
-# output-delta evidence
-bench-precision:
-	python benchmarks/fleet_throughput.py --machines 8 --epochs 3 \
-		--sequential-sample 2 \
-		--precision-sweep float32,bf16 --prefetch-sweep 0,2 \
-		--donation-arms > benchmarks/results_precision_cpu_r15.json
 
 # SLO-gated serving bench (docs/observability.md "Plane rollup and
 # control signals"): the open-loop load test evaluated against the

@@ -59,7 +59,7 @@ POST_ROWS = 256
 STREAM_CHUNKS = (128, 64, 64)
 
 SIZES = {
-    # the flagship configuration (bench.py, BASELINE.json configs 2-3)
+    # the flagship configuration (BASELINE.json configs 2-3)
     "full": dict(
         n_machines=8, n_tags=50, lookback=64, enc=(128, 64), dec=(64, 128),
         batch=512, n_timesteps=16384, epochs=3,

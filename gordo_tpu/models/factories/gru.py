@@ -33,7 +33,6 @@ def gru_model(
     dtype: Union[str, Any] = "float32",
     fused: bool = False,
     time_unroll: int = 1,
-    schedule: str = "layer",
     **kwargs,
 ) -> ModelSpec:
     """
@@ -41,9 +40,7 @@ def gru_model(
     ``fused=True`` hoists the r/z/n input projections out of the time
     scan (specs.FusedGRULayer) — same math, TPU-friendlier schedule, as
     for the LSTM family; ``time_unroll`` unrolls the fused layers' scan
-    (schedule-only). ``schedule="stacked"`` (fused only) streams all
-    layers through ONE time scan — the XLA:CPU-friendly layout; see
-    LSTMNet.schedule.
+    (schedule-only).
     """
     return recurrent_spec(
         "gru",
@@ -61,7 +58,6 @@ def gru_model(
         dtype=dtype,
         fused=fused,
         time_unroll=time_unroll,
-        schedule=schedule,
     )
 
 

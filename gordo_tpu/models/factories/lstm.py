@@ -28,7 +28,6 @@ def recurrent_spec(
     dtype: Union[str, Any] = "float32",
     fused: bool = False,
     time_unroll: int = 1,
-    schedule: str = "layer",
 ) -> ModelSpec:
     """Shared builder behind the lstm_* and gru_* factory trios."""
     n_features_out = n_features_out or n_features
@@ -43,7 +42,6 @@ def recurrent_spec(
         cell=cell,
         fused=fused,
         time_unroll=int(time_unroll),
-        schedule=schedule,
         dtype=resolve_dtype(dtype),
     )
     return ModelSpec(
@@ -73,7 +71,6 @@ def lstm_model(
     dtype: Union[str, Any] = "float32",
     fused: bool = False,
     time_unroll: int = 1,
-    schedule: str = "layer",
     **kwargs,
 ) -> ModelSpec:
     """
@@ -84,8 +81,6 @@ def lstm_model(
     ``time_unroll`` unrolls the fused layers' time scan (schedule-only;
     identical math) — XLA then fuses gate math across consecutive steps,
     cutting per-step carry-copy overhead.
-    ``schedule="stacked"`` (fused only) streams all layers through ONE
-    time scan — the XLA:CPU-friendly layout; see LSTMNet.schedule.
     """
     return recurrent_spec(
         "lstm",
@@ -103,7 +98,6 @@ def lstm_model(
         dtype=dtype,
         fused=fused,
         time_unroll=time_unroll,
-        schedule=schedule,
     )
 
 

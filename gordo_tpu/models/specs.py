@@ -235,8 +235,9 @@ def lstm_cell_step(c, h, z_t, w_h, b_h, act, dtype):
     One LSTM timestep from pre-projected input ``z_t`` (gate order
     [i, f, g, o], sigmoid gates, ``act`` on g and the cell output):
     matmul in ``dtype`` (MXU); gate math + cell state in float32, matching
-    OptimizedLSTMCell's float32 (param_dtype) carry. Shared by both the
-    per-layer and the stacked schedules so the cell math lives ONCE.
+    OptimizedLSTMCell's float32 (param_dtype) carry. The reference that
+    :func:`lstm_time_scan` is held to: a ``jax.lax.scan`` of this step,
+    its backward pass left to autodiff, computes the same numbers.
     """
     return lstm_cell_update(c, lstm_gates(h, z_t, w_h, b_h, dtype), act)
 
@@ -449,7 +450,7 @@ def gru_cell_step(h, z_t, w_rz, w_n, b_n, act, dtype, h_dim):
     One GRU timestep from pre-projected input ``z_t`` (r/z sigmoid gates,
     ``act`` on the candidate, reset gate applied to the PROJECTED hidden
     state, ``h' = (1-z)*n + z*h`` — GRUCell's convention); float32 gate
-    math like lstm_cell_step. Shared by both schedules.
+    math like lstm_cell_step. The step of :class:`FusedGRULayer`'s scan.
     """
     hd = h.astype(dtype)
     rz = (z_t[..., : 2 * h_dim] + hd @ w_rz).astype(jnp.float32)
@@ -647,166 +648,13 @@ class LSTMNet(nn.Module):
     fused: bool = False
     cell: str = "lstm"  # "lstm" | "gru"
     time_unroll: int = 1  # fused layers' scan unroll (schedule-only knob)
-    # "layer": one time scan per layer (TPU default). The LSTM's loop
-    #   multiplies a step's input itself (FusedLSTMLayer says why); the
-    #   GRU's input projection is hoisted to one (batch*time) matmul.
-    # "stacked": ALL layers stream through ONE time scan (layer l's step
-    #   consumes layer l-1's hidden state of the same timestep), so the
-    #   inter-layer (time, batch, 4h) z/hs sequence buffers never
-    #   materialize and layers >0 run small per-step gemms. On XLA:CPU
-    #   those small gemms hit ~121 GF/s where the hoisted skinny-K gemms
-    #   are bandwidth-bound at ~40 GF/s (round-5 measurements,
-    #   docs/performance.md) — the oneDNN-style streaming schedule.
-    #   Math is step-for-step identical; the param tree differs, so pick
-    #   at model-definition time (parity pinned in tests/test_fused_lstm).
-    schedule: str = "layer"
     dtype: Any = jnp.float32
-
-    def _stacked_scan(self, x):
-        """The one-scan streaming schedule over time-major x (time, batch, f)."""
-        dims = self.layer_dims
-        acts = [resolve_activation(f) for f in self.layer_funcs]
-        n_gates = 4 if self.cell == "lstm" else 3
-        t_dim, b_dim = x.shape[0], x.shape[1]
-
-        # layer 0's input projection still hoists to one big matmul —
-        # x is known ahead of the scan
-        z1 = nn.Dense(
-            n_gates * dims[0],
-            use_bias=(self.cell == "gru"),
-            dtype=self.dtype,
-            name="input_proj_0",
-        )(x.reshape(-1, x.shape[-1]))
-        z1 = z1.reshape(t_dim, b_dim, n_gates * dims[0])
-
-        w_x, b_x, w_h, b_h, w_rz, w_n, b_n = [], [], [], [], [], [], []
-        for layer, d in enumerate(dims):
-            prev = dims[layer - 1] if layer else None
-            if layer:
-                w_x.append(
-                    self.param(
-                        f"input_kernel_{layer}",
-                        nn.initializers.lecun_normal(),
-                        (prev, n_gates * d),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                )
-                b_x.append(
-                    self.param(
-                        f"input_bias_{layer}",
-                        nn.initializers.zeros_init(),
-                        (n_gates * d,),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                    if self.cell == "gru"
-                    else None
-                )
-            if self.cell == "lstm":
-                w_h.append(
-                    self.param(
-                        f"recurrent_kernel_{layer}",
-                        nn.initializers.orthogonal(),
-                        (d, 4 * d),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                )
-                b_h.append(
-                    self.param(
-                        f"recurrent_bias_{layer}",
-                        nn.initializers.zeros_init(),
-                        (4 * d,),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                )
-            else:
-                w_rz.append(
-                    self.param(
-                        f"recurrent_kernel_rz_{layer}",
-                        nn.initializers.orthogonal(),
-                        (d, 2 * d),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                )
-                w_n.append(
-                    self.param(
-                        f"recurrent_kernel_n_{layer}",
-                        nn.initializers.orthogonal(),
-                        (d, d),
-                        jnp.float32,
-                    ).astype(self.dtype)
-                )
-                b_n.append(
-                    self.param(
-                        f"recurrent_bias_n_{layer}",
-                        nn.initializers.zeros_init(),
-                        (d,),
-                        jnp.float32,
-                    )
-                )
-
-        def lstm_step(carry, z1_t):
-            new_carry = []
-            inp = None
-            for layer, (d, act) in enumerate(zip(dims, acts)):
-                c, h = carry[layer]
-                z_t = z1_t if layer == 0 else inp @ w_x[layer - 1]
-                c, h = lstm_cell_step(
-                    c, h, z_t, w_h[layer], b_h[layer], act, self.dtype
-                )
-                new_carry.append((c, h))
-                inp = h.astype(self.dtype)
-            return tuple(new_carry), None
-
-        def gru_step(carry, z1_t):
-            new_carry = []
-            inp = None
-            for layer, (d, act) in enumerate(zip(dims, acts)):
-                z_t = (
-                    z1_t
-                    if layer == 0
-                    else inp @ w_x[layer - 1] + b_x[layer - 1]
-                )
-                h = gru_cell_step(
-                    carry[layer], z_t, w_rz[layer], w_n[layer], b_n[layer],
-                    act, self.dtype, d,
-                )
-                new_carry.append(h)
-                inp = h.astype(self.dtype)
-            return tuple(new_carry), None
-
-        if self.cell == "lstm":
-            init = tuple(
-                (
-                    jnp.zeros((b_dim, d), jnp.float32),
-                    jnp.zeros((b_dim, d), jnp.float32),
-                )
-                for d in dims
-            )
-            step = lstm_step
-        else:
-            init = tuple(jnp.zeros((b_dim, d), jnp.float32) for d in dims)
-            step = gru_step
-        with jax.named_scope("scan"):  # see FusedLSTMLayer
-            final, _ = jax.lax.scan(
-                step, init, z1, unroll=max(1, int(self.time_unroll))
-            )
-        last = final[-1]
-        h_last = last[1] if self.cell == "lstm" else last
-        return h_last.astype(self.dtype)  # (batch, h_last)
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):  # x: (batch, time, features)
         if self.cell not in ("lstm", "gru"):
             raise ValueError(f"Unknown recurrent cell {self.cell!r}")
-        if self.schedule not in ("layer", "stacked"):
-            raise ValueError(f"Unknown schedule {self.schedule!r}")
-        if self.schedule == "stacked" and not self.fused:
-            # silently falling through to the nn.RNN path would train a
-            # different param tree than the caller asked to measure
-            raise ValueError('schedule="stacked" requires fused=True')
-        if self.fused and self.schedule == "stacked":
-            x = self._stacked_scan(x.swapaxes(0, 1))  # -> (batch, h_last)
-        elif self.fused:
+        if self.fused:
             # time-major through the whole stack: ONE transpose on entry,
             # none between layers, and none on exit (the head reads the
             # last timestep, hs[-1]). The round-4 CPU trace showed the

@@ -343,10 +343,6 @@ class FleetTrainer:
         Device mesh; None trains unsharded on the default device.
     donate
         Donate param/opt buffers across epoch calls (halves HBM traffic).
-    scan_unroll
-        Unroll factor for the per-epoch minibatch ``lax.scan`` — higher
-        values let XLA fuse across step boundaries (less loop overhead for
-        small cells) at the cost of compile time. 1 = no unrolling.
     optimizer
         Optional optax optimizer overriding ``spec.make_optimizer()`` —
         e.g. an ``optax.inject_hyperparams``-wrapped one whose state
@@ -375,7 +371,6 @@ class FleetTrainer:
         lookahead: int = 0,
         mesh: Optional[Mesh] = None,
         donate: bool = True,
-        scan_unroll: int = 1,
         optimizer: Optional[Any] = None,
         broadcast_data: bool = False,
         quarantine_nonfinite: bool = True,
@@ -385,7 +380,6 @@ class FleetTrainer:
         self.lookahead = int(lookahead) if spec.windowed else 0
         self.mesh = mesh
         self.donate = donate
-        self.scan_unroll = max(1, int(scan_unroll))
         self.broadcast_data = broadcast_data
         self.quarantine_nonfinite = bool(quarantine_nonfinite)
         #: GORDO_FAULT_INJECT sites whose nan-mode specs poison this
@@ -890,7 +884,6 @@ class FleetTrainer:
                     functools.partial(step, key, fm),
                     (params, opt_state),
                     batches + (step_ids,),
-                    unroll=min(self.scan_unroll, n_batches),
                 )
                 return p, o, loss_sums, w_sums
 

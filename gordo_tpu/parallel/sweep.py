@@ -45,7 +45,7 @@ class HyperparamSweep:
         share one length (the number of variants). Names must be accepted
         by the underlying optax constructor (e.g. ``learning_rate``,
         ``b1``, ``weight_decay`` for adamw).
-    lookahead, mesh, scan_unroll
+    lookahead, mesh
         Passed through to FleetTrainer — a sweep shards over the mesh's
         fleet axis like any other fleet.
     """
@@ -56,7 +56,6 @@ class HyperparamSweep:
         grid: Dict[str, Sequence[float]],
         lookahead: int = 0,
         mesh: Optional[Any] = None,
-        scan_unroll: int = 1,
     ):
         if not grid:
             raise ValueError("grid must name at least one hyperparameter")
@@ -105,7 +104,6 @@ class HyperparamSweep:
             spec,
             lookahead=lookahead,
             mesh=mesh,
-            scan_unroll=scan_unroll,
             optimizer=optimizer,
             broadcast_data=True,
         )

@@ -32,7 +32,7 @@ from gordo_tpu.ops import row_permute
 from gordo_tpu.ops.flash_attention import flash_attention
 from gordo_tpu.parallel.fleet import FleetTrainer
 
-# the flagship plant (chip_smoke.py's full size; bench.py)
+# the flagship plant (chip_smoke.py's full size)
 N_TAGS, LOOKBACK, BATCH, N_TIMESTEPS, N_MACHINES = 50, 64, 512, 16384, 8
 ENC, DEC = (128, 64), (64, 128)
 
@@ -85,12 +85,12 @@ def on_chip(tree, sharding):
     )
 
 
-def plant_spec(factory=lstm_model, **kwargs):
+def plant_spec(factory=lstm_model):
     return factory(
         n_features=N_TAGS, lookback_window=LOOKBACK,
         encoding_dim=ENC, encoding_func=("tanh",) * len(ENC),
         decoding_dim=DEC, decoding_func=("tanh",) * len(DEC),
-        fused=True, **kwargs,
+        fused=True,
     )
 
 
@@ -200,18 +200,10 @@ def test_tables_lie_as_the_fetch_expects(chip, shape):
 # -- the fused recurrent train steps -----------------------------------------
 
 
-@pytest.mark.parametrize(
-    "factory,kwargs",
-    [
-        (lstm_model, {"schedule": "layer"}),
-        (lstm_model, {"schedule": "stacked"}),
-        (gru_model, {}),
-    ],
-    ids=["lstm-layer", "lstm-stacked", "gru"],
-)
-def test_fused_recurrent_train_step_compiles(chip, factory, kwargs):
+@pytest.mark.parametrize("factory", [lstm_model, gru_model], ids=["lstm-layer", "gru"])
+def test_fused_recurrent_train_step_compiles(chip, factory):
     """One optimizer step of the fused scan at batch 512 x 64 x 50."""
-    spec = plant_spec(factory, **kwargs)
+    spec = plant_spec(factory)
     optimizer = spec.make_optimizer()
     x = jax.ShapeDtypeStruct((BATCH, LOOKBACK, N_TAGS), jnp.float32)
     y = jax.ShapeDtypeStruct((BATCH, N_TAGS), jnp.float32)

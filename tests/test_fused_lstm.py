@@ -3,7 +3,8 @@ FusedLSTMLayer parity: the one-scan LSTM (four gates' kernels side by
 side, the step's input product inside the time loop) must compute exactly
 what nn.RNN(OptimizedLSTMCell) computes when given the same weights (gate
 order [i, f, g, o]), and train end-to-end through the standard estimator
-machinery.
+machinery. A stack of FusedGRULayers is held to nn.RNN(GRUCell) the same
+way.
 """
 
 import pickle
@@ -15,7 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gordo_tpu.models import LSTMAutoEncoder
+from gordo_tpu.models import GRUAutoEncoder, LSTMAutoEncoder
+from gordo_tpu.models.factories.gru import gru_model
+from gordo_tpu.models.factories.lstm import lstm_model
 from gordo_tpu.models.specs import (
     FusedLSTMLayer,
     LSTMNet,
@@ -93,82 +96,64 @@ def test_fused_stacked_layers_match():
     np.testing.assert_allclose(out_fused, out_plain, rtol=1e-5, atol=1e-6)
 
 
-def _map_layer_fused_to_stacked(layer_params, stacked_params, cell="lstm"):
-    """Per-layer fused params -> the stacked one-scan schedule's layout."""
-    sp = jax.tree_util.tree_map(lambda a: a, stacked_params)["params"]
-    lname = "FusedLSTMLayer" if cell == "lstm" else "FusedGRULayer"
-    layer = 0
-    while f"{lname}_{layer}" in layer_params["params"]:
-        lp = layer_params["params"][f"{lname}_{layer}"]
-        if layer == 0:
-            sp["input_proj_0"]["kernel"] = lp["input_proj"]["kernel"]
-            if cell == "gru":
-                sp["input_proj_0"]["bias"] = lp["input_proj"]["bias"]
-        else:
-            sp[f"input_kernel_{layer}"] = lp["input_proj"]["kernel"]
-            if cell == "gru":
-                sp[f"input_bias_{layer}"] = lp["input_proj"]["bias"]
-        if cell == "lstm":
-            sp[f"recurrent_kernel_{layer}"] = lp["recurrent_kernel"]
-            sp[f"recurrent_bias_{layer}"] = lp["recurrent_bias"]
-        else:
-            sp[f"recurrent_kernel_rz_{layer}"] = lp["recurrent_kernel_rz"]
-            sp[f"recurrent_kernel_n_{layer}"] = lp["recurrent_kernel_n"]
-            sp[f"recurrent_bias_n_{layer}"] = lp["recurrent_bias_n"]
-        layer += 1
-    sp["Dense_0"] = layer_params["params"]["Dense_0"]
-    return {"params": sp}
+def _map_fused_gru_to_cell(layer_params, h_dim):
+    """FusedGRULayer's side-by-side r | z | n kernels -> GRUCell's per-gate
+    denses (the input biases ride ``input_proj``, the recurrent bias the
+    candidate's ``hn``)."""
+    w_i = layer_params["input_proj"]["kernel"]  # (f, 3h): r | z | n
+    b_i = layer_params["input_proj"]["bias"]  # (3h,)
+    w_rz = layer_params["recurrent_kernel_rz"]  # (h, 2h): r | z
+    return {
+        "ir": {"kernel": w_i[:, :h_dim], "bias": b_i[:h_dim]},
+        "iz": {"kernel": w_i[:, h_dim : 2 * h_dim], "bias": b_i[h_dim : 2 * h_dim]},
+        "in": {"kernel": w_i[:, 2 * h_dim :], "bias": b_i[2 * h_dim :]},
+        "hr": {"kernel": w_rz[:, :h_dim]},
+        "hz": {"kernel": w_rz[:, h_dim:]},
+        "hn": {
+            "kernel": layer_params["recurrent_kernel_n"],
+            "bias": layer_params["recurrent_bias_n"],
+        },
+    }
 
 
-def test_stacked_schedule_matches_layer_schedule():
-    """schedule="stacked" (one streaming time scan for all layers — the
-    XLA:CPU-friendly layout) must compute exactly what the per-layer
-    fused schedule computes given the same weights, for both cells."""
+def test_fused_gru_stacked_layers_match():
+    """Two time-major FusedGRULayers in one net compute what the plain
+    nn.RNN(GRUCell) stack computes given the same weights."""
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(B, T, F)), jnp.float32)
-    dims, funcs = (H, 4, H), ("tanh", "relu", "tanh")
+    dims, funcs = (H, 4), ("tanh", "relu")
 
-    for cell in ("lstm", "gru"):
-        layer_net = LSTMNet(
-            layer_dims=dims, layer_funcs=funcs, out_dim=2, fused=True, cell=cell
-        )
-        stacked_net = LSTMNet(
-            layer_dims=dims, layer_funcs=funcs, out_dim=2, fused=True,
-            cell=cell, schedule="stacked",
-        )
-        layer_params = layer_net.init(jax.random.PRNGKey(0), x)
-        stacked_params = stacked_net.init(jax.random.PRNGKey(1), x)
-        stacked_params = _map_layer_fused_to_stacked(
-            layer_params, stacked_params, cell
-        )
-        out_layer, _ = layer_net.apply(layer_params, x)
-        out_stacked, _ = stacked_net.apply(stacked_params, x)
-        np.testing.assert_allclose(out_stacked, out_layer, rtol=1e-5, atol=1e-6)
-
-
-def test_stacked_estimator_trains_and_predicts():
-    rng = np.random.default_rng(5)
-    X = rng.random((80, F)).astype("float32")
-    model = LSTMAutoEncoder(
-        kind="lstm_model",
-        lookback_window=6,
-        encoding_dim=(8,),
-        encoding_func=("tanh",),
-        decoding_dim=(8,),
-        decoding_func=("tanh",),
-        fused=True,
-        schedule="stacked",
-        epochs=2,
+    plain = LSTMNet(layer_dims=dims, layer_funcs=funcs, out_dim=2, cell="gru")
+    fused = LSTMNet(
+        layer_dims=dims, layer_funcs=funcs, out_dim=2, cell="gru", fused=True
     )
-    model.fit(X, X)
-    assert model.predict(X).shape == (80 - 6 + 1, F)
+    plain_params = plain.init(jax.random.PRNGKey(0), x)
+    fused_params = fused.init(jax.random.PRNGKey(1), x)
+
+    fp = fused_params["params"]
+    cell_params = {"params": {"Dense_0": fp["Dense_0"]}}
+    for i, dim in enumerate(dims):
+        cell_params["params"][f"GRUCell_{i}"] = _map_fused_gru_to_cell(
+            fp[f"FusedGRULayer_{i}"], dim
+        )
+    jax.tree.map(  # the transplant covers the plain net's whole tree
+        lambda a, b: None, plain_params, cell_params
+    )
+
+    out_plain, _ = plain.apply(cell_params, x)
+    out_fused, _ = fused.apply(fused_params, x)
+    np.testing.assert_allclose(out_fused, out_plain, rtol=1e-5, atol=1e-6)
 
 
-def test_fused_estimator_trains_and_pickles():
+@pytest.mark.parametrize(
+    "estimator, kind",
+    [(LSTMAutoEncoder, "lstm_model"), (GRUAutoEncoder, "gru_model")],
+)
+def test_fused_estimator_trains_and_pickles(estimator, kind):
     rng = np.random.default_rng(2)
     X = rng.random((80, F)).astype("float32")
-    model = LSTMAutoEncoder(
-        kind="lstm_model",
+    model = estimator(
+        kind=kind,
         lookback_window=6,
         encoding_dim=(8,),
         encoding_func=("tanh",),
@@ -184,25 +169,22 @@ def test_fused_estimator_trains_and_pickles():
     np.testing.assert_allclose(clone.predict(X), out, rtol=1e-5)
 
 
-def test_time_unroll_is_pure_schedule():
+@pytest.mark.parametrize("factory", [lstm_model, gru_model])
+def test_time_unroll_is_pure_schedule(factory):
     """``time_unroll`` must not change the math — unrolled and rolled
     scans produce identical outputs for identical params."""
-    from gordo_tpu.models.factories.lstm import lstm_model
-
     rng = np.random.default_rng(3)
     x = rng.random((4, 10, F)).astype("float32")
-    rolled = lstm_model(
+    rolled = factory(
         n_features=F, lookback_window=10, encoding_dim=(8,),
         encoding_func=("tanh",), decoding_dim=(8,), decoding_func=("tanh",),
         fused=True, time_unroll=1,
     )
-    unrolled = lstm_model(
+    unrolled = factory(
         n_features=F, lookback_window=10, encoding_dim=(8,),
         encoding_func=("tanh",), decoding_dim=(8,), decoding_func=("tanh",),
         fused=True, time_unroll=4,
     )
-    import jax
-
     params = rolled.module.init(jax.random.PRNGKey(0), x)
     out_rolled, _ = rolled.module.apply(params, x)
     out_unrolled, _ = unrolled.module.apply(params, x)

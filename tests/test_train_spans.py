@@ -33,11 +33,11 @@ def fleet_data():
     return StackedData(X, X, jnp.ones((M, N), jnp.float32))
 
 
-def recurrent_spec(factory=lstm_model, **kwargs):
+def recurrent_spec(factory=lstm_model):
     return factory(
         n_features=F, lookback_window=4, encoding_dim=(8,),
         encoding_func=("tanh",), decoding_dim=(8,), decoding_func=("tanh",),
-        fused=True, **kwargs,
+        fused=True,
     )
 
 
@@ -407,13 +407,9 @@ def test_step_scope_metric_reads_ms_an_epoch(capsys, metric, by_path, expected, 
     assert tuple(sorted(int(k) for k in told)) == layers
 
 
-@pytest.mark.parametrize(
-    "factory,kwargs,module",
-    [(gru_model, {}, "FusedGRULayer_0"), (lstm_model, {"schedule": "stacked"}, "LSTMNet._stacked_scan")],
-    ids=["gru", "lstm-stacked"],
-)
-def test_other_fused_scans_carry_the_scope(factory, kwargs, module):
-    paths = op_paths(lowered_epoch(recurrent_spec(factory, **kwargs), shuffle=False))
+@pytest.mark.parametrize("factory,module", [(gru_model, "FusedGRULayer_0")], ids=["gru"])
+def test_other_fused_scans_carry_the_scope(factory, module):
+    paths = op_paths(lowered_epoch(recurrent_spec(factory), shuffle=False))
     assert any(f"{module}/scan/" in p for p in paths)
 
 
